@@ -67,10 +67,6 @@ def bench_service(frame: int = 64, n_frames: int = 40,
     """In-process ceiling: the real EngineKVService.batch handler on a
     real RealtimeScheduler pump loop — everything the served path does
     except sockets and codec."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from multiraft_tpu.distributed.engine_server import EngineKVService
     from multiraft_tpu.distributed.engine_wire import EngineCmdArgs
     from multiraft_tpu.distributed.realtime import RealtimeScheduler
@@ -143,7 +139,11 @@ def bench_service(frame: int = 64, n_frames: int = 40,
 def main(argv) -> None:
     n_frames = int(argv[1]) if len(argv) > 1 else 40
     frame = int(argv[2]) if len(argv) > 2 else 64
-    out = {"frame": frame}
+    from multiraft_tpu.utils.device import claim_device
+
+    # The service ceiling runs the tick in this process, on what JAX
+    # selects — named in the output.
+    out = {"frame": frame, "device": claim_device()}
     out.update(bench_codec(frame))
     out.update(bench_service(frame, n_frames))
     print(json.dumps(out), flush=True)

@@ -177,10 +177,6 @@ def bench_firehose_inprocess(
     saved_hot = os.environ.get("MRT_PUMP_HOT")
     os.environ.setdefault("MRT_PUMP_HOT", "1")
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     import numpy as np
 
     from multiraft_tpu.distributed.engine_server import EngineKVService
@@ -455,15 +451,35 @@ def sched_wait(node, gen, timeout=60.0):
 
 def main(argv) -> None:
     mode = argv[1] if len(argv) > 1 and not argv[1].isdigit() else ""
-    if mode == "firehose":
-        # Median-of-3 for the in-process ceiling (same shared-box
-        # discipline as bench.py's cross-run statistics); one long
-        # multi-client socket window.
+    if mode == "firehose-inprocess":
+        # The in-process ceiling runs the tick in THIS process: it
+        # claims the device (what JAX selects) and says which.
+        from multiraft_tpu.utils.device import claim_device
+
+        dev = claim_device()
         reps = sorted(
             bench_firehose_inprocess()["ops_per_sec"] for _ in range(3)
         )
+        print(json.dumps({"device": dev, "reps": reps}), flush=True)
+        return
+    if mode == "firehose":
+        # One process per chip: the in-process ceiling (median of 3)
+        # holds the device, so it runs in a child that has exited
+        # before the socket leg's server child starts, and this parent
+        # never initialises a backend.  Then one long multi-client
+        # socket window.
+        import subprocess
+
+        child = subprocess.run(
+            [sys.executable, "-m", "benchmarks.serving_throughput",
+             "firehose-inprocess"],
+            check=True, stdout=subprocess.PIPE, text=True,
+        )
+        inproc = json.loads(child.stdout.strip().splitlines()[-1])
+        reps = inproc["reps"]
         socks = bench_firehose_sockets()
         print(json.dumps({
+            "inprocess_device": inproc["device"],
             "firehose_inprocess_ops_per_sec": reps[1],
             "inprocess_min": reps[0],
             "inprocess_max": reps[2],

@@ -22,10 +22,7 @@ jax.config.update("jax_platforms", "cpu")
 import dataclasses
 
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax only exports it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiraft_tpu.engine.core import EngineConfig, empty_mailbox, init_state, tick

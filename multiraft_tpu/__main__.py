@@ -21,19 +21,13 @@ import argparse
 import sys
 
 
-def _pin(platform: str) -> None:
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", platform)
-    except Exception as exc:
-        if platform != "cpu":
-            raise RuntimeError(f"could not pin platform {platform}: {exc}")
-
-
 def _serve_forever(args, build) -> int:
-    """Shared serve scaffold: pin the backend, build the node, print
+    """Shared serve scaffold: claim the device, build the node, print
     the readiness line, park the main thread.
+
+    ``--platform`` is a pin that fails when JAX cannot bring that
+    backend up; without it the server runs on what JAX selects.  Either
+    way one stderr line at readiness names the device.
 
     SIGTERM/SIGINT shut down gracefully: a durable server writes a
     final checkpoint (rotating the WAL away), so the next start
@@ -42,7 +36,13 @@ def _serve_forever(args, build) -> int:
     import signal
     import threading
 
-    _pin(args.platform)
+    from .utils.device import claim_device, device_line
+
+    try:
+        dev = claim_device(args.platform or "")
+    except RuntimeError as exc:
+        print(f"error: {exc}", file=sys.stderr, flush=True)
+        return 1
     node = build()
     stop = threading.Event()
 
@@ -58,6 +58,7 @@ def _serve_forever(args, build) -> int:
 
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, _on_signal)
+    print(f"device {device_line(dev)}", file=sys.stderr, flush=True)
     print(f"ready {node.port}", flush=True)
     stop.wait()
     svc = getattr(node, "engine_service", None)
@@ -158,8 +159,9 @@ def _add_serve_flags(p: argparse.ArgumentParser) -> None:
                    metavar="SECONDS")
     p.add_argument("--mesh-devices", type=int, default=0,
                    help="run the tick over this many local chips")
-    p.add_argument("--platform", default="cpu", choices=("cpu", "tpu"),
-                   help="pin the jax backend (tpu = own the chip)")
+    p.add_argument("--platform", default=None, choices=("cpu", "tpu"),
+                   help="pin the jax backend and fail if it does not come "
+                        "up (tpu = own the chip); default: what JAX selects")
 
 
 def main(argv=None) -> int:

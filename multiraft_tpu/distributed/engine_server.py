@@ -467,8 +467,14 @@ class EngineKVService:
     MAX_FIREHOSE = MAX_FIREHOSE_ROWS
 
     def info(self, _args=None) -> dict:
-        """Topology the columnar clerks need for client-side routing."""
-        return {"G": self.G}
+        """Topology: ``G`` is what the columnar clerks route by;
+        ``state_devices`` counts the devices that hold a shard of the
+        consensus state (1, or the mesh size when it really is spread)."""
+        shards = self.kv.driver.state.term.addressable_shards
+        return {
+            "G": self.G,
+            "state_devices": len({s.device for s in shards}),
+        }
 
     def firehose(self, blob):
         """Columnar frame (engine/firehose.py): ONE bytes blob in, one

@@ -1,7 +1,7 @@
 """ctypes loader for the native KV linearizability checker.
 
-Builds ``libporcupine.so`` from ``checker.cpp`` on first use (g++ -O2;
-no pybind11 in this image — plain C ABI + ctypes) and exposes
+Builds ``libporcupine.<source hash>.so`` from ``checker.cpp`` on first
+use (g++ -O2; no pybind11 in this image — plain C ABI + ctypes) and exposes
 :func:`check_kv_partition_native` (verdict only) and
 :func:`check_kv_partition_native_verbose` (verdict + partial
 linearizations, the reference's computePartial).  Falls back to the

@@ -1,6 +1,18 @@
-"""Crash-safe persistent-compilation-cache shim.
+"""The persistent compilation cache: where it lives, and a crash-safe
+writer for it.
 
-jax 0.4.x's file-system cache writes entries IN PLACE
+:func:`enable_compile_cache` is the ONE place the program decides where
+compiled executables are kept.  Where ``JAX_COMPILATION_CACHE_DIR`` is
+set, jax already honours it and nothing is set in code; otherwise the
+cache is ``<checkout>/.jax_cache`` — a fixed path (the path is part of
+the cache key's environment, so a directory that moves never hits),
+git-ignored, shared by every process started from this checkout.
+Every entry point that compiles calls it before its first jit
+(``python -m multiraft_tpu serve-*``, ``cluster._server_main``,
+``bench.py``, ``benchmarks/*``, ``chip_smoke.py``'s children,
+``tests/conftest.py``).
+
+jax 0.9.0's file-system cache writes entries IN PLACE
 (``LRUCache.put`` → ``Path.write_bytes``): a process SIGKILLed
 mid-write leaves a truncated serialized executable under the final
 name, and a concurrent reader can observe the same torn state while a
@@ -14,9 +26,7 @@ arbitrary points.
 :func:`harden_persistent_cache` swaps the write for the standard
 crash-safe idiom — temp file in the same directory, then an atomic
 ``os.replace`` — so the final name only ever points at a complete
-entry.  Call it before the first jit in any process that shares a
-cache dir with processes that may die (server children do, via
-cluster._server_main; the test parent does, via conftest)."""
+entry.  :func:`enable_compile_cache` calls it."""
 
 from __future__ import annotations
 
@@ -24,26 +34,59 @@ import os
 import time
 import warnings
 
-__all__ = ["harden_persistent_cache"]
+__all__ = [
+    "CACHE_ENV",
+    "cache_dir",
+    "enable_compile_cache",
+    "harden_persistent_cache",
+]
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
-def harden_persistent_cache() -> bool:
-    """Make jax's on-disk compilation-cache writes atomic.  Returns
-    True when the patch is in place (or already was), False when this
-    jax build has no file-system LRU cache to patch (nothing to do —
-    the cache, and therefore the hazard, is absent)."""
-    try:
-        from jax._src import lru_cache as _m
-    except Exception:  # pragma: no cover - jax layout changed
-        return False
-    cls = getattr(_m, "LRUCache", None)
-    if cls is None or not hasattr(cls, "put"):  # pragma: no cover
-        return False
+def cache_dir() -> str:
+    """Where this process keeps compiled executables: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``."""
+    return os.environ.get(CACHE_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on for this process and
+    make its writes atomic.  Call before the first jit.  Returns the
+    cache directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set jax has already read it and
+    this sets nothing; unset, the cache is ``<checkout>/.jax_cache``,
+    exported so that child processes (servers, examples) inherit the
+    same directory."""
+    import jax
+
+    path = cache_dir()
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", path)
+        os.environ[CACHE_ENV] = path
+    harden_persistent_cache()
+    return path
+
+
+def harden_persistent_cache() -> None:
+    """Make jax's on-disk compilation-cache writes atomic (idempotent).
+    Raises when the installed jax has no ``LRUCache.put`` to replace:
+    carrying on would bring the torn-entry segfault back silently."""
+    from jax._src import lru_cache as _m
+
+    cls = _m.LRUCache
     if getattr(cls, "_mrt_atomic_put", False):
-        return True
-
-    cache_sfx = getattr(_m, "_CACHE_SUFFIX", "-cache")
-    atime_sfx = getattr(_m, "_ATIME_SUFFIX", "-atime")
+        return
+    if not callable(getattr(cls, "put", None)):
+        raise RuntimeError(
+            "jax._src.lru_cache.LRUCache.put is gone: the crash-safe "
+            "cache writer in utils/jaxcache.py must be ported to this jax"
+        )
 
     def put(self, key: str, val: bytes) -> None:
         if not key:
@@ -54,8 +97,7 @@ def harden_persistent_cache() -> bool:
                 f"exceeds the maximum cache size of {self.max_size} bytes"
             )
             return
-        cache_path = self.path / f"{key}{cache_sfx}"
-        atime_path = self.path / f"{key}{atime_sfx}"
+        cache_path = self.path / f"{key}{_m._CACHE_SUFFIX}"
         if self.eviction_enabled:
             self.lock.acquire(timeout=self.lock_timeout_secs)
         try:
@@ -76,12 +118,13 @@ def harden_persistent_cache() -> bool:
                 except OSError:
                     pass
                 raise
-            timestamp = time.time_ns().to_bytes(8, "little")
-            atime_path.write_bytes(timestamp)
+            if self.eviction_enabled:
+                timestamp = time.time_ns().to_bytes(8, "little")
+                atime_path = self.path / f"{key}{_m._ATIME_SUFFIX}"
+                atime_path.write_bytes(timestamp)
         finally:
             if self.eviction_enabled:
                 self.lock.release()
 
     cls.put = put
     cls._mrt_atomic_put = True
-    return True

@@ -97,9 +97,12 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("MRT_DEBUG", "bool", False, "distributed.cluster",
          "Verbose harness/cluster debug logging to stderr."),
     # -- distributed.engine_cluster ------------------------------------------
-    Knob("MRT_ENGINE_PLATFORM", "str", "cpu", "distributed.engine_cluster",
-         "JAX platform the engine server process initializes "
-         "(cpu/tpu); engine-cluster launches pin it per child."),
+    Knob("MRT_ENGINE_PLATFORM", "str", "", "distributed.engine_cluster",
+         "JAX platform (cpu/tpu) engine-cluster launches write into "
+         "each server spec: it becomes the child's JAX_PLATFORMS and "
+         "the child fails if that backend does not come up. Empty = "
+         "no pin, the child runs on what JAX selects from the "
+         "launcher's environment."),
     # -- distributed.engine_pump ---------------------------------------------
     Knob("MRT_PIPELINE_DEPTH", "int", 2, "distributed.engine_pump",
          "In-flight fused tick batches the pipelined pump keeps "

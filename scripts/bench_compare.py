@@ -44,9 +44,8 @@ the fresh value, and the delta against the LATEST round.  Exit status:
 * 2 — the fresh result (or the entire history) was unreadable.
 
 Metrics missing on either side are reported as ``n/a`` and never fail
-the comparison — early rounds lack failover numbers (BENCH_r01 is a
-different headline metric entirely) and a CPU-only smoke run may lack
-everything but commits/s.  CI runs this as a NON-BLOCKING artifact
+the comparison — an early round may lack failover numbers and a
+CPU-only smoke run may lack everything but commits/s.  CI runs this as a NON-BLOCKING artifact
 step: the table lands in the job log and the exit code is recorded,
 but a perf regression alone does not veto a merge (the ±5% gate in the
 acceptance checklist is enforced on the benchmark host, where the
