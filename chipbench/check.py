@@ -1,0 +1,170 @@
+"""The comparison that decides ``correct``, made outside the window.
+
+The plain reference of this store is a register per key.  Every value
+ever written carries a unique tag, so a read names the write it saw,
+and the reference's rules can be checked directly on the whole record —
+every key, every operation — without a search:
+
+* a read returns a value that some operation really wrote to that key;
+* that write was called before the read returned (nothing from the
+  future);
+* no other acknowledged write to the key lies strictly between them —
+  called after the seen write was acknowledged, and acknowledged before
+  the read was called (no stale read, no lost acknowledged update).
+
+These are the conditions linearizability of a register puts on a read
+and the write it saw.  The program's own porcupine checker (a full
+search for a linearization) then runs over every operation on a seeded
+sample of keys with whole values; it is the program's code, so it
+stands beside this check and not in place of it.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Tuple
+
+import numpy as np
+
+from traffic import LOADER, TAG, Records, code
+
+
+class History:
+    """Flat arrays of every recorded operation: the load, the closed
+    loop (warm-up, window, drain) and the reads made after it."""
+
+    def __init__(self, records: Records, t_loaded: float) -> None:
+        n = records.n
+        self.records = records
+        # The load: record i written by the loader, acknowledged by t_loaded.
+        self.w_key = [np.arange(n)]
+        self.w_call = [np.full(n, -np.inf)]
+        self.w_ret = [np.full(n, t_loaded)]
+        self.w_code = [code(LOADER, 0) + np.arange(n)]
+        self.r_key: List[np.ndarray] = []
+        self.r_call: List[np.ndarray] = []
+        self.r_ret: List[np.ndarray] = []
+        self.r_code: List[np.ndarray] = []
+
+    def add_loop(self, loop) -> None:
+        rec = loop.rec
+        called = ~np.isnan(rec.call)
+        acked = ~np.isnan(rec.ret)
+        cl, nn = np.nonzero(called & loop.is_update)
+        self.w_key.append(loop.key_index[cl, nn])
+        self.w_call.append(rec.call[cl, nn])
+        # An update that was never acknowledged may still have been
+        # applied: it is a write that no read is obliged to see.
+        self.w_ret.append(np.where(acked[cl, nn], rec.ret[cl, nn], np.inf))
+        self.w_code.append(cl * 10 ** (TAG - 2) + nn)
+        cl, nn = np.nonzero(acked & ~loop.is_update)
+        self.add_reads(loop.key_index[cl, nn], rec.call[cl, nn],
+                       rec.ret[cl, nn], rec.got[cl, nn])
+
+    def add_reads(self, key, call, ret, got) -> None:
+        self.r_key.append(np.asarray(key, np.int64))
+        self.r_call.append(np.asarray(call, np.float64))
+        self.r_ret.append(np.asarray(ret, np.float64))
+        self.r_code.append(np.asarray(got, np.int64))
+
+
+def register_check(h: History) -> List[str]:
+    """Every read against the rules above.  Returns what is wrong, at
+    most a few lines; empty means every read was allowed."""
+    wk, wc, wr, wcode = map(np.concatenate, (h.w_key, h.w_call, h.w_ret, h.w_code))
+    rk, rc, rr, rcode = map(np.concatenate, (h.r_key, h.r_call, h.r_ret, h.r_code))
+    wrong: List[str] = []
+    # Which write did each read see?  Codes are unique over all writes.
+    order = np.argsort(wcode)
+    pos = np.searchsorted(wcode[order], rcode)
+    pos = np.minimum(pos, len(order) - 1)
+    seen = order[pos]
+    known = (wcode[seen] == rcode) & (wk[seen] == rk)
+    for i in np.nonzero(~known)[0][:3].tolist():
+        wrong.append(f"{h.records.keys[rk[i]]}: read tag {rcode[i]} that nobody wrote to it")
+    future = known & (wc[seen] > rr)
+    for i in np.nonzero(future)[0][:3].tolist():
+        wrong.append(f"{h.records.keys[rk[i]]}: read tag {rcode[i]} before it was written")
+    # Stale reads: per key, the earliest acknowledgement among writes
+    # called after time t, by a suffix minimum over writes sorted by call.
+    w_by_key = np.lexsort((wc, wk))
+    wk_s, wc_s, wr_s = wk[w_by_key], wc[w_by_key], wr[w_by_key]
+    starts = np.searchsorted(wk_s, np.arange(h.records.n + 1))
+    multi = (starts[1:] - starts[:-1]) > 1      # keys with more than the load
+    check = np.nonzero(known & multi[rk])[0]
+    r_by_key = check[np.argsort(rk[check], kind="stable")]
+    bounds = np.searchsorted(rk[r_by_key], np.arange(h.records.n + 1))
+    stale = 0
+    for k in np.nonzero(bounds[1:] > bounds[:-1])[0].tolist():
+        lo, hi = starts[k], starts[k + 1]
+        calls = wc_s[lo:hi]
+        suffix_min_ret = np.minimum.accumulate(wr_s[lo:hi][::-1])[::-1]
+        suffix_min_ret = np.append(suffix_min_ret, np.inf)
+        reads = r_by_key[bounds[k]:bounds[k + 1]]
+        after = np.searchsorted(calls, wr[seen[reads]], side="right")
+        bad = suffix_min_ret[after] < rc[reads]
+        if bad.any():
+            stale += int(bad.sum())
+            if len(wrong) < 6:
+                i = reads[np.nonzero(bad)[0][0]]
+                wrong.append(
+                    f"{h.records.keys[k]}: stale read of tag {rcode[i]}: a later "
+                    f"update was acknowledged before the read was called"
+                )
+    if stale:
+        wrong.append(f"{stale} stale reads in all")
+    return wrong
+
+
+def porcupine_sample(h: History, loop, keys, extra_reads, timeout_s: float
+                     ) -> Tuple[str, int]:
+    """The program's porcupine over every operation on ``keys`` (whole
+    values).  ``extra_reads``: ``(key, call, ret, value)`` made after
+    the loop.  Returns the verdict's name and the number of operations."""
+    from multiraft_tpu.porcupine.checker import check_operations
+    from multiraft_tpu.porcupine.kv import OP_GET, OP_PUT, KvInput, KvOutput, kv_model
+    from multiraft_tpu.porcupine.model import Operation
+
+    records, rec = h.records, loop.rec
+    keyset = np.zeros(records.n, bool)
+    keyset[list(keys)] = True
+    t_loaded = float(h.w_ret[0][0])
+    ops = [
+        Operation(LOADER, KvInput(OP_PUT, records.keys[k], records.value(LOADER, k)),
+                  t_loaded - 1.0, KvOutput(""), t_loaded)
+        for k in keys
+    ]
+    acked = ~np.isnan(rec.ret)
+    horizon = float(np.nanmax(rec.ret)) + 3600.0
+    cl, nn = np.nonzero(~np.isnan(rec.call) & keyset[loop.key_index])
+    for c, n in zip(cl.tolist(), nn.tolist()):
+        key = records.keys[loop.key_index[c, n]]
+        if loop.is_update[c, n]:
+            # unacknowledged: may take effect at any later time
+            ret = rec.ret[c, n] if acked[c, n] else horizon
+            ops.append(Operation(c, KvInput(OP_PUT, key, records.value(c, n)),
+                                 rec.call[c, n], KvOutput(""), ret))
+        elif acked[c, n]:
+            ops.append(Operation(c, KvInput(OP_GET, key), rec.call[c, n],
+                                 KvOutput(rec.kept[(c, n)]), rec.ret[c, n]))
+    for k, call, ret, value in extra_reads:
+        if keyset[k]:
+            ops.append(Operation(LOADER, KvInput(OP_GET, records.keys[k]), call,
+                                 KvOutput(value), ret))
+    verdict = check_operations(kv_model, ops, timeout=timeout_s)
+    return verdict.value, len(ops)
+
+
+def durability_counters(before: Dict[str, Any], after: Dict[str, Any],
+                        acked_updates: int) -> List[str]:
+    """An acknowledged update is in the WAL and flushed before its ack:
+    the WAL took at least as many appends as updates were acknowledged,
+    and it was fsynced.  An ack without its flush is a failed run."""
+    wrong = []
+    appends = after.get("wal.appends", 0) - before.get("wal.appends", 0)
+    fsyncs = after.get("wal.fsyncs", 0) - before.get("wal.fsyncs", 0)
+    if appends < acked_updates:
+        wrong.append(f"wal.appends grew by {appends}, under the {acked_updates} "
+                     f"updates acknowledged between the scrapes")
+    if acked_updates and fsyncs <= 0:
+        wrong.append("updates were acknowledged and wal.fsyncs did not grow")
+    return wrong

@@ -1,0 +1,496 @@
+"""One run of one cell of the on-chip benchmark.
+
+    python chipbench/run.py --workload kv10k.ycsb-a --seed 7 --seconds 30 --trace 0
+
+Starts the program's own server (``serve-kv`` on the TPU, durable) as a
+child, loads the configuration's records over sockets, drives the
+cell's traffic from this process, and prints one JSON line last:
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, with
+``--trace 1``, ``breakdown``.  ``--trace 0`` gives the cell's end-to-end
+metrics, ``--trace 1`` its per-layer metrics.  Everything that belongs
+to one configuration, traffic mix or layer metric is a data file found
+by its name in ``BENCHMARK.json`` (see README.md).
+
+This process never initialises a JAX backend: the chip belongs to the
+server child.  A run that finds no TPU fails and prints no result;
+``--rehearse-cpu`` (tiny sizes, CPU backend) is for debugging this
+script and prints a line marked as a rehearsal, never a result.
+"""
+
+from __future__ import annotations
+
+import time
+
+_T0 = time.monotonic()  # set-up is counted from the start of the process
+
+import argparse
+import json
+import os
+import re
+import shutil
+import signal
+import subprocess
+import sys
+from typing import Any, Dict, List, Optional
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+sys.path.insert(0, ROOT)
+
+import check  # noqa: E402
+import layers  # noqa: E402
+import manifest  # noqa: E402
+import traffic as traffic_mod  # noqa: E402
+from loadgen import ClosedLoop  # noqa: E402
+
+_DEVICE_RE = re.compile(r"device platform=(\S+) device_kind=(.*) devices=(\d+)$")
+TRACE_S = 3.0  # how long the profiler is on, in the middle of the window
+TAILS = (50, 95, 99)     # percentiles of latency, reads and updates apart
+READY_CAP_S = 900.0      # a first start compiles
+DRAIN_S = 10.0           # after the window, for operations in flight
+DRAWN_OPS_PER_CLIENT_PER_S = 800  # drawn before the window; far above any rate seen
+READBACK_KEYS = 1000     # updated keys read back whole after the window
+# Porcupine (a full search, with whole values) runs on a seeded sample of
+# the ranks that see more than a few operations in a window.
+PORCUPINE_KEYS, PORCUPINE_FROM_RANKS, PORCUPINE_TIMEOUT_S = 200, 5000, 20.0
+
+
+class RunFailed(Exception):
+    """The run cannot give a result; the message is the reason."""
+
+
+def say(msg: str) -> None:
+    print(f"[{time.monotonic() - _T0:7.1f}s] {msg}", flush=True)
+
+
+# ---------------------------------------------------------------------------
+# The server child
+# ---------------------------------------------------------------------------
+
+
+class Server:
+    """One ``serve-kv`` child (through ``server_child.py``) on a fresh
+    data directory.  After chip_smoke.py's ``Server``."""
+
+    def __init__(self, work: str, serve: List[str], env: Dict[str, str], platform: str,
+                 seed: int) -> None:
+        from multiraft_tpu.distributed.launch import reserve_ports
+
+        self.side = os.path.join(work, "side")
+        os.makedirs(self.side)
+        self.port = reserve_ports(1, "127.0.0.1")[0]
+        self.err_path = os.path.join(work, "server.err")
+        argv = [
+            sys.executable, os.path.join(HERE, "server_child.py"), self.side,
+            *serve, "--platform", platform, "--data-dir", os.path.join(work, "data"),
+            "--seed", str(seed % (2 ** 31 - 1)), "--port", str(self.port),
+        ]
+        env = {**os.environ, **env}  # the configuration fixes the deployment; it wins
+        if platform == "cpu":
+            env["JAX_PLATFORMS"] = "cpu"
+        with open(self.err_path, "w") as err:
+            self.proc = subprocess.Popen(
+                argv, cwd=ROOT, env=env, text=True, stdout=subprocess.PIPE, stderr=err,
+            )
+        self.reports = 0
+
+    def stderr_tail(self) -> str:
+        with open(self.err_path) as f:
+            return " | ".join(f.read().strip().splitlines()[-4:])
+
+    def wait_ready(self, cap_s: float) -> Dict[str, Any]:
+        from multiraft_tpu.distributed.launch import check_ready
+
+        try:
+            check_ready(self.proc, "serve-kv", timeout=cap_s)
+        except RuntimeError as exc:
+            raise RunFailed(f"{exc} [server stderr: {self.stderr_tail()}]") from None
+        with open(self.err_path) as f:
+            found = [m for m in map(_DEVICE_RE.search, f.read().splitlines()) if m]
+        if not found:
+            raise RunFailed("serve-kv printed no device line")
+        m = found[-1]
+        return {"platform": m.group(1), "kind": m.group(2), "count": int(m.group(3))}
+
+    def report(self, cap_s: float = 30.0) -> Dict[str, Any]:
+        """Ask the child (SIGHUP) for its device memory peak and its
+        count of compile events so far."""
+        self.reports += 1
+        path = os.path.join(self.side, f"report.{self.reports}.json")
+        self.proc.send_signal(signal.SIGHUP)
+        return json.loads(self._await_file(path, cap_s, "a report"))
+
+    def signal_and_await(self, sig: int, marker: str, cap_s: float) -> None:
+        self.proc.send_signal(sig)
+        self._await_file(os.path.join(self.side, marker), cap_s, marker)
+
+    def _await_file(self, path: str, cap_s: float, what: str) -> str:
+        deadline = time.monotonic() + cap_s
+        while not os.path.exists(path):
+            if self.proc.poll() is not None:
+                raise RunFailed(f"server exited ({self.proc.returncode}) before {what}: "
+                                f"{self.stderr_tail()}")
+            if time.monotonic() > deadline:
+                raise RunFailed(f"server child gave {what} not within {cap_s:.0f}s")
+            time.sleep(0.01)
+        with open(path) as f:
+            return f.read()
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait()
+        if self.proc.stdout is not None:
+            self.proc.stdout.close()
+
+
+# ---------------------------------------------------------------------------
+# Talking to it
+# ---------------------------------------------------------------------------
+
+
+class Client:
+    def __init__(self, port: int) -> None:
+        from multiraft_tpu.distributed.tcp import RpcNode
+
+        self.node = RpcNode()
+        self.end = self.node.client_end("127.0.0.1", port)
+
+    def call(self, verb: str, cap_s: float = 120.0) -> Any:
+        from multiraft_tpu.sim.scheduler import TIMEOUT
+
+        out = self.node.sched.wait(self.end.call(verb, None), cap_s)
+        if out is TIMEOUT or not isinstance(out, dict):
+            raise RunFailed(f"{verb} said {out!r}")
+        return out
+
+    def run(self, gen, cap_s: float) -> Any:
+        from multiraft_tpu.sim.scheduler import TIMEOUT
+
+        out = self.node.sched.wait(self.node.sched.spawn(gen), cap_s)
+        if out is TIMEOUT:
+            raise RunFailed("the server did not answer")
+        return out
+
+    def scrape(self) -> Dict[str, Any]:
+        """Counters (``Obs.snapshot``; its percentiles are since process
+        start and are not read) and cumulative histograms (``Obs.hist``)."""
+        snap = self.call("Obs.snapshot")
+        hist = self.call("Obs.hist")
+        return {"counters": snap["metrics"], "hists": hist["hists"]}
+
+    def firehose(self, ops, cap_s: float):
+        from multiraft_tpu.distributed.engine_clerks import FirehoseClerk
+
+        return self.run(
+            FirehoseClerk(self.node.sched, self.end).run_batch(ops, deadline_s=cap_s),
+            cap_s + 30.0,
+        )
+
+
+# ---------------------------------------------------------------------------
+# Reductions
+# ---------------------------------------------------------------------------
+
+
+def end_to_end(loop: ClosedLoop, t0: float, t1: float) -> Dict[str, Any]:
+    """YCSB's numbers over the window [t0, t1): operations acknowledged
+    inside it, and their latencies from call to acknowledged reply,
+    reads and updates apart."""
+    rec = loop.rec
+    lat_ms = (rec.ret - rec.call) * 1000.0
+    inside = (rec.ret >= t0) & (rec.ret < t1)       # nan compares false
+    upd = lat_ms[inside & loop.is_update]
+    rd = lat_ms[inside & ~loop.is_update]
+    if len(upd) < 100 or len(rd) < 100:
+        raise RunFailed(f"too few operations in the window for a 99th percentile: "
+                        f"{len(upd)} updates, {len(rd)} reads")
+    lost = ~np.isnan(rec.call) & np.isnan(rec.ret) & (rec.call < t1)
+    done = np.sort(rec.ret[inside])
+    stalls = np.diff(done, prepend=t0, append=t1)
+    worst = np.argsort(stalls)[-3:][::-1]
+    say("longest stretches with no operation acknowledged: " + ", ".join(
+        f"{stalls[i]:.3f}s at {np.append(done, t1)[i] - stalls[i] - t0:.1f}s" for i in worst))
+    out = {
+        "completed": int(inside.sum()), "failed": int(lost.sum()),
+        "updates": int(len(upd)), "reads": int(len(rd)),
+        "ops_per_s": float(inside.sum() / (t1 - t0)),
+    }
+    for kind, lat in (("update", upd), ("read", rd)):
+        for q in TAILS:
+            out[f"{kind}_p{q}_ms"] = float(np.percentile(lat, q))
+    return out
+
+
+def stage_times(before: Dict[str, Any], after: Dict[str, Any]) -> str:
+    """For every server histogram of seconds that took a sample in the
+    window: how many, their sum, and the upper edge of the highest bucket
+    that grew (``Hist``: bucket i ends at 1 us x 2**((i+1)/4)).  A stall
+    of the whole service shows here as one long sample in its stage."""
+    parts = []
+    for name, now in sorted(after.items()):
+        then = before.get(name) or {"n": 0, "sum": 0.0, "b": {}}
+        n = now["n"] - then["n"]
+        if not name.endswith("_s") or n <= 0:
+            continue
+        was = {int(i): c for i, c in (then.get("b") or {}).items()}
+        grew = [int(i) for i, c in (now.get("b") or {}).items() if c > was.get(int(i), 0)]
+        top = 1e-6 * 2.0 ** ((max(grew) + 1) / 4.0) if grew else float("nan")
+        parts.append(f"{name} {n} {now['sum'] - then['sum']:.3f} {top:.4f}")
+    return "; ".join(parts)
+
+
+def reduce_trace(side: str, program: str, rehearse: bool) -> Dict[str, Any]:
+    """``trace_reduce.py`` in a process of its own, pinned to the CPU:
+    reading the trace needs jax, and this process stays off it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, os.path.join(HERE, "trace_reduce.py"),
+         os.path.join(side, "trace"), "--program", program],
+        env=env, cwd=ROOT, text=True, capture_output=True, timeout=300,
+    )
+    if out.returncode != 0 and rehearse:
+        # The CPU backend writes no device plane: nothing to reduce.
+        say(f"NOTE rehearsal: {out.stderr.strip()[-200:]}")
+        return {"busy_s": 0.0, "window_s": 0.0, "metrics": {},
+                "breakdown": {"device_ops": [], "idle_gaps": []}}
+    if out.returncode != 0:
+        raise RunFailed(f"trace_reduce failed: {out.stderr.strip()[-400:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def verify(client: Client, loop: ClosedLoop, history: check.History, keep: np.ndarray,
+           rng: np.random.Generator, quiet0: Dict[str, Any], quiet1: Dict[str, Any],
+           compiled: int) -> List[str]:
+    """What decides ``correct``, outside the window: returns what is
+    wrong (empty: nothing).  ``quiet0``/``quiet1`` are the server's
+    counters before the first and after the last operation of the loop."""
+    records = history.records
+    wrong: List[str] = list(loop.rec.bad_value[:3])
+    history.add_loop(loop)
+    acked_update = loop.is_update & ~np.isnan(loop.rec.ret)
+    updated = np.unique(loop.key_index[acked_update])
+    sample = rng.choice(updated, min(READBACK_KEYS, len(updated)), replace=False)
+    sample = np.union1d(sample, np.intersect1d(keep, updated))
+    c0 = time.perf_counter()
+    got = client.firehose([("Get", records.keys[k], "") for k in sample.tolist()], 120.0)
+    c1 = time.perf_counter()
+    tags = []
+    for k, v in zip(sample.tolist(), got):
+        tag = int(v[:traffic_mod.TAG]) if v[:traffic_mod.TAG].isdigit() else -1
+        if tag < 0 or v != records.value_of_code(tag):
+            wrong.append(f"{records.keys[k]}: read back {v[:30]!r}.. ({len(v)} B), "
+                         f"not a value anyone wrote")
+        tags.append(tag)
+    history.add_reads(sample, np.full(len(sample), c0), np.full(len(sample), c1), tags)
+    wrong += check.register_check(history)
+    wrong += check.durability_counters(quiet0, quiet1, int(acked_update.sum()))
+    verdict, n_ops = check.porcupine_sample(
+        history, loop, keep.tolist(),
+        [(k, c0, c1, v) for k, v in zip(sample.tolist(), got)], PORCUPINE_TIMEOUT_S)
+    if verdict == "illegal":
+        wrong.append(f"porcupine: not linearizable over {n_ops} ops on {len(keep)} keys")
+    if compiled:
+        wrong.append(f"{compiled} compile events inside the window")
+    say(f"check: {sum(map(len, history.r_key))} reads against "
+        f"{sum(map(len, history.w_key))} writes by the register rules; {len(sample)} keys "
+        f"read back; porcupine {verdict} over {n_ops} ops on {len(keep)} keys; "
+        f"compile events in the window: {compiled}")
+    for line in wrong[:8]:
+        say(f"WRONG {line}")
+    return wrong
+
+
+# ---------------------------------------------------------------------------
+# One run
+# ---------------------------------------------------------------------------
+
+
+def run(ns) -> int:
+    cell = manifest.cell(ns.workload)
+    cfg, mix = dict(cell["config"]), cell["traffic"]
+    serve = list(cfg["serve"])
+    if ns.rehearse_cpu:
+        small = cfg["rehearse_cpu"]
+        cfg["recordcount"] = small["recordcount"]
+        serve[serve.index("--groups") + 1] = str(small["groups"])
+        cfg["groups"] = small["groups"]
+    if mix["loop"] != "closed" or mix["path"] != "command":
+        raise RunFailed(f"traffic {mix!r}: only loop=closed, path=command is built")
+    platform = "cpu" if ns.rehearse_cpu else "tpu"
+    seconds = float(ns.seconds)
+    work = os.path.join(ROOT, ".chipbench_run", ns.workload)
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
+    server: Optional[Server] = None
+    client: Optional[Client] = None
+    try:
+        server = Server(work, serve, cfg.get("env", {}), platform, ns.seed)
+        # While the child reaches the chip and compiles: the data.
+        records = traffic_mod.Records(cfg, ns.seed)
+        offset = float(cfg["window_offset_s"])
+        per_client = int(DRAWN_OPS_PER_CLIENT_PER_S * (offset + seconds + 5.0))
+        is_update, key_index = traffic_mod.sequences(mix, records, ns.seed, per_client)
+        rng = np.random.default_rng([ns.seed, 3])
+        pool = min(PORCUPINE_FROM_RANKS, records.n)
+        keep = records.key_of_rank[
+            rng.choice(pool, min(PORCUPINE_KEYS, pool), replace=False)
+        ]
+        say(f"{ns.workload}: {records.n} records x {records.valuebytes} B, "
+            f"{mix['clients']} clients x {per_client} operations drawn from seed {ns.seed}")
+
+        dev = server.wait_ready(READY_CAP_S)
+        t_ready = time.monotonic()
+        say(f"server ready; device {dev}")
+        if dev["platform"] != platform:
+            raise RunFailed(f"the server holds {dev['platform']}, not {platform}")
+        if dev["count"] < cell["chips"]:
+            raise RunFailed(f"the cell asks for {cell['chips']} chip(s), found {dev['count']}")
+        client = Client(server.port)
+        info = client.call("EngineKV.info")
+        if info["G"] != cfg["groups"]:
+            raise RunFailed(f"the server serves G={info['G']}, the configuration says {cfg['groups']}")
+
+        # FirehoseClerk cuts the batch into the server's 8,192-row frames.
+        client.firehose(records.load_ops(), 300.0)
+        t_loaded = time.perf_counter()
+        say(f"loaded in {time.monotonic() - t_ready:.1f}s after ready")
+        history = check.History(records, t_loaded)
+        quiet0 = client.scrape()
+
+        # Warm-up is the cell's own traffic, and runs on into the window.
+        loop = ClosedLoop(client.node, client.end, records, is_update, key_index, keep)
+        loop.start()
+        wait = t_ready + offset - time.monotonic()
+        if wait < 1.0:
+            say(f"NOTE set-up overran window_offset_s={offset}: {-wait:.1f}s late; the "
+                f"checkpoint falls earlier in the window than in other runs")
+        time.sleep(max(wait, 1.0))
+        report0 = server.report()
+        before = client.scrape()
+        cpu0, t0 = time.process_time(), time.perf_counter()
+        setup_s = time.monotonic() - _T0
+        say(f"window of {seconds:.0f}s starts {time.monotonic() - t_ready:.1f}s after ready")
+
+        if ns.trace:
+            time.sleep(max((seconds - TRACE_S) / 2.0, 0.0))
+            server.signal_and_await(signal.SIGUSR1, "trace.started", 60.0)
+            time.sleep(TRACE_S)
+            server.signal_and_await(signal.SIGUSR2, "trace.stopped", 120.0)
+        time.sleep(max(t0 + seconds - time.perf_counter(), 0.0))
+
+        cpu1, t1 = time.process_time(), time.perf_counter()
+        clerk = client.node.obs.metrics.counters
+        say(f"clerks so far: {clerk.get('clerk.calls', 0)} calls, "
+            f"{clerk.get('clerk.retries', 0)} retries, {clerk.get('clerk.busy', 0)} shed (ErrBusy)")
+        after = client.scrape()
+        report1 = server.report()
+        loop.stop(DRAIN_S)
+        quiet1 = client.scrape()
+        if loop.exhausted:
+            raise RunFailed("a client ran out of drawn operations: raise DRAWN_OPS_PER_CLIENT_PER_S")
+
+        e2e = end_to_end(loop, t0, t1)
+        grew = {
+            k: v - before["counters"].get(k, 0) for k, v in sorted(after["counters"].items())
+            if not k.endswith(("_p50", "_p99", "_count"))
+            and v != before["counters"].get(k, 0)
+        }
+        say(f"server counters over the window: {json.dumps(grew)}")
+        say("server clocks over the window (samples, sum s, longest <= s): "
+            + stage_times(before["hists"], after["hists"]))
+        say(f"window: {e2e['completed']} ops acknowledged ({e2e['updates']} updates, "
+            f"{e2e['reads']} reads), {e2e['failed']} never acknowledged; ms: " + ", ".join(
+                f"{kind} " + " ".join(f"p{q} {e2e[f'{kind}_p{q}_ms']:.3f}" for q in TAILS)
+                for kind in ("read", "update")))
+
+        compiled = report1["compile_events"] - report0["compile_events"]
+        wrong = verify(client, loop, history, keep, rng,
+                       quiet0["counters"], quiet1["counters"], compiled)
+
+        final = server.report()
+        server.kill()
+
+        # -- the line -------------------------------------------------
+        device = dict(dev, memory_peak_bytes=final["memory_peak_bytes"])
+        out: Dict[str, Any] = {
+            "correct": not wrong, "attempted": e2e["completed"] + e2e["failed"],
+            "failed": e2e["failed"], "metrics": {}, "device": device,
+        }
+        if not ns.trace:
+            e2e["setup_s"] = setup_s
+            for m in cell["end_to_end"]:
+                out["metrics"][m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
+        else:
+            if ns.save_trace:
+                shutil.copytree(os.path.join(server.side, "trace"), ns.save_trace,
+                                dirs_exist_ok=True)
+            traced = reduce_trace(server.side, cfg["tick_program"], ns.rehearse_cpu)
+            if traced["busy_s"] <= 0.0 and not ns.rehearse_cpu:
+                raise RunFailed("the trace shows no operation on the device")
+            device["busy_s"], device["window_s"] = traced["busy_s"], traced["window_s"]
+            out["breakdown"] = traced["breakdown"]
+            gathered = layers.Gathered(
+                t1 - t0, before["counters"], after["counters"], before["hists"],
+                after["hists"],
+                {**e2e, "cpu_share": 100.0 * (cpu1 - cpu0) / (t1 - t0)}, traced["metrics"],
+            )
+            # Device time of the tick program over the ticks it ran: the
+            # trace counts programs, the server's counters ticks per program.
+            tpp, _ = layers.read({"name": "ticks_per_program", "reader": {
+                "kind": "counter_ratio", **cfg["ticks_per_program"]}}, gathered)
+            tm = traced["metrics"]
+            if tpp and tm.get("programs"):
+                tm["tick_ms"] = 1000.0 * tm["program_s"] / (tm["programs"] * tpp)
+            for spec in cell["layers"]:
+                value, why_not = layers.read(spec, gathered)
+                if value is None:
+                    say(f"NOTE layer metric {spec['name']} left out: {why_not}")
+                else:
+                    out["metrics"][spec["name"]] = {"value": value, "unit": spec["unit"]}
+        if ns.rehearse_cpu:
+            out = {"rehearsal": True, **out}
+        print(json.dumps(out), flush=True)
+        return 0
+    finally:
+        if client is not None:
+            client.node.close()
+        if server is not None:
+            server.kill()
+        shutil.rmtree(work, ignore_errors=True)
+        if not os.listdir(os.path.dirname(work)):
+            os.rmdir(os.path.dirname(work))
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny sizes on the CPU backend, to debug this script; "
+                         "the line it prints is marked and is never a result")
+    ap.add_argument("--save-trace", default="", metavar="DIR",
+                    help="with --trace 1: also copy the profiler's files to DIR")
+    ns = ap.parse_args(argv)
+    try:
+        rc = run(ns)
+    except (RunFailed, manifest.ManifestError, ModuleNotFoundError) as exc:
+        # ModuleNotFoundError: chipbench/ without the program beside it.
+        print(f"error: {exc}", file=sys.stderr, flush=True)
+        rc = 1
+    jax_mod = sys.modules.get("jax")
+    if jax_mod is not None:
+        from jax._src import xla_bridge
+
+        assert not xla_bridge.backends_are_initialized(), (
+            "the benchmark's parent initialised a JAX backend")
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,66 @@
+"""The register rules accept what a linearizable store may answer and
+refuse a stale read, a read from the future and a made-up value."""
+
+import numpy as np
+
+import check
+import traffic
+
+CFG = {"recordcount": 50, "fieldcount": 10, "fieldlength": 100}
+
+
+def history(reads, writes):
+    """writes: (key, call, ret, client, n); reads: (key, call, ret, code)."""
+    h = check.History(traffic.Records(CFG, 1), t_loaded=0.0)
+    w = np.array(writes, float).reshape(-1, 5)
+    h.w_key.append(w[:, 0].astype(np.int64))
+    h.w_call.append(w[:, 1])
+    h.w_ret.append(w[:, 2])
+    h.w_code.append(np.array([traffic.code(int(c), int(n)) for c, n in w[:, 3:]], np.int64))
+    r = np.array(reads, float).reshape(-1, 4)
+    h.add_reads(r[:, 0], r[:, 1], r[:, 2], r[:, 3])
+    return h
+
+
+LOADED = lambda k: traffic.code(traffic.LOADER, k)
+W1, W2 = traffic.code(1, 0), traffic.code(2, 0)
+
+
+def test_allowed_histories():
+    writes = [(3, 1.0, 2.0, 1, 0), (3, 1.5, 2.5, 2, 0)]   # overlapping updates
+    reads = [
+        (3, 0.1, 0.2, LOADED(3)),    # before any update
+        (3, 1.2, 1.3, LOADED(3)),    # during the first: old value still fine
+        (3, 1.2, 1.3, W1),           # during the first: new value fine too
+        (3, 3.0, 3.1, W1),           # after both overlapped: either may be last
+        (3, 3.0, 3.1, W2),
+        (7, 5.0, 5.1, LOADED(7)),    # an untouched key
+    ]
+    assert check.register_check(history(reads, writes)) == []
+
+
+def test_stale_read_is_refused():
+    writes = [(3, 1.0, 2.0, 1, 0), (3, 2.5, 3.0, 2, 0)]   # W2 strictly after W1
+    assert check.register_check(history([(3, 3.5, 3.6, W1)], writes))          # lost update
+    assert check.register_check(history([(3, 2.1, 2.2, LOADED(3))], writes))   # stale load
+    assert check.register_check(history([(3, 3.5, 3.6, W2)], writes)) == []
+
+
+def test_unacknowledged_update_obliges_nobody():
+    writes = [(3, 1.0, np.inf, 1, 0)]
+    assert check.register_check(history([(3, 5.0, 5.1, LOADED(3))], writes)) == []
+    assert check.register_check(history([(3, 5.0, 5.1, W1)], writes)) == []
+
+
+def test_future_and_made_up_values_are_refused():
+    writes = [(3, 4.0, 5.0, 1, 0)]
+    assert check.register_check(history([(3, 1.0, 1.1, W1)], writes))        # not written yet
+    assert check.register_check(history([(3, 1.0, 1.1, 424242)], writes))    # nobody's tag
+    assert check.register_check(history([(4, 6.0, 6.1, W1)], writes))        # another key's value
+
+
+def test_durability_counters():
+    before, after = {"wal.appends": 5, "wal.fsyncs": 2}, {"wal.appends": 105, "wal.fsyncs": 30}
+    assert check.durability_counters(before, after, 100) == []
+    assert check.durability_counters(before, after, 101)
+    assert check.durability_counters(before, {"wal.appends": 105, "wal.fsyncs": 2}, 50)

@@ -1,0 +1,41 @@
+"""Layer readers: deltas of what was scraped; no event means no number."""
+
+import layers
+
+G = layers.Gathered(
+    10.0,
+    {"ticks": 100, "wal.appends": 10, "wal.fsyncs": 5, "idle": 3},
+    {"ticks": 1100, "wal.appends": 410, "wal.fsyncs": 105, "idle": 3},
+    {"a_s": {"n": 10, "sum": 1.0}, "b_s": {"n": 5, "sum": 5.0}},
+    {"a_s": {"n": 30, "sum": 1.5}, "b_s": {"n": 5, "sum": 5.0}, "c_s": {"n": 2, "sum": 0.5}},
+    {"cpu_share": 42.0}, {"tick_ms": 2.5},
+)
+
+
+def rd(**reader):
+    return layers.read({"name": "x", "reader": reader}, G)
+
+
+def test_readers():
+    assert rd(kind="counter_delta", counter="ticks") == (1000.0, "")
+    assert rd(kind="counter_rate", counter="ticks") == (100.0, "")
+    assert rd(kind="counter_ratio", num="wal.appends", den="wal.fsyncs") == (4.0, "")
+    assert rd(kind="hist_mean", hists="a_s", scale=1000.0) == (25.0, "")
+    assert rd(kind="hist_mean", hists=["a_s", "c_s"], scale=1.0) == (0.025 + 0.25, "")
+    assert rd(kind="hist_sum", hist="c_s") == (0.5, "")
+    assert rd(kind="client", field="cpu_share") == (42.0, "")
+    assert rd(kind="trace", field="tick_ms") == (2.5, "")
+
+
+def test_no_event_in_the_window_is_no_number_never_zero():
+    for reader in (
+        dict(kind="counter_delta", counter="idle"),
+        dict(kind="counter_rate", counter="never-seen"),
+        dict(kind="counter_ratio", num="ticks", den="idle"),
+        dict(kind="hist_mean", hists="b_s"),
+        dict(kind="hist_mean", hists=["a_s", "b_s"]),
+        dict(kind="hist_sum", hist="never-seen"),
+        dict(kind="trace", field="kernel_roofline"),
+    ):
+        value, why = rd(**reader)
+        assert value is None and why

@@ -1,0 +1,56 @@
+"""BENCHMARK.json and the files under chipbench/ agree by name."""
+
+import json
+import os
+
+import pytest
+
+import layers
+import manifest
+
+MAN = manifest.manifest()
+
+
+def test_every_cell_resolves_to_its_files():
+    for w in MAN["workloads"]:
+        cell = manifest.cell(w["name"])
+        assert cell["config"]["serve"][0] == "serve-kv"
+        assert cell["traffic"]["loop"] == "closed"
+        e2e = {m["name"] for m in cell["end_to_end"]}
+        assert "setup_s" in e2e and len(e2e) >= 2
+        assert cell["layers"], "every cell reports a per-layer metric"
+        assert w["chips"] == 1
+
+
+def test_configs_are_files_under_paths_and_state_what_the_manifest_says():
+    for c in MAN["configs"]:
+        assert c["file"] == f"chipbench/configs/{c['name']}.json"
+        with open(os.path.join(manifest.ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        assert str(cfg["groups"]) in cfg["serve"]
+        assert cfg["fieldcount"] * cfg["fieldlength"] == 1000  # YCSB's record, never cut
+        assert any(c["name"] == w["config"] for w in MAN["workloads"])
+
+
+def test_layer_metrics_have_a_known_reader_and_move_an_end_to_end_metric():
+    e2e = {m["name"] for m in MAN["end_to_end"]}
+    for m in MAN["per_layer"]:
+        with open(os.path.join(manifest.HERE, "layers", m["name"] + ".json")) as f:
+            spec = json.load(f)
+        assert spec["reader"]["kind"] in layers.READERS
+        assert spec["layer"] == m["layer"] and spec["moves"] == m["moves"]
+        assert m["moves"] in e2e and m["moves"] != "setup_s"
+
+
+def test_a_layer_metric_is_only_where_the_metric_it_moves_is_reported():
+    for w in MAN["workloads"]:
+        cell = manifest.cell(w["name"])
+        reported = {m["name"] for m in cell["end_to_end"]}
+        for spec in cell["layers"]:
+            assert spec["moves"] in reported, (w["name"], spec["name"])
+
+
+def test_unknown_workload_is_an_error():
+    with pytest.raises(manifest.ManifestError):
+        manifest.cell("no-such-cell")
