@@ -35,15 +35,25 @@ def _serve_forever(args, build) -> int:
     crash path and recovers via WAL replay."""
     import signal
     import threading
+    import time
 
     from .utils.device import claim_device, device_line
 
+    t0 = time.perf_counter()
     try:
         dev = claim_device(args.platform or "")
     except RuntimeError as exc:
         print(f"error: {exc}", file=sys.stderr, flush=True)
         return 1
+    backend_s = time.perf_counter() - t0  # import jax to the first device
     node = build()
+    # Time to ready by stage: gauges in every Obs.snapshot, and one line.
+    stages = f"backend={backend_s:.3f}" + "".join(
+        f" {name[len('ready.'):-len('_s')]}={secs:.3f}"
+        for name, secs in node.obs.metrics.gauges.items()
+        if name.startswith("ready.")
+    )
+    node.obs.metrics.set("ready.backend_s", backend_s)
     stop = threading.Event()
 
     def _on_signal(*_):
@@ -58,6 +68,7 @@ def _serve_forever(args, build) -> int:
 
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, _on_signal)
+    print(f"ready-in seconds: {stages}", file=sys.stderr, flush=True)
     print(f"device {device_line(dev)}", file=sys.stderr, flush=True)
     print(f"ready {node.port}", flush=True)
     stop.wait()

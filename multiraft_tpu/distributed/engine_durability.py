@@ -16,6 +16,7 @@ from __future__ import annotations
 import os
 import time
 
+from ..engine.instrument import pump_phase
 from ..engine.kv import KVOp
 from ..transport import codec
 from .engine_wire import _OPCODE, route_group
@@ -91,7 +92,8 @@ class EngineDurability:
 
     def after_pump(self) -> None:
         """Group fsync + periodic checkpoint, called once per pump."""
-        self.wal.sync()
+        with pump_phase(self.metrics, "sync"):
+            self.wal.sync()
         if self.every > 0 and (
             time.monotonic() - self._last_ckpt >= self.every
         ):
@@ -100,14 +102,13 @@ class EngineDurability:
     def checkpoint(self) -> None:
         """Atomic engine+service snapshot, then WAL rotation.  A crash
         between the two merely makes the next replay redundant."""
-        t0 = time.perf_counter()
-        self.driver.save(
-            self.ckpt_path,
-            extra={"service": self.state_owner.state_dict()},
-        )
-        self.wal.rotate()
+        with pump_phase(self.metrics, "checkpoint", hist="ckpt.save_s"):
+            self.driver.save(
+                self.ckpt_path,
+                extra={"service": self.state_owner.state_dict()},
+            )
+            self.wal.rotate()
         self.metrics.inc("ckpt.saves")
-        self.metrics.observe("ckpt.save_s", time.perf_counter() - t0)
         self._last_ckpt = time.monotonic()
 
 

@@ -26,23 +26,21 @@ that loop.  The work-queue lock registers with the lock-order sanitizer
 is caught in CI, and the thread is a daemon so a wedged device wait
 never blocks interpreter shutdown.
 
-:class:`LoopOccupancy` is the observability half: the fraction of
-scheduler-loop wall the pump path consumes (``pump.loop_occupancy``).
-Pre-pipeline this sat near 1.0 under load — the loop WAS the pump;
-with the pipeline it should collapse to the dispatch+bookkeeping cost.
+What the wait costs is on the batch, not here: ``PendingTicks.fetch``
+stamps itself and ``complete_ticks`` records ``pump.fetch_s`` and
+``pump.post_s`` (engine/instrument.py has the whole cycle).
 """
 
 from __future__ import annotations
 
 import threading
-import time
 import traceback
 from collections import deque
 from typing import Callable
 
 from .sanitize import get_sanitizer
 
-__all__ = ["EnginePump", "LoopOccupancy", "PUMP_THREAD_PREFIX"]
+__all__ = ["EnginePump", "PUMP_THREAD_PREFIX"]
 
 # Thread-name prefix: distributed/profile.py includes it (with
 # "multiraft-loop") in SERVING_THREAD_PREFIXES, the profiler's
@@ -74,10 +72,6 @@ class EnginePump:
         self._cv = threading.Condition(self._lock)
         self._q: deque = deque()
         self._stopped = False
-        # Wall seconds the pump thread spent blocked in fetches —
-        # exported by the serving loop as the pump side of the
-        # occupancy story (the loop's own share goes to LoopOccupancy).
-        self.fetch_wall_s = 0.0
         self._thread = threading.Thread(
             target=self._run, name=name, daemon=True
         )
@@ -106,35 +100,10 @@ class EnginePump:
                 if not self._q:
                     return  # stopped and drained
                 fetch, done = self._q.popleft()
-            t0 = time.perf_counter()
             try:
                 res = fetch()
             except BaseException as e:  # device failure: ship it back
                 traceback.print_exc()
                 res = e
-            self.fetch_wall_s += time.perf_counter() - t0
             self.sched.post(done, res)
 
-
-class LoopOccupancy:
-    """``pump.loop_occupancy`` gauge: scheduler-loop wall spent in the
-    pump path (dispatch + completion bookkeeping + legacy sync pumps)
-    divided by elapsed wall, over ~1 s windows.  The doctor/loadcurve
-    read it to show whether the serving thread is still monopolized by
-    the engine (≈1.0 pre-pipeline) or free for wire work."""
-
-    WINDOW_S = 1.0
-
-    def __init__(self, metrics) -> None:
-        self.m = metrics
-        self._acc = 0.0
-        self._t0 = time.monotonic()
-
-    def add(self, dt: float) -> None:
-        self._acc += dt
-        now = time.monotonic()
-        elapsed = now - self._t0
-        if elapsed >= self.WINDOW_S:
-            self.m.set("pump.loop_occupancy", min(self._acc / elapsed, 1.0))
-            self._acc = 0.0
-            self._t0 = now

@@ -35,6 +35,7 @@ from typing import Optional, Sequence
 from ..engine.core import EngineConfig
 from ..engine.firehose import MAX_FIREHOSE_ROWS
 from ..engine.host import EngineDriver
+from ..engine.instrument import count_compiles
 from ..engine.kv import BatchedKV, KVOp
 from ..porcupine.kv import OP_GET
 from .engine_durability import (
@@ -56,7 +57,7 @@ from .engine_wire import (
 )
 from ..utils.knobs import knob_bool, knob_float, knob_int
 from .admission import install_admission
-from .engine_pump import PUMP_THREAD_PREFIX, EnginePump, LoopOccupancy
+from .engine_pump import PUMP_THREAD_PREFIX, EnginePump
 from .overload import install_overload_watch
 from .wedge import install_wedge_watch
 from .realtime import (
@@ -142,7 +143,9 @@ class EngineKVService:
         self._pipe = None
         self._depth = 1
         self._pump_timer = None
-        self._occ = LoopOccupancy(self.m)
+        # perf_counter when the last pump cycle ended (after_pump
+        # returned): the next dispatch closes ``pump.gap_s`` against it.
+        self._t_cycle_end = None
         if knob_bool("MRT_ENGINE_PIPELINE"):
             loop_name = getattr(getattr(sched, "_thread", None), "name", "")
             suffix = (
@@ -231,7 +234,12 @@ class EngineKVService:
                 cp0 = time.thread_time()
                 pending = d.dispatch_ticks(self._ticks)
                 pending.t_loop_cpu = time.thread_time() - cp0
-                self._occ.add(time.perf_counter() - pending.t_dispatch)
+                if self._t_cycle_end is not None:
+                    # What the loop did between two cycles: the pump
+                    # timer's delay, frames, replies, other timers.
+                    self.m.observe(
+                        "pump.gap_s", pending.t_dispatch - self._t_cycle_end
+                    )
                 self._pipe.submit(
                     pending.fetch,
                     functools.partial(self._pump_done, pending),
@@ -257,7 +265,6 @@ class EngineKVService:
         self.kv.pump(self._ticks)
         dt = time.perf_counter() - t0
         cdt = time.thread_time() - cp0
-        self._occ.add(dt)
         self._record_pump(dt, cdt)
         self._after_pump_durability()
         self._arm_pump(self._cadence.next_delay(service_busy(self.kv)))
@@ -271,17 +278,14 @@ class EngineKVService:
         d = self.kv.driver
         if pending not in d._inflight:
             return  # already drained (final_checkpoint) or torn down
-        t0 = time.perf_counter()
         cp0 = time.thread_time()
         d.complete_ticks(pending, rec)
         self.kv.after_step(pending.n)
-        now = time.perf_counter()
         # Wall covers dispatch→completion (the client-visible pump
         # latency); CPU counts only the LOOP-side share — the split the
         # profiler uses to show the loop is no longer device-blocked.
-        dt = now - pending.t_dispatch
+        dt = time.perf_counter() - pending.t_dispatch
         cdt = (time.thread_time() - cp0) + pending.t_loop_cpu
-        self._occ.add(now - t0)
         self._record_pump(dt, cdt)
         self._after_pump_durability()
         if self._stopped:
@@ -293,10 +297,8 @@ class EngineKVService:
         self.m.observe("pump.wall_s", dt)
         # Wall-vs-CPU split: a tick whose wall ≫ CPU is device-bound
         # (the host blocked on the accelerator); wall ≈ CPU is
-        # host-bound (binding/resolution burning the loop).  The CPU
-        # side doubles as the engine stage's cost-accounting counter —
-        # the pump IS the engine stage's CPU (observe.py vocabulary).
-        self.m.observe("pump.cpu_s", cdt)
+        # host-bound (binding/resolution burning the loop).  The pump
+        # IS the engine stage's CPU (observe.py vocabulary).
         self.m.observe("cpu.engine_s", cdt)
         # Pump sequencing for the tail plane: tick id + dispatch stamp
         # (now − wall) let a committing request attribute its parked
@@ -331,11 +333,12 @@ class EngineKVService:
     def _after_pump_durability(self) -> None:
         if self._dur is not None:
             self._dur.after_pump()  # group fsync + periodic checkpoint
-            if self._write_seqs:
-                self._write_seqs = {
-                    k: v for k, v in self._write_seqs.items()
-                    if not self._dur.synced(v)
-                }
+        self._t_cycle_end = time.perf_counter()
+        if self._dur is not None and self._write_seqs:
+            self._write_seqs = {
+                k: v for k, v in self._write_seqs.items()
+                if not self._dur.synced(v)
+            }
 
     def _drain_pipeline(self) -> None:
         """Complete every in-flight batch synchronously (checkpoint /
@@ -689,8 +692,27 @@ def serve_engine_kv(
     mesh."""
     node = RpcNode(listen=True, host=host, port=port)
     sched = node.sched
+    metrics = node.obs.metrics
+    # Trace, lower and compile events by name in every scrape
+    # (engine.compiles / engine.compile_s): one inside a serving window
+    # is a stall someone has to explain.
+    count_compiles(metrics)
+    # Time to ``ready`` by stage, as gauges ``ready.<stage>_s`` set once
+    # (0.0: the stage did not run).  The first use of a program pays its
+    # compile or cache load where it falls: the 5-tick program in
+    # ``elect``, both single-tick variants and the served fused program
+    # in ``warm``.
+    ready = dict.fromkeys(
+        ("restore", "elect", "warm", "replay", "checkpoint"), 0.0
+    )
+
+    def lap(stage: str, t0: float) -> float:
+        now = time.perf_counter()
+        ready[stage] = now - t0
+        return now
 
     def build():
+        t = time.perf_counter()
         mesh = make_mesh(mesh_devices) if mesh_devices else None
         driver = None
         if data_dir:
@@ -698,11 +720,12 @@ def serve_engine_kv(
             if os.path.exists(ckpt):
                 driver = EngineDriver.restore(ckpt, mesh=mesh)
         if driver is not None:
-            node.obs.metrics.inc("engine.restores")
+            metrics.inc("engine.restores")
             kv = BatchedKV(driver, record_groups=list(record_groups or []))
             blob = driver.restored_extra.get("service")
             if blob:
                 kv.load_state_dict(blob)
+            t = lap("restore", t)
         else:
             # Shape knobs for throughput deployments (the firehose
             # bench serves G=256 at INGEST=24; defaults match the
@@ -716,6 +739,7 @@ def serve_engine_kv(
             driver = EngineDriver(cfg, seed=seed, mesh=mesh)
             kv = BatchedKV(driver, record_groups=list(record_groups or []))
             driver.run_until_quiet_leaders(2000)
+            t = lap("elect", t)
         # Warm-up BEFORE the readiness line: elect leaders and compile
         # both tick variants (quiet + loaded).  The first jit compile
         # takes tens of seconds and runs on the scheduler loop — doing
@@ -731,13 +755,13 @@ def serve_engine_kv(
         dur = (
             EngineDurability(data_dir, driver, kv,
                              checkpoint_every_s=checkpoint_every_s,
-                             metrics=node.obs.metrics)
+                             metrics=metrics)
             if data_dir else None
         )
         # Fold the driver's tick counter into the scrapeable registry
         # (tick SPANS stay gated on the diagnostic tracer below — they
         # force a device sync per tick).
-        driver.metrics = node.obs.metrics
+        driver.metrics = metrics
         if node.tracer is not None:
             driver.tracer = node.tracer  # ticks + RPCs on one timeline
         svc = EngineKVService(
@@ -746,15 +770,20 @@ def serve_engine_kv(
                 os.environ.get("MULTIRAFT_SERVE_TICKS_PER_PUMP", "2")
             ),
         )
+        t = lap("warm", t)
         if dur is not None:
             svc.replay_wal()  # recovery completes before readiness
+            t = lap("replay", t)
             # Fold the replayed state into a fresh checkpoint and
             # rotate: bounds the next recovery, and discards the
             # duplicate records the replay's own apply hooks appended.
             dur.checkpoint()
+            lap("checkpoint", t)
         return svc
 
     svc = sched.run_call(build, timeout=600.0)
+    for stage, secs in ready.items():
+        metrics.set(f"ready.{stage}_s", secs)
     node.add_service("EngineKV", svc)
     node.engine_service = svc  # keep reachable for introspection
     # Overload watch (overload.py): windowed stage-p99 + queue-gauge

@@ -37,7 +37,7 @@ from .engine_wire import (
     make_mesh,
 )
 from ..utils.knobs import knob_bool, knob_float, knob_int
-from .engine_pump import PUMP_THREAD_PREFIX, EnginePump, LoopOccupancy
+from .engine_pump import PUMP_THREAD_PREFIX, EnginePump
 from .realtime import (
     PumpCadence,
     RealtimeScheduler,
@@ -129,7 +129,6 @@ class EngineShardKVService:
         # Observability plane (see EngineKVService): the owning node's,
         # lazily defaulted via the `obs` property for stub construction.
         self._obs = obs
-        self._occ = LoopOccupancy(self.m)
         # Pump sequencing for the tail plane (see _record_pump).
         self._pumps = 0
         self._pump_t_dispatch = 0.0
@@ -771,7 +770,6 @@ class EngineShardKVService:
                 cp0 = time.thread_time()
                 pending = d.dispatch_ticks(self._ticks)
                 pending.t_loop_cpu = time.thread_time() - cp0
-                self._occ.add(time.perf_counter() - pending.t_dispatch)
                 self._pipe.submit(
                     pending.fetch,
                     functools.partial(self._pump_done, pending),
@@ -787,7 +785,6 @@ class EngineShardKVService:
         cp0 = time.thread_time()
         self.skv.pump(self._ticks)
         dt = time.perf_counter() - t0
-        self._occ.add(dt)
         self._record_pump(dt, time.thread_time() - cp0)
         self._after_pump_durability()
         self._arm_pump(self._cadence.next_delay(service_busy(self.skv)))
@@ -800,14 +797,11 @@ class EngineShardKVService:
         d = self.skv.driver
         if pending not in d._inflight:
             return  # already drained (final_checkpoint) or torn down
-        t0 = time.perf_counter()
         cp0 = time.thread_time()
         d.complete_ticks(pending, rec)
         self.skv.after_step(pending.n, orchestrate=True)
-        now = time.perf_counter()
-        self._occ.add(now - t0)
         self._record_pump(
-            now - pending.t_dispatch,
+            time.perf_counter() - pending.t_dispatch,
             (time.thread_time() - cp0) + pending.t_loop_cpu,
         )
         self._after_pump_durability()
@@ -818,7 +812,6 @@ class EngineShardKVService:
     def _record_pump(self, dt: float, cdt: float) -> None:
         self.m.inc("pump.count")
         self.m.observe("pump.wall_s", dt)
-        self.m.observe("pump.cpu_s", cdt)
         self.m.observe("cpu.engine_s", cdt)
         # Pump sequencing for the tail plane (twin of the flat engine
         # server's): tick id + dispatch stamp so a committing request

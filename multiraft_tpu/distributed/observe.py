@@ -151,6 +151,17 @@ def _rss_mb() -> Optional[float]:
 # the wrapped segments are not attributed (the sampling profiler is
 # the exact lens); the counters answer "which stage burns the loop's
 # CPU" at ~zero cost.  They ride the MRT_STAGECLOCK kill switch.
+#
+# WALL of the pump cycle and of the loop (engine/instrument.py,
+# realtime.IoScheduler): where the stage clocks follow a request, these
+# follow the two threads that serve it.  ``pump.<phase>_s`` histograms
+# (gap, dispatch, handoff, fetch, post, complete, apply, sync, with
+# ``ckpt.save_s``) tile a durable pump cycle, and ``pump.readback_bytes``
+# counts what each fetch copied; ``loop.timer_s`` / ``loop.io_s`` /
+# ``loop.idle_s`` / ``loop.polls`` are the loop thread's own cumulative
+# account, set at scrape time in ``Obs.snapshot``.  The engine modules
+# observe the phases; nothing here imports them, so a pure client node
+# still pulls in no jax.
 
 STAGES = ("wire", "dispatch", "handler", "engine", "ack", "flush", "total")
 
@@ -279,6 +290,15 @@ class ObsControl:
 
     def snapshot(self, args: Any = None) -> Dict[str, Any]:
         obs = self._node.obs
+        sched = getattr(self._node, "sched", None)
+        if hasattr(sched, "idle_s"):
+            # The loop's own account (IoScheduler): cumulative, set at
+            # scrape time, so two scrapes difference into the window's
+            # seconds in timers, in socket work and blocked in the poll.
+            obs.metrics.set("loop.timer_s", sched.timer_s)
+            obs.metrics.set("loop.io_s", sched.io_s)
+            obs.metrics.set("loop.idle_s", sched.idle_s)
+            obs.metrics.set("loop.polls", float(sched.polls))
         out: Dict[str, Any] = {
             "name": obs.name,
             "pid": os.getpid(),

@@ -478,12 +478,49 @@ def _step_down(
 # ---------------------------------------------------------------------------
 
 
+class _PhaseScope:
+    """``jax.named_scope`` for the consecutive phases of one long
+    function without indenting them: calling it leaves the phase before
+    and enters the named one, so every operation traced in between
+    carries the phase in its metadata (``op_name`` in the HLO, ``tf_op``
+    in a device trace).  Metadata only: the compiled program and the
+    compile-cache key are the same with and without."""
+
+    def __init__(self) -> None:
+        self._open = None
+
+    def __call__(self, name: str) -> None:
+        self.close()
+        self._open = jax.named_scope(name)
+        self._open.__enter__()
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
 def tick_impl(
     cfg: EngineConfig,
     state: EngineState,
     inbox: Mailbox,
     new_cmds: jnp.ndarray,  # i32[G]: Start() firehose, appended at leaders
     key: jax.Array,
+) -> Tuple[EngineState, Mailbox, Dict[str, jnp.ndarray]]:
+    scope = _PhaseScope()
+    try:
+        return _tick_phases(cfg, state, inbox, new_cmds, key, scope)
+    finally:
+        scope.close()  # a trace that raised leaves no scope behind
+
+
+def _tick_phases(
+    cfg: EngineConfig,
+    state: EngineState,
+    inbox: Mailbox,
+    new_cmds: jnp.ndarray,
+    key: jax.Array,
+    scope: _PhaseScope,
 ) -> Tuple[EngineState, Mailbox, Dict[str, jnp.ndarray]]:
     G, P, L, E = cfg.G, cfg.P, cfg.L, cfg.E
     out = empty_mailbox(cfg)
@@ -502,6 +539,7 @@ def tick_impl(
         cfg.ELECT_MIN, cfg.ELECT_MAX, dtype=jnp.int32,
     )
 
+    scope("tick.1_votes")
     # ---- 1. vote requests (reference: raft/raft_election.go:54-77) ----
     # All candidates arbitrated in ONE pass (fused r04: the per-src
     # loop emitted P dependent kernel chains; the roofline showed the
@@ -580,6 +618,7 @@ def tick_impl(
         vp_granted=jnp.where(pre_act, grant_pre, grant),
     )
 
+    scope("tick.2_tally")
     # ---- 2. vote replies → tally → leadership
     # (reference: raft/raft_election.go:27-49) ----
     # Replies commute: the tally is an OR per voter slot and step-down
@@ -721,6 +760,7 @@ def tick_impl(
             )
         )
 
+    scope("tick.3_append")
     # ---- 3. append requests (reference: raft/raft_append_entry.go:108-162) ----
     # One arbitrated pass (fused r04).  Distinct leaders always carry
     # distinct terms (election safety — a replica's appends all carry
@@ -889,6 +929,7 @@ def tick_impl(
         ap_conflict=conflict_all,
     )
 
+    scope("tick.4_replies_commit")
     # ---- 4. append replies + quorum commit advance
     # (reference: raft/raft_append_entry.go:66-105 — the north-star) ----
     # Replies commute: each src's reply touches only its own
@@ -988,6 +1029,7 @@ def tick_impl(
         )
     state = state._replace(commit=new_commit)
 
+    scope("tick.4b_check_quorum")
     # ---- 4b. check-quorum: quorum-severed leaders release their
     # groups (etcd CheckQuorum analog; beyond the reference) ----
     if cfg.check_quorum:
@@ -1022,6 +1064,7 @@ def tick_impl(
             elect_dl=jnp.where(demote, now + jitter, state.elect_dl)
         )
 
+    scope("tick.4c_membership")
     # ---- 4c. membership: a leader removed by a COMPLETED config
     # change steps down once the removing entry commits (Raft thesis
     # §4.2.2: it keeps leading — and committing — up to that point) ----
@@ -1043,6 +1086,7 @@ def tick_impl(
             elect_dl=jnp.where(removed, now + jitter, state.elect_dl)
         )
 
+    scope("tick.5_timers")
     # ---- 5. timers: elections (reference: raft/raft.go:106-125) ----
     timeout = state.alive & (now >= state.elect_dl) & (state.role != LEADER)
     if cfg.membership_on:
@@ -1093,6 +1137,7 @@ def tick_impl(
         vr_pre=jnp.broadcast_to(send_pre[:, :, None], (G, P, P)) & vr_act,
     )
 
+    scope("tick.5a_membership")
     # ---- 5a-bis. membership: joint auto-exit.  A leader whose
     # C_old,new entry has COMMITTED appends the C_new exit entry
     # in-tick (no host round-trip in the transition's critical path)
@@ -1129,6 +1174,7 @@ def tick_impl(
             cfg_idx=jnp.where(can_exit, exit_idx, state.cfg_idx),
         )
 
+    scope("tick.5b_ingest")
     # ---- 5b. Start() ingestion: leaders append the firehose ----
     # Only the leader at the group's max alive term ingests: a zombie
     # leader (older term, still alive under message loss) can never
@@ -1158,6 +1204,7 @@ def tick_impl(
     accepted_per_group = jnp.sum(accept, axis=1)  # i32[G]
     start_index = jnp.sum(jnp.where(accept > 0, last_idx, 0), axis=1)
 
+    scope("tick.5c_sends")
     # ---- 5c. append sends: heartbeat + lag repair
     # (reference: raft/raft_append_entry.go:4-65; heartbeats are full
     # appends carrying missing suffix) ----
@@ -1233,6 +1280,7 @@ def tick_impl(
         next_idx=jnp.where(send, state.next_idx + n_send, state.next_idx),
     )
 
+    scope("tick.6_apply_compact")
     # ---- 6. apply frontier + ring compaction ----
     if not cfg.host_paced_compaction:
         state = state._replace(
@@ -1254,6 +1302,7 @@ def tick_impl(
 
     state = state._replace(tick_no=now)
 
+    scope("tick.7_metrics")
     leader_commit_delta = jnp.where(
         (state.role == LEADER) & state.alive,
         state.commit - commit_before,

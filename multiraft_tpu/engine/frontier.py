@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from .host import EngineDriver, PayloadSlice
+from .instrument import pump_phase
 
 __all__ = ["FrontierService"]
 
@@ -96,6 +97,10 @@ class FrontierService:
         engine advance happened on dispatch), the synchronous path via
         :meth:`pump`.  Requires ``driver.last_metrics`` to reflect the
         ticks being accounted for."""
+        with pump_phase(self.driver.metrics, "apply"):
+            self._sweep_frontier(n_ticks)
+
+    def _sweep_frontier(self, n_ticks: int) -> None:
         self._pre_sweep()
         commit = np.asarray(self.driver.last_metrics["commit_index"])
         now = self.driver.tick

@@ -40,6 +40,7 @@ from .core import (
     init_state,
     tick,
 )
+from .instrument import pump_phase
 
 __all__ = [
     "EngineDriver",
@@ -850,39 +851,43 @@ class EngineDriver:
 
         cfg = self.cfg
         t_dispatch = time.perf_counter()
-        self.metrics.inc("ticks", n)
-        tick0 = self.tick
-        bl = jnp.asarray(
-            np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
-        )
-        for p in self._inflight:
-            # Batches already dispatched will consume part of the host
-            # backlog when they complete; the device must not ingest
-            # those commands again (the depth ≥ 2 double-ingest hazard).
-            # accepts_dev never left the device, so this stays async.
-            bl = jnp.maximum(bl - p.accepts_dev, 0)
-        with_drop = self.drop_prob > 0.0
-        with_edges = not bool(self.edge_up.all())
-        if with_edges:
-            if self._edge_dev is None:
-                # copy=True: see _mask_partitions.
-                self._edge_dev = jnp.array(self.edge_up, copy=True)
-            edge_mask = self._edge_dev
-        else:
-            edge_mask = jnp.zeros((), jnp.bool_)  # static-dead operand
-        state, inbox, _bl_left, rec = step_ticks(
-            cfg, self.state, self.inbox, n, with_drop, with_edges,
-            bl, jnp.float32(self.drop_prob), edge_mask,
-            jnp.int32(tick0), self.key,
-        )
-        self.state, self.inbox = state, inbox
-        self.tick = tick0 + n
-        pending = PendingTicks(
-            n=n, tick0=tick0, rec=rec,
-            accepts_dev=jnp.sum(rec["accepted"], axis=0),
-            t_dispatch=t_dispatch,
-        )
-        self._inflight.append(pending)  # graftlint: disable=unbounded-queue
+        with pump_phase(self.metrics, "dispatch"):
+            self.metrics.inc("ticks", n)
+            tick0 = self.tick
+            bl = jnp.asarray(
+                np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
+            )
+            for p in self._inflight:
+                # Batches already dispatched will consume part of the
+                # host backlog when they complete; the device must not
+                # ingest those commands again (the depth ≥ 2
+                # double-ingest hazard).  accepts_dev never left the
+                # device, so this stays async.
+                bl = jnp.maximum(bl - p.accepts_dev, 0)
+            with_drop = self.drop_prob > 0.0
+            with_edges = not bool(self.edge_up.all())
+            if with_edges:
+                if self._edge_dev is None:
+                    # copy=True: see _mask_partitions.
+                    self._edge_dev = jnp.array(self.edge_up, copy=True)
+                edge_mask = self._edge_dev
+            else:
+                edge_mask = jnp.zeros((), jnp.bool_)  # static-dead operand
+            state, inbox, _bl_left, rec = step_ticks(
+                cfg, self.state, self.inbox, n, with_drop, with_edges,
+                bl, jnp.float32(self.drop_prob), edge_mask,
+                jnp.int32(tick0), self.key,
+            )
+            self.state, self.inbox = state, inbox
+            self.tick = tick0 + n
+            pending = PendingTicks(
+                n=n, tick0=tick0, rec=rec,
+                accepts_dev=jnp.sum(rec["accepted"], axis=0),
+                t_dispatch=t_dispatch,
+                pump=self.metrics.counters.get("pump.count", 0),
+            )
+            self._inflight.append(pending)  # graftlint: disable=unbounded-queue
+        pending.t_dispatched = time.perf_counter()
         return pending
 
     def complete_ticks(self, pending, host_rec) -> Dict[str, Any]:
@@ -895,25 +900,36 @@ class EngineDriver:
         assert self._inflight and self._inflight[0] is pending, (
             "complete_ticks out of dispatch order"
         )
-        self._inflight.pop(0)
-        accepted = host_rec["accepted"]  # i32[n, G]
-        starts = host_rec["start_index"]
-        terms = host_rec["accept_term"] if self.on_payload_bound else None
-        # np.nonzero on [n, G] is row-major: tick-major, group-minor —
-        # exactly the serial loop's binding order.
-        for i, g in zip(*np.nonzero(accepted)):
-            k = int(accepted[i, g])
-            self.backlog[g] -= k
-            self._bind_accepted(
-                int(g), k, int(starts[i, g]),
-                int(terms[i, g]) if terms is not None else None,
+        # The fetch ran on the pump thread, which only stamps the batch:
+        # the registry is written from this (the owning) thread alone.
+        m = self.metrics
+        m.observe("pump.handoff_s", pending.t_fetch - pending.t_dispatched)
+        m.observe("pump.fetch_s", pending.t_fetched - pending.t_fetch)
+        m.observe("pump.post_s", time.perf_counter() - pending.t_fetched)
+        m.inc("pump.readback_bytes", pending.nbytes)
+        with pump_phase(m, "complete"):
+            self._inflight.pop(0)
+            accepted = host_rec["accepted"]  # i32[n, G]
+            starts = host_rec["start_index"]
+            terms = (
+                host_rec["accept_term"] if self.on_payload_bound else None
             )
-        self._commits_dev = (
-            getattr(self, "_commits_dev", 0) + int(host_rec["commits"].sum())
-        )
-        self.last_metrics = {k: v[-1] for k, v in host_rec.items()}
-        if self.tracer:
-            self._emit_tick_spans(pending, host_rec)
+            # np.nonzero on [n, G] is row-major: tick-major, group-minor —
+            # exactly the serial loop's binding order.
+            for i, g in zip(*np.nonzero(accepted)):
+                k = int(accepted[i, g])
+                self.backlog[g] -= k
+                self._bind_accepted(
+                    int(g), k, int(starts[i, g]),
+                    int(terms[i, g]) if terms is not None else None,
+                )
+            self._commits_dev = (
+                getattr(self, "_commits_dev", 0)
+                + int(host_rec["commits"].sum())
+            )
+            self.last_metrics = {k: v[-1] for k, v in host_rec.items()}
+            if self.tracer:
+                self._emit_tick_spans(pending, host_rec)
         return self.last_metrics
 
     def _emit_tick_spans(self, pending, rec) -> None:

@@ -42,6 +42,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .core import EngineConfig, EngineState, Mailbox, tick_impl
 from .host import apply_faults, mask_active
@@ -112,6 +113,7 @@ class PendingTicks:
 
     __slots__ = (
         "n", "tick0", "rec", "accepts_dev", "t_dispatch", "t_loop_cpu",
+        "pump", "t_dispatched", "t_fetch", "t_fetched", "nbytes",
     )
 
     def __init__(
@@ -121,12 +123,14 @@ class PendingTicks:
         rec: Dict[str, jnp.ndarray],
         accepts_dev: jnp.ndarray,
         t_dispatch: float,
+        pump: int = 0,
     ) -> None:
         self.n = n
         self.tick0 = tick0
         self.rec = rec
         self.accepts_dev = accepts_dev
         self.t_dispatch = t_dispatch
+        self.pump = pump  # pumps completed at dispatch: the trace tag
         # Loop-side CPU the dispatch burned (the serving loop's share
         # of this pump; completion adds its own) — set by the caller.
         self.t_loop_cpu = 0.0
@@ -134,8 +138,14 @@ class PendingTicks:
     def fetch(self) -> Dict[str, np.ndarray]:
         """Block until the batch's stacked metrics are host-resident.
         Pure device wait + copy: touches no driver state, so it is
-        safe off the scheduler loop by construction."""
-        return {k: np.asarray(v) for k, v in self.rec.items()}
-
-    def _replace_wall(self, t: float) -> None:  # pragma: no cover - tests
-        self.t_dispatch = t
+        safe off the scheduler loop by construction.  It stamps its
+        own entry, return and the bytes it brought over on the batch;
+        ``complete_ticks`` turns those into ``pump.handoff_s`` (since
+        ``dispatch_ticks`` returned), ``pump.fetch_s``, ``pump.post_s``
+        and ``pump.readback_bytes`` on the loop."""
+        self.t_fetch = time.perf_counter()
+        with TraceAnnotation("mrt.pump.fetch", pump=self.pump):
+            out = {k: np.asarray(v) for k, v in self.rec.items()}
+        self.nbytes = sum(v.nbytes for v in out.values())
+        self.t_fetched = time.perf_counter()
+        return out
