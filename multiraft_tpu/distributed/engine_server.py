@@ -38,6 +38,7 @@ from ..engine.host import EngineDriver
 from ..engine.instrument import count_compiles
 from ..engine.kv import BatchedKV, KVOp
 from ..porcupine.kv import OP_GET
+from ..sim.scheduler import TIMEOUT, Future
 from .engine_durability import (
     EngineDurability,
     await_frame_synced,
@@ -146,6 +147,9 @@ class EngineKVService:
         # perf_counter when the last pump cycle ended (after_pump
         # returned): the next dispatch closes ``pump.gap_s`` against it.
         self._t_cycle_end = None
+        # Resolved when the pump cycle in progress ends: what a parked
+        # ``command`` handler waits on (``_await_pump_end``).
+        self._cycle_end = Future()
         if knob_bool("MRT_ENGINE_PIPELINE"):
             loop_name = getattr(getattr(sched, "_thread", None), "name", "")
             suffix = (
@@ -339,6 +343,28 @@ class EngineKVService:
                 k: v for k, v in self._write_seqs.items()
                 if not self._dur.synced(v)
             }
+        # Tickets, failures and the WAL's synced frontier change here
+        # and nowhere else, so this is where parked handlers look again:
+        # each takes one step inline, after the stamp, so the wake-up is
+        # inside ``pump.gap_s``.  The fresh future goes in first: a
+        # handler that parks again waits for the NEXT cycle's end.
+        ended, self._cycle_end = self._cycle_end, Future()
+        ended.resolve()
+
+    def _await_pump_end(self, until: float):
+        """Park the calling handler (``yield from``) until the pump
+        cycle in progress ends, or until ``until`` on the scheduler's
+        clock if no pump ends first (a stalled pump; shutdown's drain,
+        which completes ticks without the hook).  False, and no wait,
+        once ``until`` has passed."""
+        left = until - self.sched.now
+        if left <= 0:
+            return False
+        woke = yield self.sched.with_timeout(self._cycle_end, left)
+        self.m.inc("kv.wait_steps")
+        if woke is TIMEOUT:
+            self.m.inc("kv.wait_timeouts")
+        return True
 
     def _drain_pipeline(self) -> None:
         """Complete every in-flight batch synchronously (checkpoint /
@@ -601,8 +627,9 @@ class EngineKVService:
                 sub_deadline = min(
                     self.sched.now + self.RESUBMIT_S, deadline
                 )
-                while not t.done and self.sched.now < sub_deadline:
-                    yield 0.002
+                while not t.done:
+                    if not (yield from self._await_pump_end(sub_deadline)):
+                        break
                 if t.done and not t.failed:
                     if stages is not None:
                         # Commit observed: submit → raft quorum +
@@ -624,13 +651,17 @@ class EngineKVService:
                     # Ack only once the apply-time WAL record is
                     # fsynced (absent = pruned = already durable, or
                     # a duplicate applied before this incarnation).
+                    # Checked at every pump end, where the group
+                    # fsync lands; at the deadline the write answers
+                    # ErrTimeout, never a false durable ack.
                     while self._dur is not None:
                         seq = self._write_seqs.get(
                             (args.client_id, args.command_id)
                         )
                         if seq is None or self._dur.synced(seq):
                             break
-                        yield 0.002
+                        if not (yield from self._await_pump_end(deadline)):
+                            return EngineCmdReply(err=ERR_TIMEOUT)
                     self.m.observe(
                         "kv.command_s", self.sched.now - t_start
                     )
