@@ -25,8 +25,10 @@ anything but a TPU fails the run.  Legs, in order:
             child; this process, over real sockets, loads 100,000 keys
             of ~100 B, reads a sample back against a dict model, runs
             concurrent Append/Get clerks on shared keys and checks the
-            history with porcupine, then ``kill -9``, restart on the
-            same --data-dir, every sampled acknowledged write read back
+            history with porcupine, checks that the server took the
+            fused pump (``pump.fetch_s`` has samples: a silent fall
+            back to the synchronous pump fails), then ``kill -9``,
+            restart on the same --data-dir, every sampled acknowledged write read back
             exactly once, and a SIGTERM that must exit 0.
 ``mesh4``   the sharded tick on a 4-device ``groups`` mesh and the same
             served path with ``--mesh-devices 4``.  Runs when the
@@ -545,8 +547,16 @@ def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
                 after.get("ticks", 0) > before.get("ticks", 0),
                 f"{name}: ticks did not advance",
             )
+            # The fused, asynchronous pump is what a server runs, on one
+            # chip or on a mesh: only it fetches on the pump thread.
+            require(
+                after.get("pump.fetch_s_count", 0) > 0,
+                f"{name}: no pump.fetch_s sample: the server fell back "
+                f"to the synchronous pump",
+            )
             say(
                 f"{name}: pump.count={after['pump.count']} "
+                f"pump.fetch_s_count={after['pump.fetch_s_count']} "
                 f"ticks {before.get('ticks', 0)} -> {after['ticks']} "
                 f"wal.fsyncs={after.get('wal.fsyncs', 'n/a')}"
             )

@@ -138,7 +138,9 @@ class EngineKVService:
         # dispatches fused tick batches and completes them when the
         # dedicated pump thread has fetched the stacked metrics; the
         # legacy synchronous pump stays selectable per pump (kill
-        # switch, mesh drivers, reorder chaos).  Durable servers pin
+        # switch, reorder chaos).  A mesh driver pipelines like a
+        # one-chip one: the batch is one program over its devices and
+        # the fetch reads each chip's shard back.  Durable servers pin
         # the depth to 1 so each checkpoint sees a drained pipeline
         # (EngineDriver.save refuses otherwise).
         self._pipe = None
@@ -253,9 +255,9 @@ class EngineKVService:
         self._pump_sync()
 
     def _pump_sync(self) -> None:
-        """Legacy synchronous pump (MRT_ENGINE_PIPELINE=0, mesh
-        drivers, reorder chaos in flight): the whole device step runs
-        on the loop thread."""
+        """Legacy synchronous pump (MRT_ENGINE_PIPELINE=0, reorder
+        chaos in flight): the whole device step runs on the loop
+        thread."""
         # About to grind for up to several milliseconds: push any
         # queued replies onto the wire first, or a client whose op
         # resolved last tick waits out this whole one before it can
@@ -717,10 +719,11 @@ def serve_engine_kv(
     — a kill -9'd process restarted on the same dir recovers every
     acknowledged write.
 
-    With ``mesh_devices`` > 0, the engine runs the shard_map tick over
-    that many local chips (G must divide evenly) — the multi-chip
-    production path; checkpoints restore back onto the same-size
-    mesh."""
+    With ``mesh_devices`` > 0, the engine's groups are sharded over
+    that many local chips (G must divide evenly) and the same fused,
+    asynchronous pump runs one ``shard_map`` program over them — the
+    multi-chip production path; checkpoints restore back onto the
+    same-size mesh.  Gauge ``engine.mesh_devices`` says how many."""
     node = RpcNode(listen=True, host=host, port=port)
     sched = node.sched
     metrics = node.obs.metrics
@@ -815,6 +818,7 @@ def serve_engine_kv(
     svc = sched.run_call(build, timeout=600.0)
     for stage, secs in ready.items():
         metrics.set(f"ready.{stage}_s", secs)
+    metrics.set("engine.mesh_devices", float(mesh_devices))
     node.add_service("EngineKV", svc)
     node.engine_service = svc  # keep reachable for introspection
     # Overload watch (overload.py): windowed stage-p99 + queue-gauge

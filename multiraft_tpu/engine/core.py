@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -506,12 +506,33 @@ def tick_impl(
     inbox: Mailbox,
     new_cmds: jnp.ndarray,  # i32[G]: Start() firehose, appended at leaders
     key: jax.Array,
+    shard: Optional[Tuple[int, jnp.ndarray]] = None,
 ) -> Tuple[EngineState, Mailbox, Dict[str, jnp.ndarray]]:
+    """``shard``: set by the ``shard_map`` callers (engine/mesh.py),
+    whose ``cfg.G`` is one device's share of the groups — see
+    :func:`shard_rows`."""
     scope = _PhaseScope()
     try:
-        return _tick_phases(cfg, state, inbox, new_cmds, key, scope)
+        return _tick_phases(cfg, state, inbox, new_cmds, key, scope, shard)
     finally:
         scope.close()  # a trace that raised leaves no scope behind
+
+
+def shard_rows(draw, G: int, shard: Optional[Tuple[int, jnp.ndarray]]):
+    """``draw(rows)``, a per-group random draw with leading axis
+    ``rows``, for the ``G`` groups of this program.  Unsharded
+    (``shard`` None) that is ``draw(G)``.  Under ``shard_map`` every
+    device holds the same key, so ``draw(G)`` would hand each device's
+    groups the draw of groups ``0..G-1``; ``shard = (G_total, row0)``
+    takes rows ``row0..row0+G`` of the whole driver's draw instead, and
+    a sharded run is bit-equal to the unsharded one.  The price is the
+    whole draw on every device (the TPU compiler does not push the
+    slice into the counter-based generator): elementwise work on
+    ``G_total`` rows, small beside a tick."""
+    if shard is None:
+        return draw(G)
+    total, row0 = shard
+    return jax.lax.dynamic_slice_in_dim(draw(total), row0, G)
 
 
 def _tick_phases(
@@ -521,6 +542,7 @@ def _tick_phases(
     new_cmds: jnp.ndarray,
     key: jax.Array,
     scope: _PhaseScope,
+    shard: Optional[Tuple[int, jnp.ndarray]] = None,
 ) -> Tuple[EngineState, Mailbox, Dict[str, jnp.ndarray]]:
     G, P, L, E = cfg.G, cfg.P, cfg.L, cfg.E
     out = empty_mailbox(cfg)
@@ -534,9 +556,12 @@ def _tick_phases(
     # single tick the resets are interchangeable — cross-tick
     # desynchronization (what liveness needs) comes from folding the
     # key per tick.
-    jitter = jax.random.randint(
-        jax.random.fold_in(key, 7), (G, P),
-        cfg.ELECT_MIN, cfg.ELECT_MAX, dtype=jnp.int32,
+    jitter = shard_rows(
+        lambda rows: jax.random.randint(
+            jax.random.fold_in(key, 7), (rows, P),
+            cfg.ELECT_MIN, cfg.ELECT_MAX, dtype=jnp.int32,
+        ),
+        G, shard,
     )
 
     scope("tick.1_votes")

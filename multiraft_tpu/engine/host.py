@@ -38,6 +38,7 @@ from .core import (
     Mailbox,
     empty_mailbox,
     init_state,
+    shard_rows,
     tick,
 )
 from .instrument import pump_phase
@@ -47,6 +48,7 @@ __all__ = [
     "PayloadRun",
     "PayloadSlice",
     "apply_faults",
+    "drop_messages",
     "mask_active",
 ]
 
@@ -120,22 +122,46 @@ def mask_active(mb: Mailbox, fn) -> Mailbox:
     return mb._replace(**{k: fn(k, getattr(mb, k)) for k in _ACTIVE_FIELDS})
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def apply_faults(
-    mailbox: Mailbox, key: jax.Array, drop_prob: jnp.ndarray, cfg: EngineConfig
+def drop_messages(
+    mailbox: Mailbox, key: jax.Array, drop_prob: jnp.ndarray, cfg: EngineConfig,
+    shard=None,
 ) -> Mailbox:
     """Drop each in-flight message independently with ``drop_prob`` —
     the dense-tensor form of labrpc's unreliable mode
     (reference: labrpc/labrpc.go:228-239,279-284; request and reply
-    drops both land here because each direction is its own edge-slot)."""
-    shape = (cfg.G, cfg.P, cfg.P)
+    drops both land here because each direction is its own edge-slot).
+    ``shard``: as for ``tick_impl`` (``core.shard_rows``), so under
+    ``shard_map`` a device's groups lose what they lose unsharded."""
     keys = jax.random.split(key, len(_ACTIVE_FIELDS))
 
     def drop(name, a):
         k = keys[_ACTIVE_FIELDS.index(name)]
-        return a & (jax.random.uniform(k, shape) >= drop_prob)
+        u = shard_rows(
+            lambda rows: jax.random.uniform(k, (rows, cfg.P, cfg.P)),
+            cfg.G, shard,
+        )
+        return a & (u >= drop_prob)
 
     return mask_active(mailbox, drop)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def apply_faults(
+    mailbox: Mailbox, key: jax.Array, drop_prob: jnp.ndarray, cfg: EngineConfig
+) -> Mailbox:
+    """:func:`drop_messages` as a program of its own (the serial loop)."""
+    return drop_messages(mailbox, key, drop_prob, cfg)
+
+
+def _fold_lanes(metrics: Dict[str, Any], xp, axis: int) -> Dict[str, Any]:
+    """A mesh driver's scalar metrics arrive as per-device lanes (the
+    zero-collective contract, engine/mesh.py: no ``psum`` on the
+    device): fold ``axis`` into the scalars every host-side reader
+    expects, with ``xp`` = ``jnp`` (still on the devices) or ``np``."""
+    out = dict(metrics)
+    for k in SCALAR_METRIC_KEYS:
+        out[k] = (xp.max if k == "max_term" else xp.sum)(out[k], axis=axis)
+    return out
 
 
 class EngineDriver:
@@ -152,26 +178,34 @@ class EngineDriver:
         self.state: EngineState = init_state(cfg, jax.random.fold_in(self.key, 0))
         self.inbox: Mailbox = empty_mailbox(cfg)
         if mesh is not None:
-            from .mesh import (
-                assert_zero_collectives,
-                make_sharded_tick,
-                shard_arrays,
-            )
-
-            self.mesh = mesh
-            self.state = shard_arrays(cfg, mesh, self.state)
-            self.inbox = shard_arrays(cfg, mesh, self.inbox)
-            self._mesh_tick = make_sharded_tick(cfg, mesh)
+            self._use_mesh(mesh)
             if check_zero_collectives:
-                import jax.numpy as _jnp
+                from .mesh import assert_zero_collectives
 
                 assert_zero_collectives(
                     self._mesh_tick,
                     self.state,
                     self.inbox,
-                    _jnp.zeros(cfg.G, _jnp.int32),
+                    jnp.zeros(cfg.G, jnp.int32),
                     self.key,
                 )
+
+    def _use_mesh(self, mesh) -> None:
+        """Spread state, mailbox and (replicated) key over ``mesh``."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from .mesh import make_sharded_tick, shard_arrays
+
+        self.mesh = mesh
+        self.state = shard_arrays(self.cfg, mesh, self.state)
+        self.inbox = shard_arrays(self.cfg, mesh, self.inbox)
+        self._mesh_tick = make_sharded_tick(self.cfg, mesh)
+        self._groups_sharding = NamedSharding(mesh, PartitionSpec("groups"))
+        self._n_shards = int(mesh.devices.size)
+        # Every device reads the key every tick: put it there once.
+        self.key = jax.device_put(
+            self.key, NamedSharding(mesh, PartitionSpec())
+        )
 
     def _init_host(self, cfg: EngineConfig, seed: int) -> None:
         """Host-side bookkeeping shared by __init__ and restore() —
@@ -215,6 +249,8 @@ class EngineDriver:
         self.last_metrics: Dict[str, Any] = {}
         self.mesh = None
         self._mesh_tick = None
+        self._groups_sharding = None  # the [G] vectors' sharding, on a mesh
+        self._n_shards = 1
         # Structured counters (utils/metrics.py): ticks always; per-tick
         # wall latency samples when the tracer (diagnostic mode) is on.
         self.metrics = Metrics()
@@ -282,12 +318,19 @@ class EngineDriver:
                 it for it in self._delayed if self.edge_up[it[2]]
             ]
 
-    def _mask_partitions(self, mb: Mailbox) -> Mailbox:
+    def _edge_mask(self) -> jnp.ndarray:
+        """``edge_up`` on the device(s), made once per change of it."""
         if self._edge_dev is None:
             # copy=True: zero-copy would alias the mutable edge_up
             # numpy mask into an async dispatch (see restore below).
-            self._edge_dev = jnp.array(self.edge_up, copy=True)
-        m = self._edge_dev
+            m = jnp.array(self.edge_up, copy=True)
+            if self.mesh is not None:
+                m = jax.device_put(m, self._groups_sharding)
+            self._edge_dev = m
+        return self._edge_dev
+
+    def _mask_partitions(self, mb: Mailbox) -> Mailbox:
+        m = self._edge_mask()
         return mask_active(mb, lambda _, a: a & m)
 
     def set_reorder(
@@ -722,7 +765,7 @@ class EngineDriver:
         """Advance ``n`` ticks.  Multi-tick calls on a pipeline-enabled
         driver run the fused device scan (engine/pipeline.py — one host
         sync per call instead of one per tick); everything else —
-        single ticks, mesh drivers, reorder chaos in flight,
+        single ticks, reorder chaos in flight,
         ``MRT_ENGINE_PIPELINE=0`` — takes the serial per-tick loop.
         Both paths are bit-identical by contract
         (tests/test_engine_pipeline.py).
@@ -741,13 +784,12 @@ class EngineDriver:
         return self._step_serial(n)
 
     def fused_eligible(self) -> bool:
-        """True when the fused scan path may run: pipeline enabled, no
-        mesh tick (its scalar metrics arrive as per-device lanes), and
+        """True when the fused scan path may run: pipeline enabled and
         no reorder chaos active or held (``_apply_reorder`` rewrites
-        the mailbox on host between ticks — inherently unfusable)."""
+        the mailbox on host between ticks — inherently unfusable).  A
+        mesh driver fuses like any other (``sharded_step_ticks``)."""
         return (
             self._pipeline_on
-            and self._mesh_tick is None
             and self.reorder_prob == 0.0
             and not self._delayed
         )
@@ -771,13 +813,7 @@ class EngineDriver:
                 state, outbox, metrics = self._mesh_tick(
                     self.state, self.inbox, new_cmds, tick_key
                 )
-                # Scalar metrics arrive as per-device lanes (the
-                # zero-collective contract, engine/mesh.py): sum to the
-                # scalars the host-side consumers expect.
-                metrics = dict(metrics)
-                for k in SCALAR_METRIC_KEYS:
-                    red = jnp.max if k == "max_term" else jnp.sum
-                    metrics[k] = red(metrics[k])
+                metrics = _fold_lanes(metrics, jnp, 0)
             else:
                 state, outbox, metrics = tick(
                     cfg, self.state, self.inbox, new_cmds, tick_key
@@ -847,37 +883,48 @@ class EngineDriver:
         payload binding and backlog bookkeeping are deferred to
         :meth:`complete_ticks` once the stacked metrics are fetched
         (``PendingTicks.fetch``, safe off-thread)."""
-        from .pipeline import PendingTicks, step_ticks
+        from .pipeline import PendingTicks, sharded_step_ticks, step_ticks
 
         cfg = self.cfg
         t_dispatch = time.perf_counter()
         with pump_phase(self.metrics, "dispatch"):
             self.metrics.inc("ticks", n)
             tick0 = self.tick
-            bl = jnp.asarray(
-                np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
-            )
+            bl = np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
+            if self.mesh is None:
+                bl = jnp.asarray(bl)
+            else:
+                bl = jax.device_put(bl, self._groups_sharding)
             for p in self._inflight:
                 # Batches already dispatched will consume part of the
                 # host backlog when they complete; the device must not
                 # ingest those commands again (the depth ≥ 2
                 # double-ingest hazard).  accepts_dev never left the
-                # device, so this stays async.
+                # device (on a mesh: its shards never left theirs), so
+                # this stays async.
                 bl = jnp.maximum(bl - p.accepts_dev, 0)
             with_drop = self.drop_prob > 0.0
             with_edges = not bool(self.edge_up.all())
-            if with_edges:
-                if self._edge_dev is None:
-                    # copy=True: see _mask_partitions.
-                    self._edge_dev = jnp.array(self.edge_up, copy=True)
-                edge_mask = self._edge_dev
+            edge_mask = self._edge_mask() if with_edges else None
+            if self.mesh is None:
+                if edge_mask is None:
+                    edge_mask = jnp.zeros((), jnp.bool_)  # static-dead operand
+                state, inbox, _bl_left, rec = step_ticks(
+                    cfg, self.state, self.inbox, n, with_drop, with_edges,
+                    bl, jnp.float32(self.drop_prob), edge_mask,
+                    jnp.int32(tick0), self.key,
+                )
             else:
-                edge_mask = jnp.zeros((), jnp.bool_)  # static-dead operand
-            state, inbox, _bl_left, rec = step_ticks(
-                cfg, self.state, self.inbox, n, with_drop, with_edges,
-                bl, jnp.float32(self.drop_prob), edge_mask,
-                jnp.int32(tick0), self.key,
-            )
+                # The same scan, each device on its groups; the scalars
+                # go as numpy so no program runs to make them.
+                if edge_mask is None:
+                    edge_mask = np.zeros((), np.bool_)
+                state, inbox, _bl_left, rec = sharded_step_ticks(
+                    cfg, self.mesh, n, with_drop, with_edges
+                )(
+                    self.state, self.inbox, bl, np.float32(self.drop_prob),
+                    edge_mask, np.int32(tick0), self.key,
+                )
             self.state, self.inbox = state, inbox
             self.tick = tick0 + n
             pending = PendingTicks(
@@ -885,6 +932,7 @@ class EngineDriver:
                 accepts_dev=jnp.sum(rec["accepted"], axis=0),
                 t_dispatch=t_dispatch,
                 pump=self.metrics.counters.get("pump.count", 0),
+                shards=self._n_shards,
             )
             self._inflight.append(pending)  # graftlint: disable=unbounded-queue
         pending.t_dispatched = time.perf_counter()
@@ -907,8 +955,11 @@ class EngineDriver:
         m.observe("pump.fetch_s", pending.t_fetched - pending.t_fetch)
         m.observe("pump.post_s", time.perf_counter() - pending.t_fetched)
         m.inc("pump.readback_bytes", pending.nbytes)
+        m.inc("pump.readback_copies", pending.ncopies)
         with pump_phase(m, "complete"):
             self._inflight.pop(0)
+            if self.mesh is not None:
+                host_rec = _fold_lanes(host_rec, np, 1)  # [n_ticks, n_devices]
             accepted = host_rec["accepted"]  # i32[n, G]
             starts = host_rec["start_index"]
             terms = (
@@ -1105,15 +1156,10 @@ class EngineDriver:
         d.inbox = Mailbox(
             **{k: jnp.array(v, copy=True) for k, v in blob["inbox"].items()}
         )
-        if mesh is not None:
-            from .mesh import make_sharded_tick, shard_arrays
-
-            d.mesh = mesh
-            d.state = shard_arrays(d.cfg, mesh, d.state)
-            d.inbox = shard_arrays(d.cfg, mesh, d.inbox)
-            d._mesh_tick = make_sharded_tick(d.cfg, mesh)
         d.tick = blob["tick"]
         d.key = jnp.array(blob["key"], copy=True)
+        if mesh is not None:
+            d._use_mesh(mesh)
         d.backlog = blob["backlog"]
         d.payloads = blob["payloads"]
         d._pending_payloads = defaultdict(list, blob["pending_payloads"])

@@ -46,6 +46,10 @@ from .core import (
 __all__ = [
     "group_pspec",
     "shard_arrays",
+    "STATE_SPECS",
+    "INBOX_SPECS",
+    "local_cfg",
+    "local_shard",
     "make_sharded_tick",
     "make_sharded_run_ticks",
     "assert_zero_collectives",
@@ -72,13 +76,33 @@ def shard_arrays(cfg: EngineConfig, mesh: Mesh, tree):
     return jax.tree.map(put, tree)
 
 
-def _local_cfg(cfg: EngineConfig, mesh: Mesh) -> EngineConfig:
+# State/mailbox fields shard on their leading (groups) axis; the tick
+# counter is a replicated scalar.
+STATE_SPECS = EngineState(
+    **{
+        f: (P() if f == "tick_no" else P("groups"))
+        for f in EngineState._fields
+    }
+)
+INBOX_SPECS = Mailbox(**{f: P("groups") for f in Mailbox._fields})
+
+
+def local_cfg(cfg: EngineConfig, mesh: Mesh) -> EngineConfig:
+    """The config of one device's share of the groups."""
     n = mesh.devices.size
     if cfg.G % n != 0:
         raise ValueError(
             f"G={cfg.G} must divide evenly over {n} mesh devices"
         )
     return dataclasses.replace(cfg, G=cfg.G // n)
+
+
+def local_shard(cfg: EngineConfig, lcfg: EngineConfig):
+    """``(G_total, row0)`` of the calling device, inside ``shard_map``:
+    what ``core.shard_rows`` needs to give a device's groups the random
+    draws the unsharded tick would give them (a group's device is its
+    index range)."""
+    return cfg.G, jax.lax.axis_index("groups") * lcfg.G
 
 
 def make_sharded_tick(
@@ -89,10 +113,12 @@ def make_sharded_tick(
     ``step(state, inbox, new_cmds, key) -> (state, outbox, metrics)``
     where scalar metrics come back as per-device lanes (sum on host).
     Per-group metric vectors keep their global [G] shape."""
-    lcfg = _local_cfg(cfg, mesh)
+    lcfg = local_cfg(cfg, mesh)
 
     def local_step(state, inbox, new_cmds, key):
-        st, mb, m = tick_impl(lcfg, state, inbox, new_cmds, key)
+        st, mb, m = tick_impl(
+            lcfg, state, inbox, new_cmds, key, local_shard(cfg, lcfg)
+        )
         # Scalars become one lane per device (out_spec "groups" then
         # concatenates them) — no psum, zero collectives.
         m = {
@@ -100,24 +126,13 @@ def make_sharded_tick(
         }
         return st, mb, m
 
-    # Build in/out specs structurally: state/mailbox fields shard on
-    # their leading (groups) axis; metrics lanes shard likewise.
-    state_fields = EngineState._fields
-    mailbox_fields = Mailbox._fields
-    state_specs = EngineState(
-        **{
-            f: (P() if f == "tick_no" else P("groups"))
-            for f in state_fields
-        }
-    )
-    inbox_specs = Mailbox(**{f: P("groups") for f in mailbox_fields})
     metric_specs = {k: P("groups") for k in METRIC_KEYS}
     return jax.jit(
         shard_map(
             local_step,
             mesh=mesh,
-            in_specs=(state_specs, inbox_specs, P("groups"), P()),
-            out_specs=(state_specs, inbox_specs, metric_specs),
+            in_specs=(STATE_SPECS, INBOX_SPECS, P("groups"), P()),
+            out_specs=(STATE_SPECS, INBOX_SPECS, metric_specs),
         )
     )
 
@@ -129,7 +144,7 @@ def make_sharded_run_ticks(
     shard_map recipe: ``lax.scan`` of the local tick per device, zero
     host round-trips and zero collectives.  Returns a jitted
     ``run(state, inbox, key) -> (state, inbox)``."""
-    lcfg = _local_cfg(cfg, mesh)
+    lcfg = local_cfg(cfg, mesh)
 
     def local_run(state, inbox, key):
         new_cmds = jnp.full((lcfg.G,), ingest_per_tick, jnp.int32)
@@ -144,19 +159,12 @@ def make_sharded_run_ticks(
         )
         return state, inbox
 
-    state_specs = EngineState(
-        **{
-            f: (P() if f == "tick_no" else P("groups"))
-            for f in EngineState._fields
-        }
-    )
-    inbox_specs = Mailbox(**{f: P("groups") for f in Mailbox._fields})
     return jax.jit(
         shard_map(
             local_run,
             mesh=mesh,
-            in_specs=(state_specs, inbox_specs, P()),
-            out_specs=(state_specs, inbox_specs),
+            in_specs=(STATE_SPECS, INBOX_SPECS, P()),
+            out_specs=(STATE_SPECS, INBOX_SPECS),
         )
     )
 
@@ -168,7 +176,7 @@ def make_sharded_run_ticks_traced(
     ``core.run_ticks_traced`` (frontiers/accept terms, [n_ticks, G]
     sharded on the groups axis) — the bench's verified mode on a mesh,
     same zero-collective recipe."""
-    lcfg = _local_cfg(cfg, mesh)
+    lcfg = local_cfg(cfg, mesh)
 
     def local_run(state, inbox, key):
         from .core import make_traced_body
@@ -180,20 +188,13 @@ def make_sharded_run_ticks_traced(
         )
         return state, inbox, rec
 
-    state_specs = EngineState(
-        **{
-            f: (P() if f == "tick_no" else P("groups"))
-            for f in EngineState._fields
-        }
-    )
-    inbox_specs = Mailbox(**{f: P("groups") for f in Mailbox._fields})
     rec_specs = {k: P(None, "groups") for k in TRACE_KEYS}
     return jax.jit(
         shard_map(
             local_run,
             mesh=mesh,
-            in_specs=(state_specs, inbox_specs, P()),
-            out_specs=(state_specs, inbox_specs, rec_specs),
+            in_specs=(STATE_SPECS, INBOX_SPECS, P()),
+            out_specs=(STATE_SPECS, INBOX_SPECS, rec_specs),
         )
     )
 

@@ -18,7 +18,10 @@ rides inside the scan: per-tick drop masks (same ``fold_in(tick_key,
 chaos run fuses identically to a clean one.  Host-side reorder
 (`_apply_reorder`) is inherently unfusable — drivers with reordering
 in flight fall back to the serial loop (see
-``EngineDriver.fused_eligible``).
+``EngineDriver.fused_eligible``).  A mesh driver runs the same scan
+under ``shard_map`` (:func:`sharded_step_ticks`): each device advances
+its share of the groups, no collective, and the host sums the scalar
+records' per-device lanes.
 
 Bit-parity with the serial loop is a hard contract
 (tests/test_engine_pipeline.py pins it via the state_planes content
@@ -44,10 +47,44 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from .core import EngineConfig, EngineState, Mailbox, tick_impl
-from .host import apply_faults, mask_active
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
-__all__ = ["step_ticks", "PendingTicks"]
+from .core import METRIC_KEYS, EngineConfig, EngineState, Mailbox, tick_impl
+from .host import drop_messages, mask_active
+from .mesh import INBOX_SPECS, STATE_SPECS, local_cfg, local_shard
+
+__all__ = ["step_ticks", "sharded_step_ticks", "PendingTicks"]
+
+
+def _scan_ticks(
+    cfg, state, inbox, n_ticks, with_drop, with_edges,
+    backlog, drop_prob, edge_mask, tick0, key, shard=None,
+):
+    """The scan both programs below run: on the whole groups axis
+    (:func:`step_ticks`) or, with ``shard``, on one device's share of
+    it (:func:`sharded_step_ticks`)."""
+
+    def body(carry, i):
+        st, mb, bl = carry
+        # Parity with the serial loop: it increments the host tick
+        # FIRST, then folds — tick i of this batch is tick0 + 1 + i.
+        tick_key = jax.random.fold_in(key, tick0 + 1 + i)
+        new_cmds = jnp.minimum(bl, jnp.int32(cfg.INGEST))
+        st, mb, m = tick_impl(cfg, st, mb, new_cmds, tick_key, shard)
+        if with_drop:
+            mb = drop_messages(
+                mb, jax.random.fold_in(tick_key, 0xFA), drop_prob, cfg, shard
+            )
+        if with_edges:
+            mb = mask_active(mb, lambda _, a: a & edge_mask)
+        bl = bl - m["accepted"]
+        return (st, mb, bl), m
+
+    (state, inbox, backlog), rec = jax.lax.scan(
+        body, (state, inbox, backlog), jnp.arange(n_ticks, dtype=jnp.int32)
+    )
+    return state, inbox, backlog, rec
 
 
 @functools.partial(
@@ -74,27 +111,53 @@ def step_ticks(
     ``with_edges`` are static so the clean path compiles none of the
     fault machinery; ``tick0`` and ``backlog`` are device values so a
     moving tick counter never retraces."""
-
-    def body(carry, i):
-        st, mb, bl = carry
-        # Parity with the serial loop: it increments the host tick
-        # FIRST, then folds — tick i of this batch is tick0 + 1 + i.
-        tick_key = jax.random.fold_in(key, tick0 + 1 + i)
-        new_cmds = jnp.minimum(bl, jnp.int32(cfg.INGEST))
-        st, mb, m = tick_impl(cfg, st, mb, new_cmds, tick_key)
-        if with_drop:
-            mb = apply_faults(
-                mb, jax.random.fold_in(tick_key, 0xFA), drop_prob, cfg
-            )
-        if with_edges:
-            mb = mask_active(mb, lambda _, a: a & edge_mask)
-        bl = bl - m["accepted"]
-        return (st, mb, bl), m
-
-    (state, inbox, backlog), rec = jax.lax.scan(
-        body, (state, inbox, backlog), jnp.arange(n_ticks, dtype=jnp.int32)
+    return _scan_ticks(
+        cfg, state, inbox, n_ticks, with_drop, with_edges,
+        backlog, drop_prob, edge_mask, tick0, key,
     )
-    return state, inbox, backlog, rec
+
+
+@functools.lru_cache(maxsize=None)
+def sharded_step_ticks(
+    cfg: EngineConfig, mesh, n_ticks: int, with_drop: bool, with_edges: bool
+):
+    """:func:`step_ticks` for a mesh driver (engine/mesh.py's recipe):
+    under ``shard_map`` each device scans its ``G / n`` groups for
+    ``n_ticks``, zero collectives.  Returns the jitted
+    ``run(state, inbox, backlog, drop_prob, edge_mask, tick0, key)``
+    with the same results, where per-group records keep their global
+    ``[n_ticks, G]`` shape sharded on the groups axis and scalar records
+    come back as ``[n_ticks, n_devices]`` lanes, one per device and not
+    ``psum``-ed: the host sums them (``EngineDriver.complete_ticks``).
+    Bit-equal to :func:`step_ticks` on one device: the random draws are
+    the unsharded ones' rows (``core.shard_rows``)."""
+    lcfg = local_cfg(cfg, mesh)
+
+    def step_ticks_mesh(state, inbox, backlog, drop_prob, edge_mask, tick0, key):
+        state, inbox, backlog, rec = _scan_ticks(
+            lcfg, state, inbox, n_ticks, with_drop, with_edges,
+            backlog, drop_prob, edge_mask, tick0, key,
+            local_shard(cfg, lcfg),
+        )
+        rec = {k: (v[:, None] if v.ndim == 1 else v) for k, v in rec.items()}
+        return state, inbox, backlog, rec
+
+    groups, whole = P("groups"), P()
+    return jax.jit(
+        shard_map(
+            step_ticks_mesh,
+            mesh=mesh,
+            in_specs=(
+                STATE_SPECS, INBOX_SPECS, groups, whole,
+                groups if with_edges else whole, whole, whole,
+            ),
+            out_specs=(
+                STATE_SPECS, INBOX_SPECS, groups,
+                {k: P(None, "groups") for k in METRIC_KEYS},
+            ),
+        ),
+        donate_argnums=(0, 1),
+    )
 
 
 class PendingTicks:
@@ -113,7 +176,8 @@ class PendingTicks:
 
     __slots__ = (
         "n", "tick0", "rec", "accepts_dev", "t_dispatch", "t_loop_cpu",
-        "pump", "t_dispatched", "t_fetch", "t_fetched", "nbytes",
+        "pump", "shards", "t_dispatched", "t_fetch", "t_fetched", "nbytes",
+        "ncopies",
     )
 
     def __init__(
@@ -124,6 +188,7 @@ class PendingTicks:
         accepts_dev: jnp.ndarray,
         t_dispatch: float,
         pump: int = 0,
+        shards: int = 1,
     ) -> None:
         self.n = n
         self.tick0 = tick0
@@ -131,6 +196,9 @@ class PendingTicks:
         self.accepts_dev = accepts_dev
         self.t_dispatch = t_dispatch
         self.pump = pump  # pumps completed at dispatch: the trace tag
+        # Devices every array of ``rec`` is spread over: 1, or the mesh
+        # driver's device count (each then holds one shard of each).
+        self.shards = shards
         # Loop-side CPU the dispatch burned (the serving loop's share
         # of this pump; completion adds its own) — set by the caller.
         self.t_loop_cpu = 0.0
@@ -139,13 +207,17 @@ class PendingTicks:
         """Block until the batch's stacked metrics are host-resident.
         Pure device wait + copy: touches no driver state, so it is
         safe off the scheduler loop by construction.  It stamps its
-        own entry, return and the bytes it brought over on the batch;
+        own entry, return, the bytes it brought over and the device
+        buffers it copied them from (arrays x shards: a mesh driver's
+        record is read back chip by chip) on the batch;
         ``complete_ticks`` turns those into ``pump.handoff_s`` (since
-        ``dispatch_ticks`` returned), ``pump.fetch_s``, ``pump.post_s``
-        and ``pump.readback_bytes`` on the loop."""
+        ``dispatch_ticks`` returned), ``pump.fetch_s``, ``pump.post_s``,
+        ``pump.readback_bytes`` and ``pump.readback_copies`` on the
+        loop."""
         self.t_fetch = time.perf_counter()
         with TraceAnnotation("mrt.pump.fetch", pump=self.pump):
             out = {k: np.asarray(v) for k, v in self.rec.items()}
         self.nbytes = sum(v.nbytes for v in out.values())
+        self.ncopies = len(out) * self.shards
         self.t_fetched = time.perf_counter()
         return out

@@ -48,14 +48,19 @@ TILE_TOL = 0.05
 CYCLE_S = 0.012
 
 
-@pytest.fixture
-def served(tmp_path, monkeypatch):
+@pytest.fixture(params=[0, 4], ids=["one-device", "mesh4"])
+def served(request, tmp_path, monkeypatch):
     """A durable ``serve-kv`` node in this process: IoScheduler loop,
-    pump thread, WAL, a checkpoint every 0.4 s."""
+    pump thread, WAL, a checkpoint every 0.4 s; on one device, and with
+    the groups sharded over four (``--mesh-devices 4``)."""
+    mesh = request.param
+    if len(jax.devices()) < mesh:
+        pytest.skip(f"need {mesh} devices")
     monkeypatch.setenv("MRT_PUMP_IDLE_S", str(CYCLE_S))
     monkeypatch.setenv("MRT_PUMP_HOT", "0")  # one cadence, busy or not
     node = serve_engine_kv(
-        port=0, G=4, data_dir=str(tmp_path), checkpoint_every_s=0.4
+        port=0, G=8 if mesh else 4, data_dir=str(tmp_path),
+        checkpoint_every_s=0.4, mesh_devices=mesh,
     )
     client = RpcNode()
     try:
@@ -91,6 +96,7 @@ def _cycle_snapshots(svc, pumps, cap_s=60.0):
             snaps.append({
                 "pumps": n, "t": svc._t_cycle_end,
                 "bytes": svc.m.counters["pump.readback_bytes"],
+                "copies": svc.m.counters["pump.readback_copies"],
                 "hists": _hist_state(svc.m, names),
             })
             if len(snaps) == 2:
@@ -118,7 +124,10 @@ def _put_some(node, client, n):
 def test_phases_tile_the_durable_pump_cycle(served):
     node, client = served
     svc = node.engine_service
+    driver = svc.kv.driver
+    shards = driver.mesh.devices.size if driver.mesh is not None else 1
     assert svc._depth == 1 and svc._pipe is not None
+    assert driver.fused_eligible()  # a mesh server pipelines like any other
     writer = threading.Thread(target=_put_some, args=(node, client, 25))
     writer.start()
     a, b = _cycle_snapshots(svc, pumps=90)
@@ -137,13 +146,18 @@ def test_phases_tile_the_durable_pump_cycle(served):
             # every phase took one sample a pump
             assert abs((n1 - n0) - pumps) <= 1, (name, n1 - n0, pumps)
     assert abs(total - wall) <= TILE_TOL * wall, (total, wall)
-    # Readback: what fetch brought over is what the record's shapes say.
-    twin = EngineDriver(svc.kv.driver.cfg, seed=1)
+    # Readback: what fetch brought over is what the record's shapes say
+    # (on a mesh the scalar records are one lane a device), in one copy
+    # for every array and device that holds a shard of it.
+    twin = EngineDriver(driver.cfg, seed=1, mesh=driver.mesh)
     p = twin.dispatch_ticks(svc._ticks)
     per_pump = sum(v.size * v.dtype.itemsize for v in p.rec.values())
+    for v in p.rec.values():
+        assert len(v.addressable_shards) == shards
     twin.complete_ticks(p, p.fetch())
     assert per_pump > 0
     assert b["bytes"] - a["bytes"] == pumps * per_pump
+    assert b["copies"] - a["copies"] == pumps * len(p.rec) * shards
 
 
 @pytest.mark.timeout_s(240)
@@ -173,6 +187,8 @@ def test_loop_account_tiles_the_loop_threads_wall(served):
     # the compile counter and time to ready ride the same scrape
     assert m["engine.compiles"] >= 0 and "ready.warm_s" in m
     assert m["ready.checkpoint_s"] > 0.0 and m["ready.restore_s"] == 0.0
+    mesh = node.engine_service.kv.driver.mesh
+    assert m["engine.mesh_devices"] == (mesh.devices.size if mesh else 0)
 
 
 def _host_lines_with(trace_dir, prefix):
@@ -218,9 +234,10 @@ def test_phases_are_on_the_profilers_clock(served, tmp_path):
 
 
 def test_sync_pump_gets_apply_and_sync_and_nothing_else(tmp_path, monkeypatch):
-    """The synchronous pump (the kill switch here; mesh drivers take
-    the same path) runs no dispatch/fetch/complete and closes no gap:
-    it gets ``pump.apply_s`` and ``pump.sync_s`` from the shared code."""
+    """The synchronous pump (the kill switch; reorder chaos in flight
+    takes the same path) runs no dispatch/fetch/complete and closes no
+    gap: it gets ``pump.apply_s`` and ``pump.sync_s`` from the shared
+    code."""
     from multiraft_tpu.distributed.engine_durability import EngineDurability
     from multiraft_tpu.distributed.observe import Observability
     from multiraft_tpu.distributed.realtime import RealtimeScheduler
@@ -254,6 +271,7 @@ def test_sync_pump_gets_apply_and_sync_and_nothing_else(tmp_path, monkeypatch):
     assert got == {"pump.wall_s", "pump.apply_s", "pump.sync_s"}, got
     assert abs(obs.metrics.hists["pump.apply_s"].count - pumps) <= 1
     assert "pump.readback_bytes" not in obs.metrics.counters
+    assert "pump.readback_copies" not in obs.metrics.counters
 
 
 _CLIENT_ONLY = """
