@@ -164,6 +164,19 @@ def _fold_lanes(metrics: Dict[str, Any], xp, axis: int) -> Dict[str, Any]:
     return out
 
 
+@jax.jit
+def _take_rows(planes, idx):
+    return tuple(p[idx] for p in planes)
+
+
+def _reconfig_open(st) -> np.ndarray:
+    """Per group (row) of the host planes ``st``: in the joint phase, or
+    the latest config entry not yet committed."""
+    return st["joint"].any(axis=1) | (
+        st["cfg_idx"].max(axis=1) > st["commit"].max(axis=1)
+    )
+
+
 class EngineDriver:
     def __init__(
         self, cfg: EngineConfig, seed: int = 0, mesh=None,
@@ -247,6 +260,13 @@ class EngineDriver:
         # binding eviction scan (see _bind_accepted).
         self._max_bound: Dict[int, int] = {}
         self.last_metrics: Dict[str, Any] = {}
+        # Membership changes this driver has begun (add_learner,
+        # begin_joint) or inherited open from a checkpoint (restore).
+        # Nothing else opens one — seed_config writes voter masks, no
+        # config entry, and the tick only finishes what begin_joint
+        # started — so while this is 0 no group is reconfiguring and
+        # the wedge watch (distributed/wedge.py) does not ask the device.
+        self.config_changes = 0
         self.mesh = None
         self._mesh_tick = None
         self._groups_sharding = None  # the [G] vectors' sharding, on a mesh
@@ -539,6 +559,7 @@ class EngineDriver:
                 f"it from the config before reseating the slot"
             )
         self.reset_replica(g, p)
+        self.config_changes += 1
         st2 = self.state
         self.state = st2._replace(
             voters_old=st2.voters_old.at[g, p].set(
@@ -600,6 +621,7 @@ class EngineDriver:
             )
         idx = int(st["base"][g, lead] + st["log_len"][g, lead]) + 1
         term = int(st["term"][g, lead])
+        self.config_changes += 1
         s = self.state
         self.state = s._replace(
             log_term=s.log_term.at[g, lead, idx % self.cfg.L].set(term),
@@ -638,16 +660,18 @@ class EngineDriver:
             alive=alive,
         )
 
-    def reconfiguring(self) -> np.ndarray:
+    def reconfiguring(self, groups=None) -> np.ndarray:
         """Per-group bool: a membership change is in flight — the group
         is in the joint phase, or its latest config entry has not yet
         committed.  Stateless read the wedge watchdog and placement
         health checks consult (a reconfiguring group's commit frontier
-        may legitimately stall while it waits on BOTH quorums)."""
-        st = self.np_state()
-        return (
-            st["joint"].any(axis=1)
-            | (st["cfg_idx"].max(axis=1) > st["commit"].max(axis=1))
+        may legitimately stall while it waits on BOTH quorums).  With
+        ``groups``, those groups' rows alone are read (:meth:`rows_of`)
+        and the answer is in their order."""
+        if groups is None:
+            return _reconfig_open(self.np_state())
+        return _reconfig_open(
+            self.rows_of(("joint", "cfg_idx", "commit"), groups)
         )
 
     # -- Start() ----------------------------------------------------------
@@ -1158,6 +1182,8 @@ class EngineDriver:
         )
         d.tick = blob["tick"]
         d.key = jnp.array(blob["key"], copy=True)
+        if _reconfig_open(blob["state"]).any():
+            d.config_changes = 1  # a reconfig was open at the checkpoint
         if mesh is not None:
             d._use_mesh(mesh)
         d.backlog = blob["backlog"]
@@ -1186,6 +1212,24 @@ class EngineDriver:
 
     def np_state(self) -> Dict[str, np.ndarray]:
         return {k: np.asarray(v) for k, v in self.state._asdict().items()}
+
+    def rows_of(self, planes, groups) -> Dict[str, np.ndarray]:
+        """Host copies of the named state planes' rows for ``groups``
+        alone: one gather on the device and ``len(groups)`` rows back,
+        where :meth:`np_state` copies every ``[G, P]`` plane.  The index
+        vector is padded to a power of two, so a growing set of groups
+        compiles at most log2(G) small programs.  Waits for the tick
+        batch in flight, like any read of ``state``; call it on the
+        owning (scheduler) thread."""
+        idx = np.asarray(groups, np.int32)
+        padded = np.zeros(1 << max(3, (len(idx) - 1).bit_length()), np.int32)
+        padded[: len(idx)] = idx
+        rows = _take_rows(
+            tuple(getattr(self.state, name) for name in planes), padded
+        )
+        return {
+            name: np.asarray(r)[: len(idx)] for name, r in zip(planes, rows)
+        }
 
     def leaders_per_group(self) -> np.ndarray:
         st = self.np_state()

@@ -29,7 +29,6 @@ from multiraft_tpu.distributed.placement import (
     LocalPlacementStore,
     PlacementController,
 )
-from multiraft_tpu.distributed.wedge import WedgeWatch
 from multiraft_tpu.harness.fleet import (
     InProcessFleet,
     LocalFleetTransport,
@@ -355,47 +354,10 @@ def test_reconfig_intent_survives_map_leader_kill():
 # ---------------------------------------------------------------------------
 
 
-class _Ctl:
-    """ObsControl stand-in with scriptable membership columns."""
-
-    def __init__(self, commit, backlog, reconfig=None, sealed=None):
-        self.commit = list(commit)
-        self.backlog = np.asarray(backlog, np.int64)
-        self.reconfig = reconfig
-        self.sealed = sealed
-
-    def groups(self):
-        out = {
-            "G": len(self.commit),
-            "commit": list(self.commit),
-            "leader": [0] * len(self.commit),
-            "term": [1] * len(self.commit),
-        }
-        if self.reconfig is not None:
-            out["reconfig"] = list(self.reconfig)
-        if self.sealed is not None:
-            out["sealed"] = list(self.sealed)
-        return out
-
-    def _engine_kv(self):
-        return types.SimpleNamespace(
-            driver=types.SimpleNamespace(backlog=self.backlog)
-        )
-
-
-def _node(rec=None):
-    return types.SimpleNamespace(
-        sched=types.SimpleNamespace(call_after=lambda *_a, **_k: None),
-        obs=types.SimpleNamespace(metrics=Metrics()),
-        _frec=rec,
-        _closed=False,
-    )
-
-
-def _watch(node, ctl, stall_ticks=3):
-    w = WedgeWatch(node, interval=999.0, stall_ticks=stall_ticks)
-    w._ctl = ctl
-    return w
+# The watch's scriptable inputs (what the pump left on the host, the
+# seal flags, the device rows behind ``driver.rows_of``) live with the
+# watch's own tests.
+from tests.test_wedge import _Ctl, _node, _watch  # noqa: E402
 
 
 def test_wedge_exempts_reconfiguring_group():
@@ -406,7 +368,7 @@ def test_wedge_exempts_reconfiguring_group():
     node = _node(_Rec())
     ctl = _Ctl(commit=[5, 9], backlog=[4, 0], reconfig=[True, False])
     w = _watch(node, ctl, stall_ticks=2)
-    for _ in range(6):
+    for _ in range(7):  # the first scrape is the baseline: no candidate
         assert w.check() == 0
     assert node.obs.metrics.counters["wedge.reconfig_exempt"] >= 6
     assert w.wedged == set()
