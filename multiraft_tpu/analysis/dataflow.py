@@ -630,10 +630,12 @@ class Dataflow:
                 d = dotted_name(n.func)
                 leaf = d.rsplit(".", 1)[-1] if d is not None else None
                 # SomeScheduler(...) ctor: every callable argument is an
-                # io/timer hook that runs on the loop thread.
+                # io/timer hook that runs on the loop thread.  So is
+                # every one a service hands its PumpCycle (the
+                # end-of-pump hook, the bound after_step).
                 if (
                     leaf is not None
-                    and leaf.endswith("Scheduler")
+                    and leaf.endswith(("Scheduler", "PumpCycle"))
                     and leaf in self.classes
                 ):
                     hook_args = list(n.args) + [

@@ -112,12 +112,15 @@ class EngineDurability:
         self._last_ckpt = time.monotonic()
 
 
-def await_frame_synced(sched, dur, write_seqs, ok, args_list, deadline):
+def await_frame_synced(wait, dur, write_seqs, ok, args_list, deadline):
     """Durable frame-ack gate shared by the services' ``batch``
     handlers (yield-from inside the handler generator): every write in
     ``ok`` must have its apply-time WAL record fsynced before it may
     ack OK; at the deadline, unsynced writes are DROPPED from ``ok``
-    (they answer ErrTimeout — never a false durable ack)."""
+    (they answer ErrTimeout — never a false durable ack).  ``wait`` is
+    the service's park-until-the-group-fsync (``wait(deadline)``,
+    yield-from; False once the deadline has passed): the records sync
+    where a pump cycle ends, which this module knows nothing of."""
     while dur is not None:
         pend = [
             i for i in ok
@@ -127,13 +130,12 @@ def await_frame_synced(sched, dur, write_seqs, ok, args_list, deadline):
         ]
         if not pend:
             break
-        if sched.now >= deadline:
+        if not (yield from wait(deadline)):
             ok -= set(pend)
             break
-        yield 0.002
 
 
-def demote_unsynced_rows(sched, dur, write_seqs, frame, err, deadline):
+def demote_unsynced_rows(wait, dur, write_seqs, frame, err, deadline):
     """Firehose form of the frame-ack gate (yield-from inside the
     handler generator): wait for every OK write ROW's apply-time WAL
     record to fsync; at the deadline, unsynced rows demote to RETRY in
@@ -147,7 +149,7 @@ def demote_unsynced_rows(sched, dur, write_seqs, frame, err, deadline):
         for c, m in zip(frame.clients_l, frame.commands_l)
     ]
     yield from await_frame_synced(
-        sched, dur, write_seqs, ok_rows, rows_view, deadline
+        wait, dur, write_seqs, ok_rows, rows_view, deadline
     )
     from ..engine.firehose import FH_RETRY
 

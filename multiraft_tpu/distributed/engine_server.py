@@ -7,7 +7,8 @@ Architecture (vs the per-replica sim/process stack in ``cluster.py``):
 
 * ``EngineKVService`` wraps a :class:`BatchedKV` on an
   :class:`EngineDriver`.  A pump timer on the process's
-  ``RealtimeScheduler`` advances the device tick loop every couple of
+  ``RealtimeScheduler`` (:class:`~.pump_cycle.PumpCycle`, which both
+  services compose) advances the device tick loop every couple of
   milliseconds; every RPC that arrived since the last pump has already
   queued its command into the per-group backlog, so one device step
   carries *all* concurrent client traffic — the batching that makes a
@@ -27,7 +28,6 @@ Wire protocol: ``EngineKV.command`` / ``EngineShardKV.command`` over
 
 from __future__ import annotations
 
-import functools
 import os
 import time
 from typing import Optional, Sequence
@@ -38,7 +38,6 @@ from ..engine.host import EngineDriver
 from ..engine.instrument import count_compiles
 from ..engine.kv import BatchedKV, KVOp
 from ..porcupine.kv import OP_GET
-from ..sim.scheduler import TIMEOUT, Future
 from .engine_durability import (
     EngineDurability,
     await_frame_synced,
@@ -56,16 +55,12 @@ from .engine_wire import (
     make_mesh,
     route_group,
 )
-from ..utils.knobs import knob_bool, knob_float, knob_int
 from .admission import install_admission
-from .engine_pump import PUMP_THREAD_PREFIX, EnginePump
+from .observe import Observability
 from .overload import install_overload_watch
+from .pump_cycle import PumpCycle
 from .wedge import install_wedge_watch
-from .realtime import (
-    PumpCadence,
-    RealtimeScheduler,
-    service_busy,
-)
+from .realtime import RealtimeScheduler
 from .tcp import RpcNode
 
 __all__ = [
@@ -111,61 +106,17 @@ class EngineKVService:
         self.sched = sched
         self.kv = kv
         self.G = kv.driver.cfg.G
-        self._cadence = PumpCadence(
-            knob_float("MRT_PUMP_IDLE_S", default=pump_interval)
-        )
-        self._ticks = ticks_per_pump
-        self._stopped = False
         self._dur = durability
         # The owning node's observability plane (tick/pump latency,
         # frame sizes, commit instants tagged with the caller's request
-        # id).  Lazily defaulted via the `obs` property, so partially
-        # constructed stubs (tests build handlers via __new__) work too.
-        self._obs = obs
+        # id); a private one when the service is built without a node.
+        self.obs = obs if obs is not None else Observability()
+        self.m = self.obs.metrics
         # (client_id, command_id) -> WAL seq of the op's apply-time
         # record; handlers gate their ack on it being fsynced.  Pruned
         # once synced (absence = already durable).
         self._write_seqs: dict = {}
-        # Black box: tick boundaries + consensus frontier transitions
-        # land in the crash-surviving ring (flightrec.py).  The
-        # frontier triple is only recorded when it CHANGES — a quiet
-        # pump loop writes one TICK record per pump and nothing else.
-        self._frec = flightrec.get_recorder()
-        self._pumps = 0
-        self._pump_t_dispatch = 0.0
-        self._last_frontier = (-1, -1, -1)
-        # Asynchronous engine pipeline (engine_pump.py): the loop
-        # dispatches fused tick batches and completes them when the
-        # dedicated pump thread has fetched the stacked metrics; the
-        # legacy synchronous pump stays selectable per pump (kill
-        # switch, reorder chaos).  A mesh driver pipelines like a
-        # one-chip one: the batch is one program over its devices and
-        # the fetch reads each chip's shard back.  Durable servers pin
-        # the depth to 1 so each checkpoint sees a drained pipeline
-        # (EngineDriver.save refuses otherwise).
-        self._pipe = None
-        self._depth = 1
-        self._pump_timer = None
-        # perf_counter when the last pump cycle ended (after_pump
-        # returned): the next dispatch closes ``pump.gap_s`` against it.
-        self._t_cycle_end = None
-        # Resolved when the pump cycle in progress ends: what a parked
-        # ``command`` handler waits on (``_await_pump_end``).
-        self._cycle_end = Future()
-        if knob_bool("MRT_ENGINE_PIPELINE"):
-            loop_name = getattr(getattr(sched, "_thread", None), "name", "")
-            suffix = (
-                loop_name[len("multiraft-loop"):]
-                if loop_name.startswith("multiraft-loop") else ""
-            )
-            self._pipe = EnginePump(sched, name=PUMP_THREAD_PREFIX + suffix)
-            self._depth = (
-                1 if durability is not None
-                else max(1, knob_int("MRT_PIPELINE_DEPTH"))
-            )
-            pump_ticks = knob_int("MRT_PUMP_TICKS")
-            if pump_ticks > 0:
-                self._ticks = pump_ticks
+        self._frec = flightrec.get_recorder()  # COMMIT records
         if durability is not None:
             # WAL at APPLY time (commit order): evict-and-resubmit can
             # commit ops in a different order than submission, and
@@ -175,208 +126,28 @@ class EngineKVService:
                 durability.log(("kv", _OPNAME[op.op], op.key, op.value,
                                 op.client_id, op.command_id)),
             )
-        if self._pipe is not None and kv.driver.fused_eligible():
-            # Warm the fused n-tick program NOW, before the first
-            # client byte: its first invocation pays the jit compile on
-            # this (loop) thread, and paying it mid-serving stalls the
-            # first rate step's tail (measured ~100 ms on the r04
-            # sweep's opening step).  The backlog is empty at
-            # construction, so this is two liveness ticks.
-            self.kv.pump(self._ticks)
-        sched.call_soon(self._pump_loop)
-
-    @property
-    def obs(self):
-        o = getattr(self, "_obs", None)
-        if o is None:
-            from .observe import Observability
-
-            o = self._obs = Observability()
-        return o
-
-    @property
-    def m(self):
-        return self.obs.metrics
+        # The pump timer, the pipeline and the wait every handler parks
+        # on (pump_cycle.py).
+        self.cycle = PumpCycle(
+            sched, self.kv, ticks_per_pump, interval=pump_interval,
+            durability=self._dur, metrics=self.m,
+            on_end=self._prune_synced,
+        )
 
     def stop(self) -> None:
-        self._stopped = True
-        pipe = getattr(self, "_pipe", None)
-        if pipe is not None:
-            pipe.stop()
+        self.cycle.stop()
 
     def final_checkpoint(self) -> bool:
-        """Graceful-shutdown hook (CLI SIGTERM): fold everything into
-        one last checkpoint so the next start skips WAL replay.  False
-        when the server is not durable."""
-        if self._dur is None:
-            return False
-        self._drain_pipeline()  # driver.save refuses in-flight batches
-        self._dur.checkpoint()
-        return True
+        return self.cycle.final_checkpoint()
 
-    def _arm_pump(self, delay: float) -> None:
-        """Single-timer discipline: exactly one pending _pump_loop
-        timer, re-armed earlier when a completion says there is work."""
-        t = self._pump_timer
-        if t is not None:
-            t.cancel()
-        self._pump_timer = self.sched.call_after(delay, self._pump_loop)
-
-    def _pump_loop(self) -> None:
-        self._pump_timer = None
-        if self._stopped:
-            return
-        d = self.kv.driver
-        if self._pipe is not None and d.fused_eligible():
-            # Pipelined path: dispatch a fused batch WITHOUT waiting —
-            # the engine-pump thread blocks on the readback and posts
-            # _pump_done back here.  The loop is free for wire work
-            # while the device computes.
-            if len(d._inflight) < self._depth:
-                # Push queued replies first (see the sync path below).
-                flush = getattr(self.sched, "flush_io", None)
-                if flush is not None:
-                    flush()
-                cp0 = time.thread_time()
-                pending = d.dispatch_ticks(self._ticks)
-                pending.t_loop_cpu = time.thread_time() - cp0
-                if self._t_cycle_end is not None:
-                    # What the loop did between two cycles: the pump
-                    # timer's delay, frames, replies, other timers.
-                    self.m.observe(
-                        "pump.gap_s", pending.t_dispatch - self._t_cycle_end
-                    )
-                self._pipe.submit(
-                    pending.fetch,
-                    functools.partial(self._pump_done, pending),
-                )
-            self._arm_pump(self._cadence.next_delay(service_busy(self.kv)))
-            return
-        self._pump_sync()
-
-    def _pump_sync(self) -> None:
-        """Legacy synchronous pump (MRT_ENGINE_PIPELINE=0, reorder
-        chaos in flight): the whole device step runs on the loop
-        thread."""
-        # About to grind for up to several milliseconds: push any
-        # queued replies onto the wire first, or a client whose op
-        # resolved last tick waits out this whole one before it can
-        # pipeline its next frame.  (No-op off the IoScheduler: sim
-        # tests drive handlers with the virtual-time Scheduler.)
-        flush = getattr(self.sched, "flush_io", None)
-        if flush is not None:
-            flush()
-        t0 = time.perf_counter()
-        cp0 = time.thread_time()
-        self.kv.pump(self._ticks)
-        dt = time.perf_counter() - t0
-        cdt = time.thread_time() - cp0
-        self._record_pump(dt, cdt)
-        self._after_pump_durability()
-        self._arm_pump(self._cadence.next_delay(service_busy(self.kv)))
-
-    def _pump_done(self, pending, rec) -> None:
-        """Loop-side completion of a dispatched batch (posted by the
-        engine-pump thread with the fetched stacked metrics): fold the
-        bookkeeping, sweep the frontier, observe, re-arm."""
-        if isinstance(rec, BaseException):
-            raise rec  # device failure: surface on the owning loop
-        d = self.kv.driver
-        if pending not in d._inflight:
-            return  # already drained (final_checkpoint) or torn down
-        cp0 = time.thread_time()
-        d.complete_ticks(pending, rec)
-        self.kv.after_step(pending.n)
-        # Wall covers dispatch→completion (the client-visible pump
-        # latency); CPU counts only the LOOP-side share — the split the
-        # profiler uses to show the loop is no longer device-blocked.
-        dt = time.perf_counter() - pending.t_dispatch
-        cdt = (time.thread_time() - cp0) + pending.t_loop_cpu
-        self._record_pump(dt, cdt)
-        self._after_pump_durability()
-        if self._stopped:
-            return
-        self._arm_pump(self._cadence.next_delay(service_busy(self.kv)))
-
-    def _record_pump(self, dt: float, cdt: float) -> None:
-        self.m.inc("pump.count")
-        self.m.observe("pump.wall_s", dt)
-        # Wall-vs-CPU split: a tick whose wall ≫ CPU is device-bound
-        # (the host blocked on the accelerator); wall ≈ CPU is
-        # host-bound (binding/resolution burning the loop).  The pump
-        # IS the engine stage's CPU (observe.py vocabulary).
-        self.m.observe("cpu.engine_s", cdt)
-        # Pump sequencing for the tail plane: tick id + dispatch stamp
-        # (now − wall) let a committing request attribute its parked
-        # time to the fused tick that carried it.  Unconditional — the
-        # flight-ring gate below must not decide whether requests know
-        # their tick.
-        self._pumps += 1
-        self._pump_t_dispatch = time.perf_counter() - dt
-        fr = self._frec
-        if fr is not None:
-            # Tick boundary + (on change only) the consensus frontier.
-            # Everything here is host-side bookkeeping the pump already
-            # computed — no device readback is added.
-            d = self.kv.driver
-            commits = int(d.commits_total)
-            fr.record(
-                flightrec.TICK, a=self._pumps, b=int(dt * 1e6), c=commits
-            )
-            lm = getattr(d, "last_metrics", None) or {}
-            frontier = (
-                commits,
-                int(lm.get("leaders", -1)),
-                int(lm.get("max_term", -1)),
-            )
-            if frontier != self._last_frontier:
-                self._last_frontier = frontier
-                fr.record(
-                    flightrec.STATE, a=frontier[0], b=frontier[1],
-                    c=frontier[2],
-                )
-
-    def _after_pump_durability(self) -> None:
-        if self._dur is not None:
-            self._dur.after_pump()  # group fsync + periodic checkpoint
-        self._t_cycle_end = time.perf_counter()
+    def _prune_synced(self) -> None:
+        """The cycle's end-of-pump hook: forget the WAL seqs the group
+        fsync just covered."""
         if self._dur is not None and self._write_seqs:
             self._write_seqs = {
                 k: v for k, v in self._write_seqs.items()
                 if not self._dur.synced(v)
             }
-        # Tickets, failures and the WAL's synced frontier change here
-        # and nowhere else, so this is where parked handlers look again:
-        # each takes one step inline, after the stamp, so the wake-up is
-        # inside ``pump.gap_s``.  The fresh future goes in first: a
-        # handler that parks again waits for the NEXT cycle's end.
-        ended, self._cycle_end = self._cycle_end, Future()
-        ended.resolve()
-
-    def _await_pump_end(self, until: float):
-        """Park the calling handler (``yield from``) until the pump
-        cycle in progress ends, or until ``until`` on the scheduler's
-        clock if no pump ends first (a stalled pump; shutdown's drain,
-        which completes ticks without the hook).  False, and no wait,
-        once ``until`` has passed."""
-        left = until - self.sched.now
-        if left <= 0:
-            return False
-        woke = yield self.sched.with_timeout(self._cycle_end, left)
-        self.m.inc("kv.wait_steps")
-        if woke is TIMEOUT:
-            self.m.inc("kv.wait_timeouts")
-        return True
-
-    def _drain_pipeline(self) -> None:
-        """Complete every in-flight batch synchronously (checkpoint /
-        shutdown path): blocks the loop, which is the point — nothing
-        else may observe a half-accounted engine."""
-        d = self.kv.driver
-        while d._inflight:
-            p = d._inflight[0]
-            d.complete_ticks(p, p.fetch())
-            self.kv.after_step(p.n)
 
     def replay_wal(self) -> int:
         """Recovery replay — delegated to
@@ -463,7 +234,8 @@ class EngineKVService:
                     for i, a in members[first_bad:]:
                         tickets[i] = submit(a)
                 if pending and not progressed:
-                    yield 0.002
+                    # tickets resolve and fail at a pump end only
+                    yield from self.cycle.wait(deadline)
             tickets = {
                 i: t for i, t in tickets.items()
                 if t.done and not t.failed
@@ -472,7 +244,7 @@ class EngineKVService:
             # (shared gate — see _await_frame_synced).
             synced_ok = set(tickets)
             yield from await_frame_synced(
-                self.sched, self._dur, self._write_seqs, synced_ok,
+                self.cycle.wait, self._dur, self._write_seqs, synced_ok,
                 args_list, deadline,
             )
             for i, a in enumerate(args_list):
@@ -540,8 +312,8 @@ class EngineKVService:
             self.m.inc("firehose.rows", n)
             t0 = self.sched.now
             deadline = t0 + self.DEADLINE_S
-            while not f.done and self.sched.now < deadline:
-                yield 0.002
+            while not f.done and (yield from self.cycle.wait(deadline)):
+                pass
             # Firehose lag: submit → frame resolution (device-side wait).
             self.m.observe("firehose.lag_s", self.sched.now - t0)
             err = f.err.copy()
@@ -554,7 +326,7 @@ class EngineKVService:
             # way).
             if self._dur is not None:
                 yield from demote_unsynced_rows(
-                    self.sched, self._dur, self._write_seqs, f, err,
+                    self.cycle.wait, self._dur, self._write_seqs, f, err,
                     deadline,
                 )
             if not f.done or (err[f.write_rows] != 0).any():
@@ -629,9 +401,10 @@ class EngineKVService:
                 sub_deadline = min(
                     self.sched.now + self.RESUBMIT_S, deadline
                 )
-                while not t.done:
-                    if not (yield from self._await_pump_end(sub_deadline)):
-                        break
+                while not t.done and (
+                    yield from self.cycle.wait(sub_deadline, counted=True)
+                ):
+                    pass
                 if t.done and not t.failed:
                     if stages is not None:
                         # Commit observed: submit → raft quorum +
@@ -642,13 +415,9 @@ class EngineKVService:
                         # the commit, and how long the proposal sat
                         # parked before that tick was dispatched (the
                         # rest of the engine leg is device work).
-                        # getattr: stub handlers built via __new__
-                        # (tests) carry no pump state.
-                        stages.tick = getattr(self, "_pumps", -1)
+                        stages.tick = self.cycle.seq
                         stages.pump_wait_s = max(
-                            0.0,
-                            getattr(self, "_pump_t_dispatch", 0.0)
-                            - t_parked,
+                            0.0, self.cycle.t_dispatch - t_parked
                         )
                     # Ack only once the apply-time WAL record is
                     # fsynced (absent = pruned = already durable, or
@@ -662,19 +431,18 @@ class EngineKVService:
                         )
                         if seq is None or self._dur.synced(seq):
                             break
-                        if not (yield from self._await_pump_end(deadline)):
+                        if not (
+                            yield from self.cycle.wait(deadline, counted=True)
+                        ):
                             return EngineCmdReply(err=ERR_TIMEOUT)
                     self.m.observe(
                         "kv.command_s", self.sched.now - t_start
                     )
-                    # getattr: stub handlers built via __new__ (tests)
-                    # carry no recorder.
-                    _fr = getattr(self, "_frec", None)
-                    if _fr is not None:
+                    if self._frec is not None:
                         # Last-committed evidence for the postmortem:
                         # survives a SIGKILL that the tracer's commit
                         # instant (below) would die with.
-                        _fr.record(
+                        self._frec.record(
                             flightrec.COMMIT, code=g,
                             a=args.client_id, b=args.command_id,
                             tag=rid or "",

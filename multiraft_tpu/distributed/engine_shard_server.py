@@ -36,13 +36,9 @@ from .engine_wire import (
     EngineCmdReply,
     make_mesh,
 )
-from ..utils.knobs import knob_bool, knob_float, knob_int
-from .engine_pump import PUMP_THREAD_PREFIX, EnginePump
-from .realtime import (
-    PumpCadence,
-    RealtimeScheduler,
-    service_busy,
-)
+from .observe import Observability
+from .pump_cycle import PumpCycle
+from .realtime import RealtimeScheduler
 from .tcp import RpcNode
 
 __all__ = ["EngineShardKVService", "serve_engine_shardkv"]
@@ -90,30 +86,6 @@ class EngineShardKVService:
     ) -> None:
         self.sched = sched
         self.skv = skv
-        self._cadence = PumpCadence(
-            knob_float("MRT_PUMP_IDLE_S", default=pump_interval)
-        )
-        self._ticks = ticks_per_pump
-        self._stopped = False
-        # Asynchronous engine pipeline — see EngineKVService; same
-        # dispatch/complete split, same durable depth pin.
-        self._pipe = None
-        self._depth = 1
-        self._pump_timer = None
-        if knob_bool("MRT_ENGINE_PIPELINE"):
-            loop_name = getattr(getattr(sched, "_thread", None), "name", "")
-            suffix = (
-                loop_name[len("multiraft-loop"):]
-                if loop_name.startswith("multiraft-loop") else ""
-            )
-            self._pipe = EnginePump(sched, name=PUMP_THREAD_PREFIX + suffix)
-            self._depth = (
-                1 if durability is not None
-                else max(1, knob_int("MRT_PIPELINE_DEPTH"))
-            )
-            pump_ticks = knob_int("MRT_PUMP_TICKS")
-            if pump_ticks > 0:
-                self._ticks = pump_ticks
         self.peers = dict(peers or {})
         # A fleet process whose peer map is momentarily empty (all gids
         # local, or rebuilt by a placement push) must KEEP answering
@@ -126,18 +98,17 @@ class EngineShardKVService:
         # reordered pushes are harmless).
         self._placement = (0, dict(placement0 or {}))
         self._dur = durability
-        # Observability plane (see EngineKVService): the owning node's,
-        # lazily defaulted via the `obs` property for stub construction.
-        self._obs = obs
-        # Pump sequencing for the tail plane (see _record_pump).
-        self._pumps = 0
-        self._pump_t_dispatch = 0.0
+        # The owning node's observability plane (a private one when the
+        # service is built without a node).
+        self.obs = obs if obs is not None else Observability()
+        self.m = self.obs.metrics
         # seq of the WAL record covering each applied insert — the GC
         # gate below refuses to ask the old owner to delete until the
         # inserted blob (possibly the last copy) is fsynced here.
         self._insert_seqs: dict = {}
-        # (client_id, command_id) -> WAL seq, apply-time (commit order)
-        # — see EngineKVService; pruned once synced.
+        # (client_id, command_id) -> WAL seq of the op's apply-time
+        # (commit order) record; handlers gate their ack on it being
+        # fsynced.  Pruned once synced (absence = already durable).
         self._write_seqs: dict = {}
         self._admin_seqs: dict = {}  # command_id -> WAL seq
         # seq of the WAL record covering each applied delete — the
@@ -183,7 +154,7 @@ class EngineShardKVService:
             from . import flightrec
             from .stateplane import StandbyStore, StatePlane
 
-            self._standby = StandbyStore(obs=self._obs)
+            self._standby = StandbyStore(obs=obs)
             self._plane = StatePlane(
                 skv, me=int(me), n_procs=len(self._fleet_addrs),
                 send=self._ship_send, rules=ship_rules,
@@ -192,7 +163,7 @@ class EngineShardKVService:
                     (lambda: durability.wal.appended)
                     if durability is not None else None
                 ),
-                obs=self._obs, recorder=flightrec.get_recorder(),
+                obs=obs, recorder=flightrec.get_recorder(),
             )
             # Attach AFTER the durability on_write hook above, so the
             # WAL record exists (wal.appended names it) when the plane
@@ -203,27 +174,16 @@ class EngineShardKVService:
                 # acked the shipment covering the record (the zero-
                 # acknowledged-write-loss mode of the chaos gate).
                 self._dur.extra_sync_gate = self._plane.covered
-        if self._pipe is not None and skv.driver.fused_eligible():
-            # Warm the fused n-tick program before serving: its first
-            # invocation pays the jit compile on this (loop) thread —
-            # mid-serving it stalls the opening rate step's tail.  No
-            # orchestration during construction; the backlog is empty,
-            # so this is two liveness ticks.
-            self.skv.pump(self._ticks, orchestrate=False)
-        sched.call_soon(self._pump_loop)
-
-    @property
-    def obs(self):
-        o = getattr(self, "_obs", None)
-        if o is None:
-            from .observe import Observability
-
-            o = self._obs = Observability()
-        return o
-
-    @property
-    def m(self):
-        return self.obs.metrics
+        # The pump timer, the pipeline and the wait every handler parks
+        # on (pump_cycle.py).  Every served pump orchestrates migration;
+        # the warm-up during construction does not.
+        self.cycle = PumpCycle(
+            sched, self.skv, ticks_per_pump, interval=pump_interval,
+            durability=self._dur, metrics=self.m,
+            on_end=self._after_pump,
+            after_step=functools.partial(skv.after_step, orchestrate=True),
+            warm=functools.partial(skv.pump, orchestrate=False),
+        )
 
     # -- durability hooks (apply-time, loop thread) -----------------------
 
@@ -327,7 +287,10 @@ class EngineShardKVService:
                 if rep.cur.num >= num:
                     sh = rep.shards[shard]
                     return (SK_OK, dict(sh.data), dict(sh.latest))
-                yield 0.01  # config catching up (the ErrNotReady gate)
+                # Not a pump-end wait: the puller is a config ahead
+                # until the admin's RPC reaches THIS process too (the
+                # ErrNotReady gate), so look again on a timer.
+                yield 0.01
             return (ERR_NOT_READY,)
 
         return run()
@@ -347,31 +310,27 @@ class EngineShardKVService:
         def run():
             t = self.skv.delete_shard(src_gid, shard, num)
             deadline = self.sched.now + self.DEADLINE_S
-            while self.sched.now < deadline:
-                if t.done:
-                    if t.failed:
-                        return (ERR_TIMEOUT,)
-                    if t.err != SK_OK:
-                        return (t.err,)
-                    # Gate the OK on the delete's WAL record being
-                    # fsynced: the puller confirms on our OK and never
-                    # re-asks, so losing the record to a crash would
-                    # strand a BEPULLING slot here forever.  (Absent =
-                    # pruned = already durable, or the slot was already
-                    # clear and no record was written — also durable.)
-                    # Deadline-bounded: a stalled fsync must surface as
-                    # a timeout the puller retries, not a pinned
-                    # generator.
-                    while self._dur is not None:
-                        seq = self._delete_seqs.get((src_gid, shard, num))
-                        if seq is None or self._dur.synced(seq):
-                            break
-                        if self.sched.now >= deadline:
-                            return (ERR_TIMEOUT,)
-                        yield 0.002
-                    return (SK_OK,)
-                yield 0.005
-            return (ERR_TIMEOUT,)
+            while not t.done:
+                if not (yield from self.cycle.wait(deadline)):
+                    return (ERR_TIMEOUT,)
+            if t.failed:
+                return (ERR_TIMEOUT,)
+            if t.err != SK_OK:
+                return (t.err,)
+            # Gate the OK on the delete's WAL record being fsynced: the
+            # puller confirms on our OK and never re-asks, so losing the
+            # record to a crash would strand a BEPULLING slot here
+            # forever.  (Absent = pruned = already durable, or the slot
+            # was already clear and no record was written — also
+            # durable.)  Deadline-bounded: a stalled fsync must surface
+            # as a timeout the puller retries, not a pinned generator.
+            while self._dur is not None:
+                seq = self._delete_seqs.get((src_gid, shard, num))
+                if seq is None or self._dur.synced(seq):
+                    break
+                if not (yield from self.cycle.wait(deadline)):
+                    return (ERR_TIMEOUT,)
+            return (SK_OK,)
 
         return run()
 
@@ -404,7 +363,9 @@ class EngineShardKVService:
                 blob = self.skv.export_group(gid)
                 if blob is not None:
                     return (SK_OK, blob)
-                yield 0.01  # mid-migration / config in flight: settle
+                # Not a pump-end wait: the group settles when its peers
+                # (other processes) finish the migration in flight.
+                yield 0.01
             return (ERR_NOT_READY,)
 
         return run()
@@ -463,7 +424,8 @@ class EngineShardKVService:
                     self._rebuild_peers()  # route it to its new owner
                     self.m.inc("place.drops")
                     return (SK_OK,)
-                yield 0.005
+                # the slot's tail applies resolve in a pump's sweep
+                yield from self.cycle.wait(deadline)
             return (ERR_TIMEOUT,)
 
         return run()
@@ -702,13 +664,13 @@ class EngineShardKVService:
             except ValueError as e:
                 return ("err", str(e))
             deadline = self.sched.now + self.DEADLINE_S
-            while not f.done and self.sched.now < deadline:
-                yield 0.002
+            while not f.done and (yield from self.cycle.wait(deadline)):
+                pass
             err = f.err.copy()
             # Durable mode: the shared firehose ack gate.
             if self._dur is not None:
                 yield from demote_unsynced_rows(
-                    self.sched, self._dur, self._write_seqs, f, err,
+                    self.cycle.wait, self._dur, self._write_seqs, f, err,
                     deadline,
                 )
             # Shards whose write rows did not land OK: gets there mirror
@@ -736,93 +698,15 @@ class EngineShardKVService:
         return run()
 
     def stop(self) -> None:
-        self._stopped = True
-        pipe = getattr(self, "_pipe", None)
-        if pipe is not None:
-            pipe.stop()
+        self.cycle.stop()
 
     def final_checkpoint(self) -> bool:
-        """Graceful-shutdown hook — see EngineKVService."""
-        if self._dur is None:
-            return False
-        self._drain_pipeline()  # driver.save refuses in-flight batches
-        self._dur.checkpoint()
-        return True
+        return self.cycle.final_checkpoint()
 
-    def _arm_pump(self, delay: float) -> None:
-        """Single-timer discipline — see EngineKVService."""
-        t = self._pump_timer
-        if t is not None:
-            t.cancel()
-        self._pump_timer = self.sched.call_after(delay, self._pump_loop)
-
-    def _pump_loop(self) -> None:
-        self._pump_timer = None
-        if self._stopped:
-            return
-        d = self.skv.driver
-        if self._pipe is not None and d.fused_eligible():
-            # Pipelined path — see EngineKVService._pump_loop.
-            if len(d._inflight) < self._depth:
-                flush = getattr(self.sched, "flush_io", None)
-                if flush is not None:
-                    flush()
-                cp0 = time.thread_time()
-                pending = d.dispatch_ticks(self._ticks)
-                pending.t_loop_cpu = time.thread_time() - cp0
-                self._pipe.submit(
-                    pending.fetch,
-                    functools.partial(self._pump_done, pending),
-                )
-            self._arm_pump(self._cadence.next_delay(service_busy(self.skv)))
-            return
-        self._pump_sync()
-
-    def _pump_sync(self) -> None:
-        """Legacy synchronous pump (MRT_ENGINE_PIPELINE=0, mesh
-        drivers, reorder chaos in flight)."""
-        t0 = time.perf_counter()
-        cp0 = time.thread_time()
-        self.skv.pump(self._ticks)
-        dt = time.perf_counter() - t0
-        self._record_pump(dt, time.thread_time() - cp0)
-        self._after_pump_durability()
-        self._arm_pump(self._cadence.next_delay(service_busy(self.skv)))
-
-    def _pump_done(self, pending, rec) -> None:
-        """Loop-side completion of a dispatched batch — see
-        EngineKVService._pump_done."""
-        if isinstance(rec, BaseException):
-            raise rec
-        d = self.skv.driver
-        if pending not in d._inflight:
-            return  # already drained (final_checkpoint) or torn down
-        cp0 = time.thread_time()
-        d.complete_ticks(pending, rec)
-        self.skv.after_step(pending.n, orchestrate=True)
-        self._record_pump(
-            time.perf_counter() - pending.t_dispatch,
-            (time.thread_time() - cp0) + pending.t_loop_cpu,
-        )
-        self._after_pump_durability()
-        if self._stopped:
-            return
-        self._arm_pump(self._cadence.next_delay(service_busy(self.skv)))
-
-    def _record_pump(self, dt: float, cdt: float) -> None:
-        self.m.inc("pump.count")
-        self.m.observe("pump.wall_s", dt)
-        self.m.observe("cpu.engine_s", cdt)
-        # Pump sequencing for the tail plane (twin of the flat engine
-        # server's): tick id + dispatch stamp so a committing request
-        # can attribute its parked time to the fused tick that
-        # carried it.
-        self._pumps += 1
-        self._pump_t_dispatch = time.perf_counter() - dt
-
-    def _after_pump_durability(self) -> None:
+    def _after_pump(self) -> None:
+        """The cycle's end-of-pump hook: forget the WAL seqs the group
+        fsync just covered, then ship to the standbys."""
         if self._dur is not None:
-            self._dur.after_pump()  # group fsync + periodic checkpoint
             for attr in ("_insert_seqs", "_write_seqs", "_admin_seqs",
                          "_delete_seqs"):
                 seqs = getattr(self, attr)
@@ -833,15 +717,6 @@ class EngineShardKVService:
                     })
         if self._plane is not None:
             self._plane.ship_round()
-
-    def _drain_pipeline(self) -> None:
-        """Complete every in-flight batch synchronously (checkpoint /
-        shutdown path) — see EngineKVService."""
-        d = self.skv.driver
-        while d._inflight:
-            p = d._inflight[0]
-            d.complete_ticks(p, p.fetch())
-            self.skv.after_step(p.n, orchestrate=True)
 
     def replay_wal(self) -> int:
         """Recovery replay — delegated to
@@ -934,14 +809,16 @@ class EngineShardKVService:
                     if cursor[qk] >= len(members):
                         pending.discard(qk)
                 if pending and not progressed:
-                    yield 0.002
+                    # tickets resolve, and the config moves, at a pump
+                    # end only
+                    yield from self.cycle.wait(deadline)
             # Durable frame ack (shared gate — see _await_frame_synced).
             ok = {
                 i for i, t in tickets.items()
                 if t.done and not t.failed and t.err == OK
             }
             yield from await_frame_synced(
-                self.sched, self._dur, self._write_seqs, ok,
+                self.cycle.wait, self._dur, self._write_seqs, ok,
                 args_list, deadline,
             )
             for i, a in enumerate(args_list):
@@ -985,7 +862,10 @@ class EngineShardKVService:
                         # process — answer so the clerk re-routes.
                         if self._fleet:
                             return EngineCmdReply(err=ERR_WRONG_GROUP)
-                        yield 0.01  # config moving; shard not serving here
+                        # Not a pump-end wait: the shard serves here once
+                        # an admin op (another process's RPC) and the
+                        # migration it starts have moved the config.
+                        yield 0.01
                         continue
                     value = t.value if t.err == OK else ""
                     return EngineCmdReply(err=OK, value=value)
@@ -1010,7 +890,9 @@ class EngineShardKVService:
                     if self._fleet:
                         # Hosted by a peer process: tell the clerk.
                         return EngineCmdReply(err=ERR_WRONG_GROUP)
-                    yield 0.01  # shard unassigned; config still moving
+                    # Shard unassigned: as above, waits for an admin
+                    # op's RPC, not for a pump end.
+                    yield 0.01
                     continue
                 if self._fleet and self.skv.is_sealed(gid):
                     # Mid-placement-migration: every apply would be a
@@ -1034,32 +916,36 @@ class EngineShardKVService:
                 sub_deadline = min(
                     self.sched.now + self.RESUBMIT_S, deadline
                 )
-                while not t.done and self.sched.now < sub_deadline:
-                    yield 0.002
+                while not t.done and (
+                    yield from self.cycle.wait(sub_deadline, counted=True)
+                ):
+                    pass
                 if not t.done or t.failed or t.err == ERR_WRONG_GROUP:
                     continue  # resubmit / re-route; dedup-safe
                 if stages is not None:
                     # Commit observed; the fsync gate below lands in
                     # the ack leg (folded at dispatch completion).
                     stages.fold(self.m, "engine")
-                    # Tail attribution: carrying tick + parked time
-                    # (getattr: stub handlers built via __new__ in
-                    # tests carry no pump state).
-                    stages.tick = getattr(self, "_pumps", -1)
+                    # Tail attribution: carrying tick + parked time.
+                    stages.tick = self.cycle.seq
                     stages.pump_wait_s = max(
-                        0.0,
-                        getattr(self, "_pump_t_dispatch", 0.0)
-                        - t_parked,
+                        0.0, self.cycle.t_dispatch - t_parked
                     )
                 # Ack gates on the apply-time WAL record being fsynced
-                # (absent = pruned/duplicate = already durable).
+                # (absent = pruned/duplicate = already durable), checked
+                # at every pump end, where the group fsync lands; at the
+                # deadline the write answers ErrTimeout, never a false
+                # durable ack.
                 while self._dur is not None:
                     seq = self._write_seqs.get(
                         (args.client_id, args.command_id)
                     )
                     if seq is None or self._dur.synced(seq):
                         break
-                    yield 0.002
+                    if not (
+                        yield from self.cycle.wait(deadline, counted=True)
+                    ):
+                        return EngineCmdReply(err=ERR_TIMEOUT)
                 self.m.observe("kv.command_s", self.sched.now - t_start)
                 if rid is not None:
                     self.obs.tracer.instant(
@@ -1096,21 +982,22 @@ class EngineShardKVService:
             else:
                 t = getattr(self.skv, kind)(payload, command_id=cmd)
             deadline = self.sched.now + self.DEADLINE_S
-            while self.sched.now < deadline:
-                if t.done:
-                    if t.failed:
-                        return EngineCmdReply(err=ERR_TIMEOUT)
-                    # Ack gates on the apply-time ("admin", ...) WAL
-                    # record (logged by the on_ctrl hook in commit
-                    # order) being fsynced.
-                    while self._dur is not None:
-                        seq = self._admin_seqs.get(t.command_id)
-                        if seq is None or self._dur.synced(seq):
-                            break
-                        yield 0.002
-                    return EngineCmdReply(err=OK)
-                yield 0.005
-            return EngineCmdReply(err=ERR_TIMEOUT)
+            while not t.done:
+                if not (yield from self.cycle.wait(deadline)):
+                    return EngineCmdReply(err=ERR_TIMEOUT)
+            if t.failed:
+                return EngineCmdReply(err=ERR_TIMEOUT)
+            # Ack gates on the apply-time ("admin", ...) WAL record
+            # (logged by the on_ctrl hook in commit order) being
+            # fsynced; ErrTimeout at the deadline, never a false
+            # durable ack.
+            while self._dur is not None:
+                seq = self._admin_seqs.get(t.command_id)
+                if seq is None or self._dur.synced(seq):
+                    break
+                if not (yield from self.cycle.wait(deadline)):
+                    return EngineCmdReply(err=ERR_TIMEOUT)
+            return EngineCmdReply(err=OK)
 
         return run()
 

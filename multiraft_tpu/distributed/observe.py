@@ -142,7 +142,7 @@ def _rss_mb() -> Optional[float]:
 #   cpu.handler_s   synchronous handler execution; engine write ops
 #                   add their per-submit binding cost from the
 #                   generator body (engine_server.command)
-#   cpu.engine_s    pump tick CPU (engine_server._pump_loop) — the
+#   cpu.engine_s    pump tick CPU (pump_cycle.PumpCycle) — the
 #                   engine stage's CPU *is* the pump
 #   cpu.ack_s       completion bookkeeping (tcp._dispatch._done)
 #   cpu.flush_s     reply encode + vectored write (tcp._flush_replies)

@@ -798,7 +798,8 @@ class EngineDriver:
         land here while the serving loop still has dispatched batches
         in flight: drain them first, in dispatch order — safe because
         ``step`` already must run on the owning thread, and the serving
-        loop's ``_pump_done`` ignores batches completed from under it."""
+        loop's ``PumpCycle._pump_done`` ignores batches completed from
+        under it."""
         while self._inflight:
             p = self._inflight[0]
             self.complete_ticks(p, p.fetch())
@@ -806,6 +807,13 @@ class EngineDriver:
             pending = self.dispatch_ticks(n)
             return self.complete_ticks(pending, pending.fetch())
         return self._step_serial(n)
+
+    @property
+    def pipeline_on(self) -> bool:
+        """``MRT_ENGINE_PIPELINE`` as this driver read it, the knob's
+        one reader: the serving pump cycle asks here whether to start
+        its pump thread."""
+        return self._pipeline_on
 
     def fused_eligible(self) -> bool:
         """True when the fused scan path may run: pipeline enabled and

@@ -18,7 +18,7 @@ test.  ``gap``,
 ``handoff`` (the pump thread's wake-up: its condition variable, then
 the GIL the loop still holds) and ``post`` (the completion's wait for
 the loop) enclose no code: they are waits between stamps, observed
-where the stamps meet — ``EngineKVService`` and
+where the stamps meet — ``PumpCycle`` (distributed/pump_cycle.py) and
 ``EngineDriver.complete_ticks``.
 
 This module lives in ``engine/`` because jax is already imported here:

@@ -103,18 +103,15 @@ KNOBS: Tuple[Knob, ...] = (
          "the child fails if that backend does not come up. Empty = "
          "no pin, the child runs on what JAX selects from the "
          "launcher's environment."),
-    # -- distributed.engine_pump ---------------------------------------------
-    Knob("MRT_PIPELINE_DEPTH", "int", 2, "distributed.engine_pump",
+    # -- distributed.pump_cycle ----------------------------------------------
+    Knob("MRT_PIPELINE_DEPTH", "int", 2, "distributed.pump_cycle",
          "In-flight fused tick batches the pipelined pump keeps "
          "dispatched (overlaps host bookkeeping with device compute); "
          "durable servers pin it to 1 so every checkpoint sees a "
          "drained pipeline."),
-    Knob("MRT_PUMP_IDLE_S", "float", 0.002, "distributed.engine_pump",
+    Knob("MRT_PUMP_IDLE_S", "float", 0.002, "distributed.pump_cycle",
          "Idle engine-pump cadence in seconds (the adaptive cadence's "
          "slow interval when no traffic is flowing)."),
-    Knob("MRT_PUMP_TICKS", "int", 0, "distributed.engine_pump",
-         "Fused device ticks per dispatched pipeline batch (0 = the "
-         "server's ticks_per_pump)."),
     # -- distributed.flightrec ----------------------------------------------
     Knob("MRT_FLIGHTREC_DIR", "str", None, "distributed.flightrec",
          "Directory for the crash-safe flight-recorder rings; unset "

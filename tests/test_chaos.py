@@ -659,6 +659,7 @@ def _firehose_reply(synced: bool):
     whose fsync either lands or never does."""
     from multiraft_tpu.distributed.engine_server import EngineKVService
     from multiraft_tpu.engine.firehose import FirehoseFrame, unpack_reply
+    from multiraft_tpu.utils.metrics import Metrics
 
     blob = _frame_blob(
         [1, 0], [0, 0], [7, 7], [1, 0], [b"k", b"k"], [b"v", b""],
@@ -677,6 +678,15 @@ def _firehose_reply(synced: bool):
     )
     svc._dur = types.SimpleNamespace(synced=lambda seq: synced)
     svc._write_seqs = {(7, 1): 42}
+    svc.m = Metrics()
+
+    def wait(until):  # PumpCycle.wait, on the stub clock: no pump ever ends
+        if svc.sched.now >= until:
+            return False
+        yield until - svc.sched.now
+        return True
+
+    svc.cycle = types.SimpleNamespace(wait=wait)
     out = _drive(svc.firehose(blob), svc.sched)
     return unpack_reply(out)
 

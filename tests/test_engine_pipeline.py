@@ -293,8 +293,8 @@ def test_pipelined_service_serves_and_reports():
             return EngineKVService(sched, BatchedKV(d), obs=obs)
 
         svc = sched.run_call(build, timeout=150)
-        assert svc._pipe is not None
-        assert svc._pipe._thread.name.startswith("multiraft-pump")
+        assert svc.cycle.pipe is not None
+        assert svc.cycle.pipe._thread.name.startswith("multiraft-pump")
         t = sched.run_call(lambda: svc.kv.submit(
             0, KVOp(op=OP_PUT, key="a", value="1",
                     client_id=1, command_id=1)))

@@ -26,7 +26,9 @@ jax = pytest.importorskip("jax")
 from multiraft_tpu.distributed.engine_server import (  # noqa: E402
     EngineClerk,
     EngineKVService,
+    EngineShardNetClerk,
     serve_engine_kv,
+    serve_engine_shardkv,
 )
 from multiraft_tpu.distributed.tcp import RpcNode  # noqa: E402
 from multiraft_tpu.engine.core import EngineConfig  # noqa: E402
@@ -48,20 +50,31 @@ TILE_TOL = 0.05
 CYCLE_S = 0.012
 
 
-@pytest.fixture(params=[0, 4], ids=["one-device", "mesh4"])
+@pytest.fixture(
+    params=[("kv", 0), ("kv", 4), ("shardkv", 0)],
+    ids=["one-device", "mesh4", "shardkv"],
+)
 def served(request, tmp_path, monkeypatch):
     """A durable ``serve-kv`` node in this process: IoScheduler loop,
     pump thread, WAL, a checkpoint every 0.4 s; on one device, and with
-    the groups sharded over four (``--mesh-devices 4``)."""
-    mesh = request.param
+    the groups sharded over four (``--mesh-devices 4``).  And a durable
+    ``serve-shardkv`` node: the sharded service composes the same cycle
+    (distributed/pump_cycle.py), so the same clocks tile its loop."""
+    kind, mesh = request.param
     if len(jax.devices()) < mesh:
         pytest.skip(f"need {mesh} devices")
     monkeypatch.setenv("MRT_PUMP_IDLE_S", str(CYCLE_S))
     monkeypatch.setenv("MRT_PUMP_HOT", "0")  # one cadence, busy or not
-    node = serve_engine_kv(
-        port=0, G=8 if mesh else 4, data_dir=str(tmp_path),
-        checkpoint_every_s=0.4, mesh_devices=mesh,
-    )
+    if kind == "kv":
+        node = serve_engine_kv(
+            port=0, G=8 if mesh else 4, data_dir=str(tmp_path),
+            checkpoint_every_s=0.4, mesh_devices=mesh,
+        )
+    else:
+        node = serve_engine_shardkv(
+            port=0, G=4, join_gids=[1], data_dir=str(tmp_path),
+            checkpoint_every_s=0.4,
+        )
     client = RpcNode()
     try:
         yield node, client
@@ -85,7 +98,8 @@ def _cycle_snapshots(svc, pumps, cap_s=60.0):
     names = [f"pump.{p}_s" for p in PHASES] + ["ckpt.save_s"]
     snaps = []
     done = threading.Event()
-    inner = svc._after_pump_durability
+    cycle = svc.cycle
+    inner = cycle._end_cycle
 
     def at_cycle_end():
         inner()
@@ -94,7 +108,7 @@ def _cycle_snapshots(svc, pumps, cap_s=60.0):
         n = svc.m.counters["pump.count"]
         if not snaps or n >= snaps[0]["pumps"] + pumps:
             snaps.append({
-                "pumps": n, "t": svc._t_cycle_end,
+                "pumps": n, "t": cycle.t_end,
                 "bytes": svc.m.counters["pump.readback_bytes"],
                 "copies": svc.m.counters["pump.readback_copies"],
                 "hists": _hist_state(svc.m, names),
@@ -102,17 +116,18 @@ def _cycle_snapshots(svc, pumps, cap_s=60.0):
             if len(snaps) == 2:
                 done.set()
 
-    svc._after_pump_durability = at_cycle_end
+    cycle._end_cycle = at_cycle_end
     try:
         assert done.wait(cap_s), "the pump loop did not run"
     finally:
-        svc._after_pump_durability = inner
+        cycle._end_cycle = inner
     return snaps
 
 
 def _put_some(node, client, n):
     end = client.client_end("127.0.0.1", node.port)
-    ck = EngineClerk(client.sched, end)
+    sharded = hasattr(node.engine_service, "skv")
+    ck = (EngineShardNetClerk if sharded else EngineClerk)(client.sched, end)
     for i in range(n):
         out = client.sched.wait(
             client.sched.spawn(ck.put(f"k{i % 7}", f"v{i}")), 30.0
@@ -124,9 +139,9 @@ def _put_some(node, client, n):
 def test_phases_tile_the_durable_pump_cycle(served):
     node, client = served
     svc = node.engine_service
-    driver = svc.kv.driver
+    driver = svc.cycle.engine.driver
     shards = driver.mesh.devices.size if driver.mesh is not None else 1
-    assert svc._depth == 1 and svc._pipe is not None
+    assert svc.cycle.depth == 1 and svc.cycle.pipe is not None
     assert driver.fused_eligible()  # a mesh server pipelines like any other
     writer = threading.Thread(target=_put_some, args=(node, client, 25))
     writer.start()
@@ -150,7 +165,7 @@ def test_phases_tile_the_durable_pump_cycle(served):
     # (on a mesh the scalar records are one lane a device), in one copy
     # for every array and device that holds a shard of it.
     twin = EngineDriver(driver.cfg, seed=1, mesh=driver.mesh)
-    p = twin.dispatch_ticks(svc._ticks)
+    p = twin.dispatch_ticks(svc.cycle.ticks)
     per_pump = sum(v.size * v.dtype.itemsize for v in p.rec.values())
     for v in p.rec.values():
         assert len(v.addressable_shards) == shards
@@ -184,6 +199,8 @@ def test_loop_account_tiles_the_loop_threads_wall(served):
     m = snap["metrics"]
     assert m["loop.timer_s"] >= timer1 and m["loop.idle_s"] >= idle1
     assert m["loop.io_s"] >= io1 and m["loop.polls"] >= polls1
+    if hasattr(node.engine_service, "skv"):
+        return  # serve_engine_kv alone publishes what follows
     # the compile counter and time to ready ride the same scrape
     assert m["engine.compiles"] >= 0 and "ready.warm_s" in m
     assert m["ready.checkpoint_s"] > 0.0 and m["ready.restore_s"] == 0.0
@@ -256,7 +273,7 @@ def test_sync_pump_gets_apply_and_sync_and_nothing_else(tmp_path, monkeypatch):
             return EngineKVService(sched, kv, durability=dur, obs=obs)
 
         svc = sched.run_call(build, timeout=150)
-        assert svc._pipe is None
+        assert svc.cycle.pipe is None
         deadline = time.monotonic() + 30
         while (time.monotonic() < deadline
                and obs.metrics.counters["pump.count"] < 5):
