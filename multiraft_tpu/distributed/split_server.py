@@ -213,7 +213,7 @@ class SplitPersistence:
         self.wal.rotate()
         self._last_snap = time.monotonic()
         for g in gs:
-            self.peering.gc_floor[g] = self.kv.applied_upto[g]
+            self.peering.gc_floor[g] = int(self.kv.applied_upto[g])
 
     # -- recovery ----------------------------------------------------------
 
@@ -290,7 +290,7 @@ class SplitPersistence:
                 kv.replay_apply(g, idx, payload)
             kv.applied_upto[g] = idx
         for g in peering.split_gs:
-            peering.gc_floor[g] = kv.applied_upto[g]
+            peering.gc_floor[g] = int(kv.applied_upto[g])
         return True
 
 

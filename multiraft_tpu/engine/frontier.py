@@ -33,7 +33,11 @@ class FrontierService:
 
     def __init__(self, driver: EngineDriver) -> None:
         self.driver = driver
-        self.applied_upto = [0] * driver.cfg.G
+        # Per-group applied index (the read index engine/kv.py ``get``
+        # documents).  A numpy vector so the sweep can compare it with
+        # the commit frontier in one operation; checkpoints carry it as
+        # a plain list of ints.
+        self.applied_upto = np.zeros(driver.cfg.G, np.int64)
         driver.on_payload_evicted = self._on_evicted
         self._sweep_countdown = self.ORPHAN_SWEEP_TICKS
         # Entries applied by the LAST pump's sweep — the serving pump
@@ -77,10 +81,10 @@ class FrontierService:
         """Service state to checkpoint alongside the engine — pass as
         ``driver.save(path, extra=svc.state_dict())`` so both snapshot
         the same tick boundary.  Subclasses extend."""
-        return {"applied_upto": list(self.applied_upto)}
+        return {"applied_upto": self.applied_upto.tolist()}
 
     def load_state_dict(self, blob: Dict[str, Any]) -> None:
-        self.applied_upto = list(blob["applied_upto"])
+        self.applied_upto = np.array(blob["applied_upto"], np.int64)
 
     # -- the loop ----------------------------------------------------------
 
@@ -105,10 +109,14 @@ class FrontierService:
         commit = np.asarray(self.driver.last_metrics["commit_index"])
         now = self.driver.tick
         applied = 0
-        for g in range(self.driver.cfg.G):
+        # Only the groups whose commit moved past their applied index,
+        # in ascending order: what a walk over every group would visit.
+        moved = np.flatnonzero(commit > self.applied_upto)
+        self.driver.metrics.inc("apply.groups_swept", len(moved))
+        for g in moved.tolist():
             upto = int(commit[g])
             while self.applied_upto[g] < upto:
-                idx = self.applied_upto[g] + 1
+                idx = int(self.applied_upto[g]) + 1
                 # pop: an applied payload is never needed again (host
                 # memory stays bounded under a sustained firehose) —
                 # unless split-group resends still need it (see

@@ -753,7 +753,7 @@ class BatchedShardKV(FrontierService):
         commit = int(
             np.asarray(self.driver.last_metrics["commit_index"])[loc]
         )
-        return self.applied_upto[loc] >= commit
+        return bool(self.applied_upto[loc] >= commit)
 
     def drop_gid(self, gid: int) -> None:
         """Free ``gid``'s engine slot after a migration (or an abandoned

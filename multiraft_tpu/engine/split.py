@@ -512,7 +512,7 @@ class SplitFrontierMixin:
         self.driver.state = st._replace(
             applied=fn(
                 st.applied, st.base, st.commit,
-                jnp.asarray(np.asarray(self.applied_upto, np.int32)),
+                jnp.asarray(self.applied_upto.astype(np.int32)),
             )
         )
 
@@ -611,7 +611,7 @@ class SplitKV(SplitFrontierMixin, BatchedKV):
         """Applied state of group ``g`` for an InstallSnapshot slab:
         the kvraft snapshot payload (KV map + dup table,
         reference: kvraft/server.go:159-183) at the applied frontier."""
-        return self.applied_upto[g], {
+        return int(self.applied_upto[g]), {
             "data": dict(self.data[g]),
             "sessions": dict(self.sessions[g]),
         }

@@ -216,14 +216,14 @@ class SplitShardKV(SplitFrontierMixin, BatchedShardKV):
         slots otherwise (pending tickets are per-process volatile state
         and never travel)."""
         if g == 0:
-            return self.applied_upto[0], {
+            return int(self.applied_upto[0]), {
                 "kind": "ctrl",
                 "configs": [_config_to_wire(c) for c in self.configs],
                 "latest": {int(k): int(v)
                            for k, v in self._ctrl_latest.items()},
             }
         rep = self.reps[self._l2g[g]]
-        return self.applied_upto[g], {
+        return int(self.applied_upto[g]), {
             "kind": "rep",
             "cur": _config_to_wire(rep.cur),
             "prev": _config_to_wire(rep.prev),
