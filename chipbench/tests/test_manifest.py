@@ -19,7 +19,7 @@ def test_every_cell_resolves_to_its_files():
         e2e = {m["name"] for m in cell["end_to_end"]}
         assert "setup_s" in e2e and len(e2e) >= 2
         assert cell["layers"], "every cell reports a per-layer metric"
-        assert w["chips"] == 1
+        assert w["chips"] in (1, 4)
 
 
 def test_configs_are_files_under_paths_and_state_what_the_manifest_says():
@@ -49,6 +49,33 @@ def test_a_layer_metric_is_only_where_the_metric_it_moves_is_reported():
         reported = {m["name"] for m in cell["end_to_end"]}
         for spec in cell["layers"]:
             assert spec["moves"] in reported, (w["name"], spec["name"])
+
+
+# Every cell carries these two; every other end-to-end metric is opt-in:
+# a cell joins its list only where its own two sets of runs let the bound
+# resolve (README.md, "Add a cell").
+IN_EVERY_CELL = {"update_p50_ms", "setup_s"}
+
+
+def test_every_end_to_end_metric_but_the_two_every_cell_carries_is_opt_in():
+    for m in MAN["end_to_end"]:
+        assert ("workloads" not in m) == (m["name"] in IN_EVERY_CELL), m["name"]
+
+
+def test_a_workloads_list_is_not_empty_and_names_cells():
+    cells = {w["name"] for w in MAN["workloads"]}
+    for m in MAN["end_to_end"] + MAN["per_layer"]:
+        if "workloads" in m:
+            assert m["workloads"], m["name"]
+            assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+            assert set(m["workloads"]) <= cells, m["name"]
+
+
+def test_a_layer_metric_for_every_cell_moves_a_metric_of_every_cell():
+    listed = {m["name"] for m in MAN["end_to_end"] if "workloads" in m}
+    for m in MAN["per_layer"]:
+        if "workloads" not in m:
+            assert m["moves"] not in listed, m["name"]
 
 
 def test_unknown_workload_is_an_error():

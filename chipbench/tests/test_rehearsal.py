@@ -27,8 +27,8 @@ def test_rehearsal_prints_the_contract_line(trace):
     want = {"correct", "attempted", "failed", "metrics", "device"}
     assert set(line) == (want | {"breakdown"} if trace else want)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
-    man = manifest.manifest()
-    names = {m["name"] for m in man["per_layer" if trace else "end_to_end"]}
+    cell = manifest.cell(CELL)  # the cell's own lists: tails are opt-in
+    names = {m["name"] for m in cell["layers" if trace else "end_to_end"]}
     assert set(line["metrics"]) <= names
     if not trace:
         assert set(line["metrics"]) == names
