@@ -30,6 +30,10 @@ anything but a TPU fails the run.  Legs, in order:
             back to the synchronous pump fails), then ``kill -9``,
             restart on the same --data-dir, every sampled acknowledged write read back
             exactly once, and a SIGTERM that must exit 0.
+``served5`` the same served path with ``--replicas 5`` (BASELINE.json
+            config 5's replica count: 50,000 replicas' state), and
+            then a start with ``--replicas 3`` on that --data-dir,
+            which must refuse by name and serve nothing.
 ``mesh4``   the sharded tick on a 4-device ``groups`` mesh and the same
             served path with ``--mesh-devices 4``.  Runs when the
             chip-holding child reports >= 4 devices; the output says in
@@ -59,7 +63,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-LEGS = ("tick", "bench", "served", "mesh4")
+LEGS = ("tick", "bench", "served", "served5", "mesh4")
 BUDGET_S = 1150.0  # the contract allows 1200 s, compilation included
 
 _T0 = time.monotonic()
@@ -353,7 +357,7 @@ class Server:
     """One ``serve-kv`` child on ``data_dir``."""
 
     def __init__(self, data_dir: str, err_path: str, rehearse: bool,
-                 groups: int, mesh: int, seed: int) -> None:
+                 groups: int, mesh: int, seed: int, replicas: int = 3) -> None:
         from multiraft_tpu.distributed.launch import reserve_ports
 
         self.port = reserve_ports(1, "127.0.0.1")[0]
@@ -362,7 +366,9 @@ class Server:
             "--platform", "cpu" if rehearse else "tpu",
             "--groups", str(groups), "--data-dir", data_dir,
             "--seed", str(seed), "--port", str(self.port),
-        ] + (["--mesh-devices", str(mesh)] if mesh else [])
+        ] + (["--mesh-devices", str(mesh)] if mesh else []) + (
+            ["--replicas", str(replicas)] if replicas != 3 else []
+        )
         self.err_path = err_path
         self.t_start = time.monotonic()
         with open(err_path, "w") as err:
@@ -414,7 +420,9 @@ def cache_entries() -> int:
         return 0
 
 
-def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
+def leg_served(
+    rehearse: bool, seed: int, mesh: int = 0, replicas: int = 3
+) -> Dict[str, Any]:
     import random
 
     from multiraft_tpu.distributed.engine_cluster import BlockingEngineClerk
@@ -426,7 +434,9 @@ def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
     from multiraft_tpu.porcupine.model import CheckResult
     from multiraft_tpu.sim.scheduler import TIMEOUT
 
-    name = f"served(mesh={mesh})" if mesh else "served"
+    name = "served" + (str(replicas) if replicas != 3 else "")
+    if mesh:
+        name += f"(mesh={mesh})"
     sz = sizes(rehearse)
     G, n_keys, n_sample = sz["served_G"], sz["keys"], sz["sample"]
     rng = random.Random(seed)
@@ -467,9 +477,9 @@ def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
 
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
         data_dir = os.path.join(tmp, "data")
-        mk = lambda i: Server(
+        mk = lambda i, replicas=replicas: Server(
             data_dir, os.path.join(tmp, f"server{i}.err"), rehearse, G,
-            mesh, seed,
+            mesh, seed, replicas,
         )
         srv = None
         try:
@@ -488,12 +498,16 @@ def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
             require(isinstance(info, dict), f"{name}: info said {info!r}")
             require(info["G"] == G, f"{name}: serves G={info['G']}")
             require(
+                info["P"] == replicas,
+                f"{name}: serves P={info['P']}, asked for {replicas}",
+            )
+            require(
                 info["state_devices"] == (mesh or 1),
                 f"{name}: state on {info['state_devices']} device(s), "
                 f"expected {mesh or 1}",
             )
             say(
-                f"{name}: G={G} x P=3, consensus state spread over "
+                f"{name}: G={G} x P={replicas}, consensus state spread over "
                 f"{info['state_devices']} device(s)"
             )
             before = snapshot(node, end)
@@ -607,6 +621,29 @@ def leg_served(rehearse: bool, seed: int, mesh: int = 0) -> Dict[str, Any]:
                 f"{name}: no engine.ckpt after SIGTERM",
             )
             say(f"{name}: SIGTERM -> final checkpoint, exit 0")
+
+            # -- another replica count on that dir: refused by name ----
+            if replicas != 3:
+                srv = mk(3, replicas=3)
+                try:
+                    rc = srv.proc.wait(timeout=remaining(240.0))
+                except subprocess.TimeoutExpired:
+                    raise LegFailed(
+                        f"{name}: --replicas 3 on a --data-dir written "
+                        f"at {replicas} did not refuse"
+                    ) from None
+                said = srv.stderr_tail()
+                require(
+                    rc != 0 and f"{replicas} replicas" in said
+                    and "asked for 3" in said,
+                    f"{name}: --replicas 3 on that --data-dir: exit {rc}",
+                )
+                require(
+                    "ready" not in srv.proc.stdout.read(),
+                    f"{name}: the refused server said it was ready",
+                )
+                say(f"{name}: --replicas 3 on the same --data-dir refused: "
+                    f"{said.rsplit(' | ', 1)[-1][:160]}")
         except (LegFailed, TimeoutError) as exc:  # a blocking clerk gave up
             tail = srv.stderr_tail() if srv is not None else ""
             raise LegFailed(f"{exc} [server stderr: {tail}]") from None
@@ -743,6 +780,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     device = leg_bench(rehearse)
                 elif leg == "served":
                     device = leg_served(rehearse, ns.seed)
+                elif leg == "served5":
+                    device = leg_served(rehearse, ns.seed, replicas=5)
                 else:
                     if _claimed and _claimed[-1]["count"] < 4:
                         # An earlier chip-holding child already said how

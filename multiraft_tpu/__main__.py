@@ -46,7 +46,14 @@ def _serve_forever(args, build) -> int:
         print(f"error: {exc}", file=sys.stderr, flush=True)
         return 1
     backend_s = time.perf_counter() - t0  # import jax to the first device
-    node = build()
+    try:
+        node = build()
+    except ValueError as exc:
+        # What the flags ask for cannot be served: a shape EngineConfig
+        # refuses, or a --data-dir written at another replica count or
+        # mesh size.
+        print(f"error: {exc}", file=sys.stderr, flush=True)
+        return 1
     # Time to ready by stage: gauges in every Obs.snapshot, and one line.
     stages = f"backend={backend_s:.3f}" + "".join(
         f" {name[len('ready.'):-len('_s')]}={secs:.3f}"
@@ -92,6 +99,7 @@ def _cmd_serve_kv(args) -> int:
             data_dir=args.data_dir,
             checkpoint_every_s=args.checkpoint_every,
             mesh_devices=args.mesh_devices,
+            replicas=args.replicas,
         )
 
     return _serve_forever(args, build)
@@ -120,6 +128,7 @@ def _cmd_serve_shardkv(args) -> int:
             data_dir=args.data_dir,
             checkpoint_every_s=args.checkpoint_every,
             mesh_devices=args.mesh_devices,
+            replicas=args.replicas,
         )
 
     return _serve_forever(args, build)
@@ -157,12 +166,30 @@ def _cmd_kv(args) -> int:
         node.close()
 
 
+def _replicas(text: str) -> int:
+    """``--replicas``: a group commits with a majority, so an even count
+    buys no failure the odd count below it does not survive, and fewer
+    than 3 survive none.  The upper limit is ``EngineConfig``'s."""
+    n = int(text)
+    if n < 3 or n % 2 == 0:
+        raise argparse.ArgumentTypeError(
+            f"{n}: replicas a group must be odd and at least 3 (a group "
+            f"of 2f+1 survives f failed replicas; an even count adds "
+            f"none)"
+        )
+    return n
+
+
 def _add_serve_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--port", type=int, default=0,
                    help="listen port (0 = ephemeral, printed on ready)")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--groups", type=int, default=64,
                    help="engine consensus groups (G)")
+    p.add_argument("--replicas", type=_replicas, default=3, metavar="N",
+                   help="replicas a group (odd, >= 3; default 3): a group "
+                        "keeps serving with N//2 of them down.  A "
+                        "--data-dir written at another N is refused")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--data-dir", default=None,
                    help="enable durability (checkpoints + WAL) here")

@@ -270,12 +270,14 @@ class EngineKVService:
     MAX_FIREHOSE = MAX_FIREHOSE_ROWS
 
     def info(self, _args=None) -> dict:
-        """Topology: ``G`` is what the columnar clerks route by;
-        ``state_devices`` counts the devices that hold a shard of the
-        consensus state (1, or the mesh size when it really is spread)."""
+        """Topology: ``G`` is what the columnar clerks route by, ``P``
+        the replicas a group; ``state_devices`` counts the devices that
+        hold a shard of the consensus state (1, or the mesh size when it
+        really is spread)."""
         shards = self.kv.driver.state.term.addressable_shards
         return {
             "G": self.G,
+            "P": self.kv.driver.cfg.P,
             "state_devices": len({s.device for s in shards}),
         }
 
@@ -476,11 +478,12 @@ def serve_engine_kv(
     data_dir: Optional[str] = None,
     checkpoint_every_s: float = 30.0,
     mesh_devices: int = 0,
+    replicas: int = 3,
 ) -> RpcNode:
     """Bring up the chip-owning engine KV server process: one
-    EngineDriver (G groups), a BatchedKV, the pump loop, and a
-    listening RpcNode.  Returns the node (caller keeps the process
-    alive).
+    EngineDriver (G groups of ``replicas`` replicas each), a BatchedKV,
+    the pump loop, and a listening RpcNode.  Returns the node (caller
+    keeps the process alive).
 
     With ``data_dir``, the server is DURABLE: periodic atomic
     checkpoints + a write-ahead log of acked ops (see EngineDurability)
@@ -491,7 +494,11 @@ def serve_engine_kv(
     that many local chips (G must divide evenly) and the same fused,
     asynchronous pump runs one ``shard_map`` program over them — the
     multi-chip production path; checkpoints restore back onto the
-    same-size mesh.  Gauge ``engine.mesh_devices`` says how many."""
+    same-size mesh.  Gauge ``engine.mesh_devices`` says how many.
+
+    A group commits with a majority of its ``replicas`` (gauge
+    ``engine.replicas``).  A ``data_dir`` whose checkpoint was written
+    at another replica count is refused (ValueError naming both)."""
     node = RpcNode(listen=True, host=host, port=port)
     sched = node.sched
     metrics = node.obs.metrics
@@ -520,7 +527,9 @@ def serve_engine_kv(
         if data_dir:
             ckpt = os.path.join(data_dir, "engine.ckpt")
             if os.path.exists(ckpt):
-                driver = EngineDriver.restore(ckpt, mesh=mesh)
+                driver = EngineDriver.restore(
+                    ckpt, mesh=mesh, replicas=replicas
+                )
         if driver is not None:
             metrics.inc("engine.restores")
             kv = BatchedKV(driver, record_groups=list(record_groups or []))
@@ -533,7 +542,7 @@ def serve_engine_kv(
             # bench serves G=256 at INGEST=24; defaults match the
             # round-2 serving shape).
             cfg = EngineConfig(
-                G=G, P=3,
+                G=G, P=replicas,
                 L=int(os.environ.get("MULTIRAFT_SERVE_L", "64")),
                 E=int(os.environ.get("MULTIRAFT_SERVE_E", "8")),
                 INGEST=int(os.environ.get("MULTIRAFT_SERVE_INGEST", "8")),
@@ -583,10 +592,15 @@ def serve_engine_kv(
             lap("checkpoint", t)
         return svc
 
-    svc = sched.run_call(build, timeout=600.0)
+    try:
+        svc = sched.run_call(build, timeout=600.0)
+    except BaseException:
+        node.close()  # a refused start leaves no listener behind
+        raise
     for stage, secs in ready.items():
         metrics.set(f"ready.{stage}_s", secs)
     metrics.set("engine.mesh_devices", float(mesh_devices))
+    metrics.set("engine.replicas", float(svc.kv.driver.cfg.P))
     node.add_service("EngineKV", svc)
     node.engine_service = svc  # keep reachable for introspection
     # Overload watch (overload.py): windowed stage-p99 + queue-gauge

@@ -1060,19 +1060,23 @@ def serve_engine_shardkv(
         for g in local_gids:
             placement0[int(g)] = (host, int(port))
 
+    n_replicas = max(3, int(replicas))
+
     def build():
         mesh = make_mesh(mesh_devices) if mesh_devices else None
         driver = None
         if data_dir:
             ckpt = os.path.join(data_dir, "engine.ckpt")
             if os.path.exists(ckpt):
-                driver = EngineDriver.restore(ckpt, mesh=mesh)
+                driver = EngineDriver.restore(
+                    ckpt, mesh=mesh, replicas=n_replicas
+                )
         restored = driver is not None
         if restored:
             node.obs.metrics.inc("engine.restores")
         if not restored:
             cfg = EngineConfig(
-                G=G_local, P=max(3, int(replicas)), L=64, E=8, INGEST=8
+                G=G_local, P=n_replicas, L=64, E=8, INGEST=8
             )
             driver = EngineDriver(cfg, seed=seed, mesh=mesh)
             if voters is not None and len(set(voters)) < cfg.P:

@@ -826,13 +826,20 @@ class EngineDriver:
             and not self._delayed
         )
 
+    def _count_ticks(self, n: int) -> None:
+        """``ticks``, and the replicas those ticks advanced
+        (``engine.replica_ticks`` / ``ticks`` = G x P: a scrape says
+        which deployment this driver holds)."""
+        self.metrics.inc("ticks", n)
+        self.metrics.inc("engine.replica_ticks", n * self.cfg.G * self.cfg.P)
+
     def _step_serial(self, n: int = 1) -> Dict[str, Any]:
         assert not self._inflight, (
             "serial step with fused tick batches in flight — complete "
             "them first, or the two tick streams interleave"
         )
         cfg = self.cfg
-        self.metrics.inc("ticks", n)
+        self._count_ticks(n)
         for _ in range(n):
             self.tick += 1
             t_wall = time.perf_counter() if self.tracer else 0.0
@@ -920,7 +927,7 @@ class EngineDriver:
         cfg = self.cfg
         t_dispatch = time.perf_counter()
         with pump_phase(self.metrics, "dispatch"):
-            self.metrics.inc("ticks", n)
+            self._count_ticks(n)
             tick0 = self.tick
             bl = np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
             if self.mesh is None:
@@ -1141,10 +1148,17 @@ class EngineDriver:
         return path
 
     @classmethod
-    def restore(cls, path: str, mesh=None) -> "EngineDriver":
+    def restore(
+        cls, path: str, mesh=None, replicas: Optional[int] = None
+    ) -> "EngineDriver":
         """Rebuild a driver from :meth:`save`.  The returned driver
         continues from the exact saved tick; the checkpoint's ``extra``
         dict is available as ``driver.restored_extra``.
+
+        The driver takes the checkpoint's own ``cfg``.  A caller that
+        was asked for a replica count passes it as ``replicas``: a
+        checkpoint written at another P is refused, never served under
+        a flag that says otherwise.
 
         A checkpoint taken from a mesh driver must be restored with a
         ``mesh`` (same device count) — silently coming back
@@ -1172,6 +1186,13 @@ class EngineDriver:
             raise ValueError(
                 f"checkpoint was taken on {saved_mesh} devices but "
                 f"restore got a {int(mesh.devices.size)}-device mesh"
+            )
+        if replicas is not None and blob["cfg"].P != replicas:
+            raise ValueError(
+                f"checkpoint {path} was written with {blob['cfg'].P} "
+                f"replicas a group, this server was asked for {replicas}: "
+                f"start it with --replicas {blob['cfg'].P} or on a fresh "
+                f"--data-dir"
             )
         d = object.__new__(cls)  # skip __init__: no throwaway device state
         d._init_host(blob["cfg"], seed=0)

@@ -36,15 +36,17 @@ def _check_rehearsal(out, legs):
 def test_rehearsal_runs_every_single_chip_leg_on_the_cpu():
     """Tiny shapes, Pallas interpreted, every check the chip run makes
     — and the output says 'rehearsal', so it cannot pass for one."""
-    out = _smoke("--rehearse-cpu", "--legs", "tick,bench,served")
+    out = _smoke("--rehearse-cpu", "--legs", "tick,bench,served,served5")
     summary = _check_rehearsal(
-        out, {"tick": "ran", "bench": "ran", "served": "ran"}
+        out, {"tick": "ran", "bench": "ran", "served": "ran", "served5": "ran"}
     )
     assert summary["partial"] is True  # mesh4 was not asked for
     assert "parity: pallas == jnp" in out.stdout
     assert "porcupine ok over" in out.stdout
     assert "present exactly once after restart" in out.stdout
     assert "SIGTERM -> final checkpoint, exit 0" in out.stdout
+    assert "served5: G=32 x P=5" in out.stdout
+    assert "served5: --replicas 3 on the same --data-dir refused" in out.stdout
 
 
 @pytest.mark.slow

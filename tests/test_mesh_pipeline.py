@@ -17,6 +17,7 @@ pipelined branch and that its phases tile is ``test_pump_phases.py``'s
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -77,17 +78,27 @@ def faults(kind: str, rnd: int, drivers) -> None:
                 d.partition_replica(1, 2, True)
 
 
-@pytest.mark.parametrize("kind", ["clean", "drop", "edges"])
-def test_fused_sharded_pump_is_the_single_device_serial_loop(mesh, kind):
+@pytest.mark.parametrize("kind, replicas", [
+    pytest.param("clean", 3, id="clean"),
+    pytest.param("drop", 3, id="drop"),
+    pytest.param("edges", 3, id="edges"),
+    # five replicas a group: the [G,P,P] planes shard like the [G,P] ones
+    pytest.param("clean", 5, id="clean-p5"),
+    pytest.param("edges", 5, id="edges-p5"),
+])
+def test_fused_sharded_pump_is_the_single_device_serial_loop(
+    mesh, kind, replicas
+):
     """Same seed, same submissions, same faults: the four-device fused
     scan against ``_step_serial`` on one device, pump after pump."""
-    sharded = EngineDriver(CFG, seed=7, mesh=mesh)
-    plain = EngineDriver(CFG, seed=7)
+    cfg = dataclasses.replace(CFG, P=replicas)
+    sharded = EngineDriver(cfg, seed=7, mesh=mesh)
+    plain = EngineDriver(cfg, seed=7)
     plain._pipeline_on = False
     assert sharded.fused_eligible() and not plain.fused_eligible()
     rng = np.random.default_rng(13)
     for rnd in range(14):
-        for g in range(CFG.G):
+        for g in range(cfg.G):
             for j in range(int(rng.integers(0, 6))):
                 for d in (sharded, plain):
                     d.start(g, ("cmd", rnd, g, j))
@@ -96,7 +107,7 @@ def test_fused_sharded_pump_is_the_single_device_serial_loop(mesh, kind):
         sharded.step(n)
         plain._step_serial(n)
         assert_same_world(sharded, plain, (kind, rnd))
-    assert sharded.commits_total > 5 * CFG.G and len(sharded.payloads) > 100
+    assert sharded.commits_total > 5 * cfg.G and len(sharded.payloads) > 100
     # The state never left the mesh, and the readback was per device.
     assert len(sharded.state.term.addressable_shards) == DEVICES
     c = sharded.metrics.counters
@@ -172,8 +183,9 @@ def _serve(tmp_path, **kw):
 
 
 @pytest.mark.timeout_s(300)
+@pytest.mark.parametrize("replicas", [3, 5], ids=["p3", "p5"])
 def test_mesh_server_restores_onto_its_mesh_and_keeps_every_acked_write(
-    mesh, tmp_path
+    mesh, tmp_path, replicas
 ):
     """Checkpoint, kill, ``restore(mesh=)`` under the fused pump: what a
     dict says after the acknowledged operations is what the restarted
@@ -188,11 +200,12 @@ def test_mesh_server_restores_onto_its_mesh_and_keeps_every_acked_write(
         return out
 
     model = {}
-    node = _serve(tmp_path, checkpoint_every_s=0.5)
+    node = _serve(tmp_path, checkpoint_every_s=0.5, replicas=replicas)
     client = RpcNode()
     try:
         svc = node.engine_service
         assert svc.kv.driver.mesh is not None and svc.kv.driver.fused_eligible()
+        assert svc.kv.driver.cfg.P == replicas
         ck = EngineClerk(client.sched, client.client_end("127.0.0.1", node.port))
         saves = lambda: node.obs.metrics.hists["ckpt.save_s"].count
         for i in range(30):
@@ -216,12 +229,17 @@ def test_mesh_server_restores_onto_its_mesh_and_keeps_every_acked_write(
         node.sched.run_call(node.engine_service.stop, timeout=30)
         node.close()
 
-    node = _serve(tmp_path, checkpoint_every_s=3600.0)
+    node = _serve(tmp_path, checkpoint_every_s=3600.0, replicas=replicas)
     client = RpcNode()
     try:
         m = node.obs.metrics
         assert m.counters["engine.restores"] == 1
         d = node.engine_service.kv.driver
+        assert d.cfg.P == replicas
+        # every [G,P,P] plane came back split over the mesh too
+        assert node.sched.run_call(
+            lambda: len(d.state.match_idx.addressable_shards), timeout=30
+        ) == DEVICES
         # on the loop: the pump donates the state it steps
         assert node.sched.run_call(
             lambda: len(d.state.term.addressable_shards), timeout=30

@@ -51,16 +51,17 @@ CYCLE_S = 0.012
 
 
 @pytest.fixture(
-    params=[("kv", 0), ("kv", 4), ("shardkv", 0)],
-    ids=["one-device", "mesh4", "shardkv"],
+    params=[("kv", 0, 3), ("kv", 4, 3), ("shardkv", 0, 3), ("kv", 0, 5)],
+    ids=["one-device", "mesh4", "shardkv", "one-device-p5"],
 )
 def served(request, tmp_path, monkeypatch):
     """A durable ``serve-kv`` node in this process: IoScheduler loop,
-    pump thread, WAL, a checkpoint every 0.4 s; on one device, and with
-    the groups sharded over four (``--mesh-devices 4``).  And a durable
+    pump thread, WAL, a checkpoint every 0.4 s; on one device, with
+    the groups sharded over four (``--mesh-devices 4``), and with five
+    replicas a group (``--replicas 5``).  And a durable
     ``serve-shardkv`` node: the sharded service composes the same cycle
     (distributed/pump_cycle.py), so the same clocks tile its loop."""
-    kind, mesh = request.param
+    kind, mesh, replicas = request.param
     if len(jax.devices()) < mesh:
         pytest.skip(f"need {mesh} devices")
     monkeypatch.setenv("MRT_PUMP_IDLE_S", str(CYCLE_S))
@@ -68,7 +69,7 @@ def served(request, tmp_path, monkeypatch):
     if kind == "kv":
         node = serve_engine_kv(
             port=0, G=8 if mesh else 4, data_dir=str(tmp_path),
-            checkpoint_every_s=0.4, mesh_devices=mesh,
+            checkpoint_every_s=0.4, mesh_devices=mesh, replicas=replicas,
         )
     else:
         node = serve_engine_shardkv(
@@ -204,8 +205,10 @@ def test_loop_account_tiles_the_loop_threads_wall(served):
     # the compile counter and time to ready ride the same scrape
     assert m["engine.compiles"] >= 0 and "ready.warm_s" in m
     assert m["ready.checkpoint_s"] > 0.0 and m["ready.restore_s"] == 0.0
-    mesh = node.engine_service.kv.driver.mesh
+    driver = node.engine_service.kv.driver
+    mesh = driver.mesh
     assert m["engine.mesh_devices"] == (mesh.devices.size if mesh else 0)
+    assert m["engine.replicas"] == driver.cfg.P
 
 
 def _host_lines_with(trace_dir, prefix):

@@ -433,14 +433,18 @@ def test_open_reconfig_on_a_real_driver_exempts_until_it_closes():
 
 
 @pytest.mark.timeout_s(240)
-@pytest.mark.parametrize("mesh", [0, 4], ids=["one-device", "mesh4"])
+@pytest.mark.parametrize(
+    "mesh, replicas", [(0, 3), (4, 3), (0, 5)],
+    ids=["one-device", "mesh4", "one-device-p5"],
+)
 def test_tag_carries_leader_and_term_read_at_the_scrape(
-    mesh, tmp_path, monkeypatch
+    mesh, replicas, tmp_path, monkeypatch
 ):
-    """A served driver (``serve-kv``'s node, one device and the groups
-    sharded over four): the tripped group's tag is the leader and term
-    its rows hold on the device at that scrape, and the server's own
-    watch, scraping all the while, has asked the device nothing."""
+    """A served driver (``serve-kv``'s node: one device, the groups
+    sharded over four, five replicas a group): the tripped group's tag
+    is the leader and term its rows hold on the device at that scrape,
+    and the server's own watch, scraping all the while, has asked the
+    device nothing."""
     import jax
 
     from multiraft_tpu.distributed.engine_server import serve_engine_kv
@@ -450,10 +454,11 @@ def test_tag_carries_leader_and_term_read_at_the_scrape(
     monkeypatch.setenv("MRT_WEDGE_INTERVAL", "0.02")
     node = serve_engine_kv(
         port=0, G=8 if mesh else 4, data_dir=str(tmp_path),
-        mesh_devices=mesh,
+        mesh_devices=mesh, replicas=replicas,
     )
     try:
         driver = node.engine_service.kv.driver
+        assert driver.cfg.P == replicas
         rec = _Rec()
         stub = types.SimpleNamespace(
             sched=types.SimpleNamespace(call_after=lambda *_a, **_k: None),
