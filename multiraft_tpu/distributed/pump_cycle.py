@@ -38,7 +38,8 @@ class PumpCycle:
     served engine.
 
     ``engine`` is the frontier service (``BatchedKV``,
-    ``BatchedShardKV``): ``.driver``, ``.pump(n)``, ``.after_step(n)``.
+    ``BatchedShardKV``): ``.driver``, ``.pump(n)``, ``.after_step(n)``,
+    ``.warm_orphan_sweep()``.
     ``after_step`` and ``warm`` replace the latter two where a service
     binds arguments of its own (the sharded service orchestrates
     migration on every served pump, and not while it is constructed).
@@ -121,6 +122,10 @@ class PumpCycle:
                 # sweep's opening step).  The backlog is empty at
                 # construction, so this is two liveness ticks.
                 (warm or engine.pump)(ticks)
+        # The orphan sweep's gather (engine/frontier.py) runs on every
+        # 32nd served pump, whichever path pumps: its one program is
+        # warmed here too, though nothing is bound yet.
+        engine.warm_orphan_sweep()
         sched.call_soon(self._pump_loop)
 
     # -- what a service and its handlers use ---------------------------------

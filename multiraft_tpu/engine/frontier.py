@@ -12,14 +12,19 @@ the sweep condition and eviction contract live in exactly one place.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from .core import LEADER
 from .host import EngineDriver, PayloadSlice
 from .instrument import pump_phase
 
 __all__ = ["FrontierService"]
+
+# What the orphan sweep reads of a bound group, in the order
+# ``_log_ends`` unpacks them.
+_ORPHAN_PLANES = ("role", "alive", "term", "base", "log_len")
 
 
 class FrontierService:
@@ -30,6 +35,13 @@ class FrontierService:
     (runs after each frontier sweep — orchestration goes here)."""
 
     ORPHAN_SWEEP_TICKS = 64
+    # Rows one gather of the orphan sweep asks of the device, however
+    # few groups hold a binding (a closed loop of 64 clients binds at
+    # most 64): a fixed width is ONE program a driver, which
+    # ``warm_orphan_sweep`` runs before the node is ready, so the sweep
+    # never compiles while clients are served.  More bound groups than
+    # this are read in chunks of the same width.
+    ORPHAN_SWEEP_ROWS = 256
 
     def __init__(self, driver: EngineDriver) -> None:
         self.driver = driver
@@ -173,18 +185,12 @@ class FrontierService:
         instead of waiting out the frame deadline."""
         if not self.driver.payloads:
             return 0
-        st = self.driver.np_state()
+        ends = self._log_ends(
+            list(dict.fromkeys(g for g, _ in self.driver.payloads))
+        )
         failed = 0
-        last_cache: Dict[int, Optional[int]] = {}
         for (g, idx) in list(self.driver.payloads.keys()):
-            if g not in last_cache:
-                p = self.driver.leader_of(g)
-                last_cache[g] = (
-                    None
-                    if p is None
-                    else int(st["base"][g, p] + st["log_len"][g, p])
-                )
-            last = last_cache[g]
+            last = ends[g]
             payload = self.driver.payloads.get((g, idx))
             count = payload.count if isinstance(payload, PayloadSlice) else 1
             if (
@@ -212,3 +218,41 @@ class FrontierService:
                 self._on_evicted(tail)
                 failed += 1
         return failed
+
+    def _log_ends(self, groups: List[int]) -> Dict[int, Optional[int]]:
+        """For each of ``groups``, the last log index of its leader, or
+        None where it has none.  The leader is the live replica in role
+        LEADER with the highest term, the lowest index among equals (the
+        driver's own per-group answer).  Reads those groups' rows
+        of five ``[G, P]`` planes, ``ORPHAN_SWEEP_ROWS`` at a time, and
+        nothing else of the state; like any read of it, it waits for a
+        tick batch in flight."""
+        m = self.driver.metrics
+        width = self.ORPHAN_SWEEP_ROWS
+        ends: Dict[int, Optional[int]] = {}
+        with pump_phase(m, "orphan", hist="apply.orphan_s"):
+            for at in range(0, len(groups), width):
+                chunk = groups[at:at + width]
+                idx = np.zeros(width, np.int32)  # padded with group 0
+                idx[:len(chunk)] = chunk
+                role, alive, term, base, log_len = self.driver.rows_stacked(
+                    _ORPHAN_PLANES, idx
+                )
+                m.inc("apply.orphan_rows", width)
+                lead = (role == LEADER) & (alive != 0)
+                # argmax takes the first of equals: the lowest index.
+                p = np.where(lead, term, np.iinfo(np.int32).min).argmax(axis=1)
+                last = (base + log_len)[np.arange(width), p]
+                for g, has, end in zip(
+                    chunk, lead.any(axis=1).tolist(), last.tolist()
+                ):
+                    ends[g] = end if has else None
+        m.inc("apply.orphan_sweeps")
+        return ends
+
+    def warm_orphan_sweep(self) -> None:
+        """Run the sweep's one gather program once, uncounted
+        (``PumpCycle`` calls this before the node is ready)."""
+        self.driver.rows_stacked(
+            _ORPHAN_PLANES, np.zeros(self.ORPHAN_SWEEP_ROWS, np.int32)
+        )

@@ -169,6 +169,20 @@ def _take_rows(planes, idx):
     return tuple(p[idx] for p in planes)
 
 
+@jax.jit
+def _take_rows_stacked(planes, idx):
+    return jnp.stack([p[idx].astype(jnp.int32) for p in planes])
+
+
+def _padded_index(groups) -> np.ndarray:
+    """``groups`` as an ``int32`` vector padded with group 0 to a power
+    of two (8 at least): the shape the gathers below compile for."""
+    idx = np.asarray(groups, np.int32)
+    padded = np.zeros(1 << max(3, (len(idx) - 1).bit_length()), np.int32)
+    padded[: len(idx)] = idx
+    return padded
+
+
 def _reconfig_open(st) -> np.ndarray:
     """Per group (row) of the host planes ``st``: in the joint phase, or
     the latest config entry not yet committed."""
@@ -1237,7 +1251,12 @@ class EngineDriver:
         d.restored_extra = blob["extra"]
         return d
 
-    # -- inspection (host readbacks; test/debug path) ---------------------
+    # -- host readbacks ----------------------------------------------------
+    # ``rows_of`` / ``rows_stacked`` gather the rows asked for and are
+    # what a served node calls (the orphan sweep on every 32nd pump, the
+    # wedge watch for a stalled group); everything built on ``np_state``
+    # copies every plane whole and is the membership, test and debug
+    # path.
 
     def np_state(self) -> Dict[str, np.ndarray]:
         return {k: np.asarray(v) for k, v in self.state._asdict().items()}
@@ -1250,15 +1269,27 @@ class EngineDriver:
         compiles at most log2(G) small programs.  Waits for the tick
         batch in flight, like any read of ``state``; call it on the
         owning (scheduler) thread."""
-        idx = np.asarray(groups, np.int32)
-        padded = np.zeros(1 << max(3, (len(idx) - 1).bit_length()), np.int32)
-        padded[: len(idx)] = idx
         rows = _take_rows(
-            tuple(getattr(self.state, name) for name in planes), padded
+            tuple(getattr(self.state, name) for name in planes),
+            _padded_index(groups),
         )
         return {
-            name: np.asarray(r)[: len(idx)] for name, r in zip(planes, rows)
+            name: np.asarray(r)[: len(groups)]
+            for name, r in zip(planes, rows)
         }
+
+    def rows_stacked(self, planes, groups) -> np.ndarray:
+        """:meth:`rows_of` for ``[G, P]`` planes as ONE ``int32`` array
+        ``[len(planes), len(groups), P]`` (a ``bool`` plane reads 0 / 1):
+        one copy off the device where :meth:`rows_of` makes one a plane
+        (0.9 ms each on the chip).  A caller on the serving path asks
+        for a fixed number of rows, so that it owns one program, and
+        runs it once before ``ready``."""
+        rows = _take_rows_stacked(
+            tuple(getattr(self.state, name) for name in planes),
+            _padded_index(groups),
+        )
+        return np.asarray(rows)[:, : len(groups)]
 
     def leaders_per_group(self) -> np.ndarray:
         st = self.np_state()
