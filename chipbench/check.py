@@ -67,9 +67,10 @@ class History:
         self.r_code.append(np.asarray(got, np.int64))
 
 
-def register_check(h: History) -> List[str]:
+def register_check(h: History) -> Tuple[List[str], Dict[str, int]]:
     """Every read against the rules above.  Returns what is wrong, at
-    most a few lines; empty means every read was allowed."""
+    most a few lines (empty: every read was allowed), and how many reads
+    broke each rule: the numbers ``correct`` compares, each with limit 0."""
     wk, wc, wr, wcode = map(np.concatenate, (h.w_key, h.w_call, h.w_ret, h.w_code))
     rk, rc, rr, rcode = map(np.concatenate, (h.r_key, h.r_call, h.r_ret, h.r_code))
     wrong: List[str] = []
@@ -112,7 +113,8 @@ def register_check(h: History) -> List[str]:
                 )
     if stale:
         wrong.append(f"{stale} stale reads in all")
-    return wrong
+    return wrong, {"reads_of_values_nobody_wrote": int((~known).sum()),
+                   "reads_from_the_future": int(future.sum()), "stale_reads": stale}
 
 
 def porcupine_sample(h: History, loop, keys, extra_reads, timeout_s: float
@@ -155,10 +157,11 @@ def porcupine_sample(h: History, loop, keys, extra_reads, timeout_s: float
 
 
 def durability_counters(before: Dict[str, Any], after: Dict[str, Any],
-                        acked_updates: int) -> List[str]:
+                        acked_updates: int) -> Tuple[List[str], Dict[str, int]]:
     """An acknowledged update is in the WAL and flushed before its ack:
     the WAL took at least as many appends as updates were acknowledged,
-    and it was fsynced.  An ack without its flush is a failed run."""
+    and it was fsynced.  An ack without its flush is a failed run.
+    Returns what is wrong and the two numbers compared (limit 0)."""
     wrong = []
     appends = after.get("wal.appends", 0) - before.get("wal.appends", 0)
     fsyncs = after.get("wal.fsyncs", 0) - before.get("wal.fsyncs", 0)
@@ -167,4 +170,5 @@ def durability_counters(before: Dict[str, Any], after: Dict[str, Any],
                      f"updates acknowledged between the scrapes")
     if acked_updates and fsyncs <= 0:
         wrong.append("updates were acknowledged and wal.fsyncs did not grow")
-    return wrong
+    return wrong, {"acked_updates_not_in_wal": max(acked_updates - int(appends), 0),
+                   "acks_without_fsync": int(bool(acked_updates and fsyncs <= 0))}

@@ -14,7 +14,9 @@ Readers (``"reader": {"kind": ..., ...}``):
 ``hist_sum``       ``hist``, ``scale``: sum delta times scale
 ``counter_delta``  ``counter``: how much it grew
 ``counter_rate``   ``counter``: growth per second of window
-``counter_ratio``  ``num``, ``den``: growth of one over growth of the other
+``counter_ratio``  ``num``, ``den`` (one name or a list, summed), ``scale``:
+                   growth of one over growth of the other, times scale
+                   (0 where ``num`` is one of ``den`` and only the rest grew)
 ``client``         ``field``: a number the load generator measured
 ``trace``          ``field``: a number from the reduced trace
 """
@@ -83,11 +85,16 @@ def _counter_rate(g: Gathered, r: Dict[str, Any]) -> Reading:
 
 
 def _counter_ratio(g: Gathered, r: Dict[str, Any]) -> Reading:
+    dens = r["den"] if isinstance(r["den"], list) else [r["den"]]
+    grown = sum(g.counter(name) or 0.0 for name in dens)
+    if grown <= 0:
+        return None, f"counter {' + '.join(dens)} did not grow in the window"
     num, why = _counter_delta(g, {"counter": r["num"]})
-    den, why2 = _counter_delta(g, {"counter": r["den"]})
-    if num is None or den is None:
-        return None, why or why2
-    return num / den, ""
+    if num is None:
+        # A share of a whole that grew (``num`` is one of ``den``) reads 0
+        # where its part did not: no call shed is 0 %, not nothing to read.
+        return (0.0, "") if r["num"] in dens else (None, why)
+    return num / grown * float(r.get("scale", 1.0)), ""
 
 
 def _field(source: str) -> Callable[[Gathered, Dict[str, Any]], Reading]:

@@ -22,6 +22,12 @@ def history(reads, writes):
     return h
 
 
+def wrong(h):
+    lines, counts = check.register_check(h)
+    assert bool(lines) == any(counts.values())
+    return lines
+
+
 LOADED = lambda k: traffic.code(traffic.LOADER, k)
 W1, W2 = traffic.code(1, 0), traffic.code(2, 0)
 
@@ -36,31 +42,34 @@ def test_allowed_histories():
         (3, 3.0, 3.1, W2),
         (7, 5.0, 5.1, LOADED(7)),    # an untouched key
     ]
-    assert check.register_check(history(reads, writes)) == []
+    assert wrong(history(reads, writes)) == []
 
 
 def test_stale_read_is_refused():
     writes = [(3, 1.0, 2.0, 1, 0), (3, 2.5, 3.0, 2, 0)]   # W2 strictly after W1
-    assert check.register_check(history([(3, 3.5, 3.6, W1)], writes))          # lost update
-    assert check.register_check(history([(3, 2.1, 2.2, LOADED(3))], writes))   # stale load
-    assert check.register_check(history([(3, 3.5, 3.6, W2)], writes)) == []
+    assert wrong(history([(3, 3.5, 3.6, W1)], writes))          # lost update
+    assert wrong(history([(3, 2.1, 2.2, LOADED(3))], writes))   # stale load
+    assert wrong(history([(3, 3.5, 3.6, W2)], writes)) == []
 
 
 def test_unacknowledged_update_obliges_nobody():
     writes = [(3, 1.0, np.inf, 1, 0)]
-    assert check.register_check(history([(3, 5.0, 5.1, LOADED(3))], writes)) == []
-    assert check.register_check(history([(3, 5.0, 5.1, W1)], writes)) == []
+    assert wrong(history([(3, 5.0, 5.1, LOADED(3))], writes)) == []
+    assert wrong(history([(3, 5.0, 5.1, W1)], writes)) == []
 
 
 def test_future_and_made_up_values_are_refused():
     writes = [(3, 4.0, 5.0, 1, 0)]
-    assert check.register_check(history([(3, 1.0, 1.1, W1)], writes))        # not written yet
-    assert check.register_check(history([(3, 1.0, 1.1, 424242)], writes))    # nobody's tag
-    assert check.register_check(history([(4, 6.0, 6.1, W1)], writes))        # another key's value
+    assert wrong(history([(3, 1.0, 1.1, W1)], writes))        # not written yet
+    assert wrong(history([(3, 1.0, 1.1, 424242)], writes))    # nobody's tag
+    assert wrong(history([(4, 6.0, 6.1, W1)], writes))        # another key's value
 
 
 def test_durability_counters():
     before, after = {"wal.appends": 5, "wal.fsyncs": 2}, {"wal.appends": 105, "wal.fsyncs": 30}
-    assert check.durability_counters(before, after, 100) == []
-    assert check.durability_counters(before, after, 101)
-    assert check.durability_counters(before, {"wal.appends": 105, "wal.fsyncs": 2}, 50)
+    assert check.durability_counters(before, after, 100) == (
+        [], {"acked_updates_not_in_wal": 0, "acks_without_fsync": 0})
+    lines, counts = check.durability_counters(before, after, 101)
+    assert lines and counts["acked_updates_not_in_wal"] == 1
+    lines, counts = check.durability_counters(before, {"wal.appends": 105, "wal.fsyncs": 2}, 50)
+    assert lines and counts["acks_without_fsync"] == 1

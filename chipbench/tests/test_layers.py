@@ -39,3 +39,11 @@ def test_no_event_in_the_window_is_no_number_never_zero():
     ):
         value, why = rd(**reader)
         assert value is None and why
+
+
+def test_a_share_of_a_whole_that_grew_reads_zero_where_its_part_did_not():
+    share = dict(kind="counter_ratio", num="shed", den=["shed", "ticks"], scale=100.0)
+    assert rd(**share) == (0.0, "")                 # nothing shed of 1,000: 0 %
+    assert rd(**{**share, "num": "wal.appends", "den": ["wal.appends", "wal.fsyncs"]}) == (80.0, "")
+    value, why = rd(**{**share, "den": ["shed", "idle"]})
+    assert value is None and why                    # the whole did not grow: nothing to read

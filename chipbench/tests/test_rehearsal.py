@@ -1,6 +1,10 @@
 """A rehearsal on the CPU prints, last, a line with the contract's keys
-and no other (but the mark that says it is a rehearsal, not a result)."""
+and no other (but the mark that says it is a rehearsal, not a result):
+for a closed-loop cell and for the open-loop one.  Then the rest of a
+run with the timed path broken underneath: ``correct`` comes out false;
+and a generator that cannot offer its schedule gives no result."""
 
+import copy
 import json
 import os
 import subprocess
@@ -10,33 +14,49 @@ import pytest
 
 import manifest
 
-CELL = manifest.manifest()["workloads"][0]["name"]
+CELLS = [w["name"] for w in manifest.manifest()["workloads"]]
+CELL = CELLS[0]
+OPEN = [c for c in CELLS if manifest.cell(c)["traffic"]["loop"] == "open"]
+SEED = str(2 ** 31 + 5)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_rehearsal_prints_the_contract_line(trace):
-    out = subprocess.run(
-        [sys.executable, os.path.join(manifest.HERE, "run.py"), "--workload", CELL,
-         "--seed", str(2 ** 31 + 5), "--seconds", "4", "--trace", str(trace),
-         "--rehearse-cpu"],
-        cwd=manifest.ROOT, text=True, capture_output=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
+def check_line(line, cell_name, trace):
     assert line.pop("rehearsal") is True
-    want = {"correct", "attempted", "failed", "metrics", "device"}
+    want = {"correct", "attempted", "failed", "metrics", "device", "compared"}
     assert set(line) == (want | {"breakdown"} if trace else want)
+    assert list(line)[-1] == "compared"
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
-    cell = manifest.cell(CELL)  # the cell's own lists: tails are opt-in
+    assert line["compared"] and all(c == {"value": 0, "limit": 0} for c in line["compared"].values())
+    cell = manifest.cell(cell_name)  # the cell's own lists: tails are opt-in
     names = {m["name"] for m in cell["layers" if trace else "end_to_end"]}
     assert set(line["metrics"]) <= names
     if not trace:
         assert set(line["metrics"]) == names
-    for name, m in line["metrics"].items():
-        assert set(m) == {"value", "unit"} and m["value"] > 0, name
+    for name, m in line["metrics"].items():   # a share of the calls may be 0 %: none was shed
+        assert set(m) == {"value", "unit"} and (m["value"] > 0 or name == "admit.shed_share"), name
     device = {"platform", "kind", "count", "memory_peak_bytes"}
     assert set(line["device"]) == (device | {"busy_s", "window_s"} if trace else device)
-    assert not os.path.exists(os.path.join(manifest.ROOT, ".chipbench_run", CELL))
+    assert not os.path.exists(os.path.join(manifest.ROOT, ".chipbench_run", cell_name))
+
+
+@pytest.mark.parametrize("cell,trace", [(CELL, 0), (CELL, 1)] + [(c, 1) for c in OPEN]
+                         + [(OPEN[0], 0)])
+def test_rehearsal_prints_the_contract_line(cell, trace):
+    out = subprocess.run(
+        [sys.executable, os.path.join(manifest.HERE, "run.py"), "--workload", cell,
+         "--seed", SEED, "--seconds", "4", "--trace", str(trace), "--rehearse-cpu"],
+        cwd=manifest.ROOT, text=True, capture_output=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    # every number compared stands beside its limit, last on stderr too
+    assert out.stderr.strip().splitlines()[-1].startswith("compared ")
+    check_line(line, cell, trace)
+    if cell in OPEN:
+        assert "ms from due:" in out.stdout and "generator: " in out.stdout
+        if trace:   # what the generator says of itself is there to be read
+            assert {"client.late_p99_ms", "client.inflight_p95", "client.inflight_end"} \
+                <= set(line["metrics"])
 
 
 def test_no_tpu_no_result():
@@ -49,3 +69,75 @@ def test_no_tpu_no_result():
     )
     assert out.returncode != 0
     assert not any(l.startswith("{") for l in out.stdout.splitlines())
+
+
+# -- the rest of a run, in this process, with something changed underneath ----
+
+
+def rehearse(monkeypatch, capfd, cell_name, change_cell=None):
+    import run
+
+    cell = copy.deepcopy(manifest.cell(cell_name))
+    if change_cell:
+        change_cell(cell)
+    monkeypatch.setattr(run.manifest, "cell", lambda name: cell)
+    rc = run.main(["--workload", cell_name, "--seed", SEED, "--seconds", "4", "--trace", "0",
+                   "--rehearse-cpu"])
+    out, err = capfd.readouterr()
+    lines = [json.loads(l) for l in out.splitlines() if l.startswith("{")]
+    return rc, lines, out, err
+
+
+def test_the_service_name_comes_from_the_configuration(monkeypatch, capfd):
+    def spell_it_out(cell):
+        cell["config"]["service"] = "EngineKV"
+    rc, lines, _out, err = rehearse(monkeypatch, capfd, OPEN[0], spell_it_out)
+    assert rc == 0 and lines[-1]["correct"] is True, err[-1000:]
+
+    def another(cell):
+        cell["config"]["service"] = "NoSuchKV"
+    rc, lines, _out, err = rehearse(monkeypatch, capfd, OPEN[0], another)
+    assert rc == 1 and not lines and "NoSuchKV.info" in err
+
+
+def test_a_generator_that_cannot_offer_its_schedule_gives_no_result(monkeypatch, capfd):
+    def starve(cell):     # one session: an arrival while it is busy (~10 ms an update) waits
+        cell["traffic"]["rehearse_cpu"] = {"rate_ops_s": 60, "sessions": 1}
+    rc, lines, out, err = rehearse(monkeypatch, capfd, OPEN[0], starve)
+    assert rc == 1 and not lines
+    assert "generator: " in out and "error: the generator did not offer its schedule" in err
+
+
+@pytest.mark.parametrize("fault", ["answer_altered", "update_acknowledged_and_never_sent"])
+def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault):
+    from multiraft_tpu.distributed.engine_clerks import EngineClerk
+    from traffic import TAG
+
+    seen = {"n": 0}
+    if fault == "answer_altered":
+        real_get = EngineClerk.get
+
+        def get(self, key):        # one read in twenty names a write nobody made
+            v = yield from real_get(self, key)
+            seen["n"] += 1
+            if seen["n"] % 20 == 0:
+                v = f"{(int(v[:TAG]) + 1) % 10 ** TAG:0{TAG}d}" + v[TAG:]
+            return v
+        monkeypatch.setattr(EngineClerk, "get", get)
+    else:
+        real_put = EngineClerk.put
+
+        def put(self, key, value):  # one update in five is acknowledged by nobody but the clerk
+            seen["n"] += 1
+            if seen["n"] % 5 == 0:
+                return ""
+            return (yield from real_put(self, key, value))
+        monkeypatch.setattr(EngineClerk, "put", put)
+    rc, lines, _out, err = rehearse(monkeypatch, capfd, OPEN[0])
+    assert rc == 0 and lines[-1]["correct"] is False, err[-1000:]
+    failed = {k for k, c in lines[-1]["compared"].items() if c["value"] > c["limit"]}
+    assert failed and "compared " in err
+    if fault == "answer_altered":
+        assert failed & {"reads_of_values_nobody_wrote", "reads_from_the_future"}
+    else:
+        assert failed & {"stale_reads", "acked_updates_not_in_wal", "keys_read_back_wrong"}

@@ -2,22 +2,26 @@
 in, every client's whole operation sequence out — before the window, so
 the window spends the client's CPU on the wire and not on the dice.
 
-A mix (``chipbench/traffic/<name>.json``) gives ``loop`` ("closed"),
-``clients``, the shares ``read`` and ``update``, ``distribution``
-("zipfian" or "uniform") with ``theta``, and ``path`` ("command": one
-``EngineKV.command`` RPC per operation).  Every seed draws from the
-same distribution over the same number of records: a seed changes which
-keys are hot and the order of operations, never the amount of work.
+A mix (``chipbench/traffic/<name>.json``) gives ``loop`` ("closed" with
+``clients``, or "open" with ``rate_ops_s``, ``sessions`` and optionally
+``burst``: :func:`arrivals`), the shares
+``read`` and ``update``, ``distribution`` ("zipfian" or "uniform") with
+``theta``, and ``path`` ("command": one ``<service>.command`` RPC per
+operation).  Every seed draws from the same distribution over the same
+number of records: a seed changes which keys are hot, the order of
+operations and, in an open loop, the instants they are due, never the
+amount of work.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import numpy as np
 
 TAG = 10                # digits at the head of every value: who wrote it
 LOADER = 99             # the "client" that wrote the loaded records
+CYCLE_S = 1.0           # an open loop's bursts come once a cycle (arrivals)
 _ALPHABET = np.frombuffer(b"abcdefghijklmnopqrstuvwxyz0123456789", np.uint8)
 
 
@@ -68,10 +72,12 @@ class Records:
 
 
 def sequences(traffic: Dict[str, Any], records: Records, seed: int,
-              ops_per_client: int):
+              ops_per_client: int, clients: Optional[int] = None):
     """``(is_update, key_index)``: two ``[clients, ops_per_client]``
-    arrays, client ``c``'s ``n``-th operation at ``[c, n]``."""
-    clients = int(traffic["clients"])
+    arrays, client ``c``'s ``n``-th operation at ``[c, n]``.  A closed
+    mix says how many ``clients``; an open loop gives the rows of its
+    record (``loadgen.OpenLoop.ROWS``)."""
+    clients = int(traffic["clients"] if clients is None else clients)
     read, update = float(traffic["read"]), float(traffic["update"])
     if abs(read + update - 1.0) > 1e-9:
         raise ValueError("traffic: read + update must be 1")
@@ -87,3 +93,37 @@ def sequences(traffic: Dict[str, Any], records: Records, seed: int,
         raise ValueError(f"traffic: distribution {traffic['distribution']!r}")
     is_update = rng.random(shape) < update
     return is_update, records.key_of_rank[rank].astype(np.int64)
+
+
+def arrivals(traffic: Dict[str, Any], seed: int, duration_s: float) -> np.ndarray:
+    """When every operation of an open loop is due, in seconds after the
+    loop starts: sorted, inside ``[0, duration_s)``.
+
+    ``rate_ops_s`` is the mean offered rate.  Time is cut into cycles of
+    ``CYCLE_S``; with ``burst`` = ``{"factor", "duty"}`` the first
+    ``duty`` of every cycle is offered ``factor`` x the mean and the rest
+    ``(1 - factor x duty) / (1 - duty)`` x the mean, so the mean stays
+    ``rate_ops_s`` (the shape of ``benchmarks/openloop.py``'s ``bursty``
+    mode, restated).  Each stretch of constant rate gets the
+    whole number of arrivals its rate gives it (the cumulative count,
+    rounded), placed as sorted uniforms: a Poisson process conditioned
+    on its count in every stretch.  So a seed (stream ``[seed, 4]``)
+    moves the instants and never the amount of work: every seed offers
+    the same number of operations in every phase of every cycle.
+    """
+    rate, cycle = float(traffic["rate_ops_s"]), CYCLE_S
+    burst = traffic.get("burst") or {"factor": 1.0, "duty": 1.0}
+    factor, duty = float(burst["factor"]), float(burst["duty"])
+    if not (rate > 0 and 0 < duty <= 1 and 0 < factor * duty <= 1):
+        raise ValueError(f"traffic: rate {rate}, burst {burst}: nothing left for the rest of a cycle")
+    # The edges of the stretches, and the rate of each as a multiple of the mean.
+    starts = np.arange(0.0, duration_s, cycle)
+    edges = np.unique(np.minimum(
+        np.concatenate([starts, starts + duty * cycle, [duration_s]]), duration_s))
+    in_burst = np.mod(edges[:-1], cycle) < duty * cycle - 1e-9
+    level = np.where(in_burst, factor, (1.0 - factor * duty) / max(1.0 - duty, 1e-12))
+    due_by_edge = np.concatenate([[0.0], np.cumsum(rate * level * np.diff(edges))])
+    counts = np.diff(np.round(due_by_edge).astype(np.int64))
+    stretch = np.repeat(np.arange(len(counts)), counts)
+    u = np.random.default_rng([seed, 4]).random(len(stretch))
+    return np.sort(edges[stretch] + u * np.diff(edges)[stretch])
