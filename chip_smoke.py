@@ -34,6 +34,18 @@ anything but a TPU fails the run.  Legs, in order:
             config 5's replica count: 50,000 replicas' state), and
             then a start with ``--replicas 3`` on that --data-dir,
             which must refuse by name and serve nothing.
+``sharded`` ``serve-shardkv --groups 10000 --shards 33330 --join all``
+            (BASELINE.json config 3's shape, 10 shards : 3 groups, at
+            9,999 replica groups): the bootstrap is ONE join (config 1,
+            3,333 groups of four shards and 6,666 of three, 33,330 live
+            slots); 100,000 keys loaded through ``FirehoseClerk`` and
+            read back against the plain reference's dict model
+            (``harness/shardref.py``); a ``leave`` of 100 groups and
+            their ``join`` back under concurrent Append/Get clerks on
+            keys of shards that move (porcupine), timed from the
+            operation to ``shard.slots`` settled, with the counters each
+            grew; ``kill -9``, restart, every acknowledged write read
+            back once; SIGTERM exits 0.
 ``mesh4``   the sharded tick on a 4-device ``groups`` mesh and the same
             served path with ``--mesh-devices 4``.  Runs when the
             chip-holding child reports >= 4 devices; the output says in
@@ -63,7 +75,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-LEGS = ("tick", "bench", "served", "served5", "mesh4")
+LEGS = ("tick", "bench", "served", "served5", "sharded", "mesh4")
 BUDGET_S = 1150.0  # the contract allows 1200 s, compilation included
 
 _T0 = time.monotonic()
@@ -354,18 +366,20 @@ def leg_bench(rehearse: bool) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 class Server:
-    """One ``serve-kv`` child on ``data_dir``."""
+    """One ``serve-kv`` (or ``verb``) child on ``data_dir``."""
 
     def __init__(self, data_dir: str, err_path: str, rehearse: bool,
-                 groups: int, mesh: int, seed: int, replicas: int = 3) -> None:
+                 groups: int, mesh: int, seed: int, replicas: int = 3,
+                 verb: str = "serve-kv", extra: Tuple[str, ...] = ()) -> None:
         from multiraft_tpu.distributed.launch import reserve_ports
 
         self.port = reserve_ports(1, "127.0.0.1")[0]
+        self.verb = verb
         argv = [
-            sys.executable, "-m", "multiraft_tpu", "serve-kv",
+            sys.executable, "-m", "multiraft_tpu", verb,
             "--platform", "cpu" if rehearse else "tpu",
             "--groups", str(groups), "--data-dir", data_dir,
-            "--seed", str(seed), "--port", str(self.port),
+            "--seed", str(seed), "--port", str(self.port), *extra,
         ] + (["--mesh-devices", str(mesh)] if mesh else []) + (
             ["--replicas", str(replicas)] if replicas != 3 else []
         )
@@ -389,13 +403,13 @@ class Server:
         from multiraft_tpu.distributed.launch import check_ready
 
         try:
-            check_ready(self.proc, "serve-kv", timeout=remaining(cap_s))
+            check_ready(self.proc, self.verb, timeout=remaining(cap_s))
         except RuntimeError as exc:
             raise LegFailed(str(exc)) from None
         self.ready_s = time.monotonic() - self.t_start
         with open(self.err_path) as f:
             devs = [d for d in map(note_device, f.read().splitlines()) if d]
-        require(bool(devs), "serve-kv: printed no device line")
+        require(bool(devs), f"{self.verb}: printed no device line")
         return devs[-1]
 
     def kill9(self) -> None:
@@ -408,7 +422,7 @@ class Server:
             return self.proc.wait(timeout=remaining(cap_s))
         except subprocess.TimeoutExpired:
             self.kill9()
-            raise LegFailed("serve-kv: did not exit on SIGTERM; killed")
+            raise LegFailed(f"{self.verb}: did not exit on SIGTERM; killed")
 
 
 def cache_entries() -> int:
@@ -654,6 +668,267 @@ def leg_served(
 
 
 # ---------------------------------------------------------------------------
+# Leg: sharded
+# ---------------------------------------------------------------------------
+
+
+def leg_sharded(rehearse: bool, seed: int) -> Dict[str, Any]:
+    import random
+    from collections import Counter
+
+    from multiraft_tpu.distributed.engine_cluster import BlockingEngineClerk
+    from multiraft_tpu.distributed.engine_server import FirehoseClerk
+    from multiraft_tpu.distributed.tcp import RpcNode
+    from multiraft_tpu.harness import run_clerk_load
+    from multiraft_tpu.harness.shardref import ShardRef
+    from multiraft_tpu.porcupine.checker import check_operations
+    from multiraft_tpu.porcupine.kv import OP_APPEND, kv_model
+    from multiraft_tpu.porcupine.model import CheckResult
+    from multiraft_tpu.services.shardctrler import ShardSpace
+    from multiraft_tpu.sim.scheduler import TIMEOUT
+
+    name, service = "sharded", "EngineShardKV"
+    sz = sizes(rehearse)
+    G, n_keys, n_sample = sz["served_G"], sz["keys"], sz["sample"]
+    n_shards = (G - 1) * 10 // 3       # the source's 10 shards : 3 groups
+    leaving = list(range(1, max(3, G // 100) + 1))        # 100 of 9,999
+    space = ShardSpace.of(n_shards)
+    rng = random.Random(seed)
+    alphabet = "abcdefghijklmnopqrstuvwxyz0123456789"
+    ref = ShardRef(n_shards, space.shard_of)   # the dict model, by shard
+    for i in range(n_keys):
+        ref.put(f"user{i:012d}", "".join(rng.choices(alphabet, k=100)))
+    model = ref.items()
+    sample = rng.sample(sorted(model), n_sample)
+    nodes: List[RpcNode] = []
+
+    def run(node: RpcNode, gen, cap_s: float):
+        out = node.sched.wait(node.sched.spawn(gen), remaining(cap_s))
+        require(out is not TIMEOUT, f"{name}: the server did not answer")
+        return out
+
+    def call(node: RpcNode, end, verb: str, args=None, cap_s: float = 60.0):
+        out = node.sched.wait(end.call(verb, args), remaining(cap_s))
+        require(out is not TIMEOUT and out is not None,
+                f"{name}: {verb} said {out!r}")
+        return out
+
+    def connect(server: Server) -> Tuple[RpcNode, Any]:
+        node = RpcNode()
+        nodes.append(node)
+        return node, node.client_end("127.0.0.1", server.port)
+
+    def read_sample(node: RpcNode, end, what: str) -> None:
+        got = run(
+            node,
+            FirehoseClerk(node.sched, end, service).run_batch(
+                [("Get", k, "") for k in sample], deadline_s=120.0
+            ),
+            150.0,
+        )
+        bad = [k for k, v in zip(sample, got) if v != ref.get(k)]
+        require(not bad, f"{name}: {what}: {len(bad)} keys differ: {bad[:3]}")
+        say(f"{name}: {what}: {len(sample)} sampled Gets match the model")
+
+    def reconfigure(node, end, kind: str, cmd: int, owners: List[int]):
+        """One admin operation, then poll until the migration it starts
+        is over: every group has applied the config, every shard whose
+        owner changed was pulled, inserted, deleted at its old owner and
+        confirmed, and the live slots number the shards again.  Returns
+        the new config's owners."""
+        before = call(node, end, "Obs.snapshot")["metrics"]
+        t0 = time.monotonic()
+        reply = call(node, end, f"{service}.admin", (kind, leaving, cmd))
+        require(reply.err == "OK", f"{name}: {kind} said {reply.err}")
+        acked = time.monotonic() - t0
+        _, owners_now, groups_now = call(node, end, f"{service}.config")
+        shards_moving = sum(1 for a, b in zip(owners, owners_now) if a != b)
+        require(
+            (set(leaving) <= set(groups_now)) == (kind == "join")
+            and shards_moving >= len(leaving),
+            f"{name}: {kind}: {shards_moving} shards change owner",
+        )
+        grew: Dict[str, float] = {}
+        while True:
+            now = call(node, end, "Obs.snapshot")["metrics"]
+            grew = {k: now[k] - before.get(k, 0) for k in sorted(now)
+                    if k.startswith("shard.") and not k.endswith(("_p50", "_p99"))
+                    and now[k] != before.get(k, 0)}
+            if (now["shard.slots"] == n_shards
+                    and grew.get("shard.config_applies", 0) == G - 1
+                    and grew.get("shard.confirms", 0) == shards_moving):
+                break
+            require(time.monotonic() - t0 < remaining(300.0),
+                    f"{name}: {kind} of {len(leaving)} groups did not settle: {grew}")
+            time.sleep(0.25)
+        settled = time.monotonic() - t0
+        require(grew.get("shard.deletes") == shards_moving
+                and grew.get("shard.inserts") == shards_moving,
+                f"{name}: {kind}: {grew}")
+        say(f"{name}: {kind} of {len(leaving)} groups ({shards_moving} "
+            f"shards change owner): acknowledged in {acked:.2f}s, "
+            f"shard.slots settled {settled:.2f}s after the operation; grew "
+            f"{json.dumps(grew)}")
+        return owners_now
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+        data_dir = os.path.join(tmp, "data")
+        mk = lambda i: Server(
+            data_dir, os.path.join(tmp, f"server{i}.err"), rehearse, G, 0,
+            seed, verb="serve-shardkv",
+            extra=("--shards", str(n_shards), "--join", "all"),
+        )
+        srv = None
+        try:
+            srv = mk(1)
+            dev = srv.wait_ready(420.0)
+            check_device(name, dev, rehearse)
+            node, end = connect(srv)
+            info = call(node, end, f"{service}.info")
+            require(
+                (info["G"], info["P"], info["shards"], info["partitioner"])
+                == (G, 3, n_shards, "crc32"),
+                f"{name}: info said {info}",
+            )
+            m = call(node, end, "Obs.snapshot")["metrics"]
+            require(
+                (m["shard.count"], m["shard.slots"], m["shard.config_num"])
+                == (n_shards, n_shards, 1),
+                f"{name}: after the bootstrap join the gauges read "
+                f"{ {k: v for k, v in m.items() if k.startswith('shard.')} }",
+            )
+            num, owners, groups = call(node, end, f"{service}.config")
+            per_group = Counter(Counter(owners).values())
+            require(
+                num == 1 and len(groups) == G - 1
+                and set(per_group) <= {3, 4} and 0 not in owners,
+                f"{name}: config {num}: {len(groups)} groups, shards a "
+                f"group {dict(per_group)}",
+            )
+            if G <= 1000:   # small enough for the reference's plain loops
+                ref.join(range(1, G))
+                require(owners == ref.owner,
+                        f"{name}: the owners are not the reference's")
+            say(
+                f"{name}: first start ready in {srv.ready_s:.1f}s "
+                f"(ready.join_s={m['ready.join_s']:.2f}); G={G} x P=3, "
+                f"{n_shards} shards ({info['partitioner']}), config 1 = ONE "
+                f"join of {G - 1} groups, shards a group {dict(per_group)}, "
+                f"shard.slots={int(m['shard.slots'])}"
+            )
+
+            # -- load, read back against the dict model ----------------
+            t0 = time.monotonic()
+            run(
+                node,
+                FirehoseClerk(node.sched, end, service).run_batch(
+                    [("Put", k, v) for k, v in model.items()],
+                    deadline_s=240.0,
+                ),
+                300.0,
+            )
+            say(f"{name}: loaded {n_keys} keys x 100 B in "
+                f"{time.monotonic() - t0:.1f}s")
+            read_sample(node, end, "after load")
+
+            # -- leave and join back, under clerk traffic --------------
+            # Keys of shards the leaving groups own, so that they move.
+            gone = set(leaving)
+            moving = [s for s, g in enumerate(owners) if g in gone]
+            shared: List[str] = []
+            i = 0
+            while len(shared) < 2:
+                if owners[space.shard_of(f"shared{i}")] in gone:
+                    shared.append(f"shared{i}")
+                i += 1
+            shared.append("shared-at-rest")
+            history: List[Any] = []
+            worker = threading.Thread(
+                target=lambda: history.extend(run_clerk_load(
+                    lambda: BlockingEngineClerk(srv.port, service=service),
+                    shared, n_workers=3,
+                    ops_per_worker=16 if rehearse else 60,
+                    op_timeout=120.0,
+                )),
+                daemon=True,
+            )
+            worker.start()
+            owners2 = reconfigure(node, end, "leave", 2, owners)
+            require(not gone & set(owners2),
+                    f"{name}: a group that left still owns a shard")
+            back = sum(1 for a, b in zip(owners, owners2) if a != b)
+            require(back == len(moving),
+                    f"{name}: the leave moved {back} shards, not the "
+                    f"{len(moving)} the leaving groups held")
+            owners3 = reconfigure(node, end, "join", 3, owners2)
+            if G <= 1000:
+                ref.leave(leaving)
+                ref.join(leaving)
+                require(owners3 == ref.owner,
+                        f"{name}: after the leave and the join back the "
+                        f"owners are not the reference's")
+            worker.join(remaining(240.0))
+            require(not worker.is_alive(), f"{name}: the clerks never finished")
+            acked = {
+                key: [
+                    op.input.value for op in history
+                    if op.input.op == OP_APPEND and op.input.key == key
+                ]
+                for key in shared
+            }
+            verdict = check_operations(kv_model, history, timeout=60.0)
+            require(
+                verdict is CheckResult.OK,
+                f"{name}: porcupine says {verdict.value} over "
+                f"{len(history)} ops",
+            )
+            say(f"{name}: porcupine ok over {len(history)} concurrent "
+                f"Append/Get ops from 3 clerks through both migrations")
+            read_sample(node, end, "after the leave and the join back")
+            m = call(node, end, "Obs.snapshot")["metrics"]
+            require(m["shard.config_num"] == 3 and m["shard.slots"] == n_shards,
+                    f"{name}: config {m['shard.config_num']}, "
+                    f"{m['shard.slots']} slots")
+
+            # -- kill -9, restart on the same dir ----------------------
+            srv.kill9()
+            srv = mk(2)
+            dev2 = srv.wait_ready(420.0)
+            check_device(f"{name} restart", dev2, rehearse)
+            say(f"{name}: kill -9 + restart ready in {srv.ready_s:.1f}s "
+                f"(checkpoint + WAL replay)")
+            node, end = connect(srv)
+            m = call(node, end, "Obs.snapshot")["metrics"]
+            require(m["shard.config_num"] == 3 and m["shard.slots"] == n_shards,
+                    f"{name}: after restart config {m['shard.config_num']}, "
+                    f"{m['shard.slots']} slots: a restart re-ran the "
+                    f"bootstrap or lost a migration")
+            read_sample(node, end, "after kill -9 + restart")
+            ck = BlockingEngineClerk(srv.port, service=service)
+            nodes.append(ck.node)
+            for key, tags in acked.items():
+                val = ck.get(key, timeout=60.0)
+                wrong = [t for t in tags if val.count(t) != 1]
+                require(
+                    not wrong and len(val) == sum(map(len, tags)),
+                    f"{name}: acked appends not exactly once in "
+                    f"{key}={val!r}: {wrong}",
+                )
+            say(f"{name}: every acknowledged append present exactly once "
+                f"after restart")
+            rc = srv.terminate(240.0)
+            require(rc == 0, f"{name}: exit {rc} on SIGTERM")
+            say(f"{name}: SIGTERM -> final checkpoint, exit 0")
+        except (LegFailed, TimeoutError) as exc:
+            tail = srv.stderr_tail() if srv is not None else ""
+            raise LegFailed(f"{exc} [server stderr: {tail}]") from None
+        finally:
+            for n in nodes:
+                n.close()
+    return dev
+
+
+# ---------------------------------------------------------------------------
 # Leg: mesh4
 # ---------------------------------------------------------------------------
 
@@ -782,6 +1057,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     device = leg_served(rehearse, ns.seed)
                 elif leg == "served5":
                     device = leg_served(rehearse, ns.seed, replicas=5)
+                elif leg == "sharded":
+                    device = leg_sharded(rehearse, ns.seed)
                 else:
                     if _claimed and _claimed[-1]["count"] < 4:
                         # An earlier chip-holding child already said how

@@ -115,14 +115,14 @@ def _cmd_serve_shardkv(args) -> int:
             h, p = addr.rsplit(":", 1)
             peer_addrs[int(gid)] = (h, int(p))
         gids = [int(g) for g in args.gids.split(",")] if args.gids else None
+        hosted = gids if gids is not None else list(range(1, args.groups))
         return serve_engine_shardkv(
             port=args.port,
             G=args.groups,
             host=args.host,
             seed=args.seed,
-            join_gids=(
-                [int(g) for g in args.join.split(",")] if args.join else None
-            ),
+            join_gids=_gid_list(args.join, hosted) if args.join else None,
+            shards=args.shards,
             gids=gids,
             peer_addrs=peer_addrs or None,
             data_dir=args.data_dir,
@@ -180,6 +180,19 @@ def _replicas(text: str) -> int:
     return n
 
 
+def _gid_list(text: str, hosted) -> list:
+    """``--join``: ``all`` (every replica group this server hosts,
+    whatever ``--groups`` is), or a comma list of gids and ranges
+    (``1,2,7-9``; a range includes both ends)."""
+    if text == "all":
+        return list(hosted)
+    out = []
+    for part in text.split(","):
+        lo, _, hi = part.partition("-")
+        out.extend(range(int(lo), int(hi or lo) + 1))
+    return out
+
+
 def _add_serve_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--port", type=int, default=0,
                    help="listen port (0 = ephemeral, printed on ready)")
@@ -213,8 +226,16 @@ def main(argv=None) -> int:
     s2 = sub.add_parser("serve-shardkv",
                         help="sharded engine server (standalone or fleet)")
     _add_serve_flags(s2)
-    s2.add_argument("--join", default=None, metavar="GID,GID",
-                    help="bootstrap-join these gids before readiness")
+    s2.add_argument("--join", default=None, metavar="all|GID,LO-HI",
+                    help="bootstrap-join these gids in ONE join operation "
+                         "(config 1) before readiness: `all` = every "
+                         "replica group this server hosts, or a comma list "
+                         "of gids and ranges (1,2,7-9)")
+    s2.add_argument("--shards", type=int, default=10, metavar="N",
+                    help="the shard space (default 10, the reference's, "
+                         "keyed by a key's first byte); any other N hashes "
+                         "the whole key (crc32 %% N).  A --data-dir written "
+                         "at another N is refused")
     s2.add_argument("--gids", default=None, metavar="GID,GID",
                     help="fleet mode: the global gids THIS process hosts")
     s2.add_argument("--peer", action="append", metavar="GID=HOST:PORT",

@@ -237,6 +237,7 @@ class FirehoseClerk(EngineClerk):
     ) -> None:
         super().__init__(sched, end, service, lane=lane)
         self._G = None
+        self._sharded = None  # a ShardFirehoseClerk, once info says so
 
     def _topology(self, deadline):
         while self._G is None:
@@ -245,6 +246,19 @@ class FirehoseClerk(EngineClerk):
             fut: Future = self.end.call(f"{self.service}.info", None)
             reply = yield self.sched.with_timeout(fut, 3.5)
             if reply is not None and reply is not TIMEOUT:
+                if "shards" in reply:
+                    # A sharded service: the group column is the gid
+                    # that owns the key's shard, by the shard space the
+                    # SERVER states and its latest config, one process
+                    # hosting every gid.
+                    from ..services.shardctrler import ShardSpace
+
+                    self._sharded = ShardFirehoseClerk(
+                        self.sched, {}, end=self.end, service=self.service,
+                        space=ShardSpace(
+                            int(reply["shards"]), reply["partitioner"]
+                        ),
+                    )
                 self._G = int(reply["G"])
             else:
                 yield self._backoff.next_delay()
@@ -254,6 +268,9 @@ class FirehoseClerk(EngineClerk):
     def run_batch(self, ops, deadline_s: float = 30.0):
         """ops = [(op, key, value), ...] → list of values (Gets) in
         order.  Generator (spawn on the scheduler)."""
+        yield from self._topology(self.sched.now + deadline_s)
+        if self._sharded is not None:
+            return (yield from self._sharded.run_batch(ops, deadline_s))
         out = []
         for s in range(0, len(ops), self.MAX_FRAME):
             part = yield from self._one_frame(
@@ -345,10 +362,24 @@ class ShardFirehoseClerk:
 
     from ..engine.firehose import MAX_FIREHOSE_ROWS as MAX_FRAME
 
-    def __init__(self, sched, ends_by_gid: dict) -> None:
+    def __init__(
+        self, sched, ends_by_gid: dict, end=None,
+        service: str = "EngineShardKV", space=None,
+    ) -> None:
+        """``end``: the one process that hosts every gid ``ends_by_gid``
+        does not name (a standalone ``serve-shardkv``).  ``space``: the
+        server's shard space (``EngineShardKV.info``); default the
+        reference's."""
+        from ..services.shardctrler import ShardSpace
+
         self.sched = sched
+        self.service = service
         self.ends = dict(ends_by_gid)
-        self._all = list(dict.fromkeys(self.ends.values()))
+        self._one = end
+        self._all = list(dict.fromkeys(
+            [*self.ends.values(), *([end] if end is not None else [])]
+        ))
+        self.space = space if space is not None else ShardSpace.of()
         self.client_id = unique_client_id(next(EngineClerk._next))
         self.command_id = 0
         self._cfg = None
@@ -359,7 +390,7 @@ class ShardFirehoseClerk:
             if self.sched.now >= deadline:
                 raise TimeoutError("config fetch exceeded deadline")
             for end in self._all:
-                fut: Future = end.call("EngineShardKV.config", None)
+                fut: Future = end.call(f"{self.service}.config", None)
                 reply = yield self.sched.with_timeout(fut, 3.5)
                 if reply is not None and reply is not TIMEOUT:
                     self._cfg = reply
@@ -379,7 +410,6 @@ class ShardFirehoseClerk:
             pack_request,
             unpack_reply,
         )
-        from ..services.shardkv import key2shard
         from .engine_wire import _OPCODE
 
         n = len(ops)
@@ -390,6 +420,7 @@ class ShardFirehoseClerk:
                 self.command_id += 1
                 cmd = self.command_id
             rows.append((op, key, value, cmd))
+        key2shard = self.space.shard_of
         shards = [key2shard(key) for _, key, _, _ in rows]
         results = [""] * n
         done = [False] * n
@@ -428,7 +459,7 @@ class ShardFirehoseClerk:
                 unrouted = 0
                 for i in todo:
                     gid = cfg[1][shards[i]]
-                    end = self.ends.get(gid)
+                    end = self.ends.get(gid, self._one if gid else None)
                     if end is None:
                         # Shard unassigned (gid 0) or owned by a
                         # process we have no end for: wait for the
@@ -454,7 +485,7 @@ class ShardFirehoseClerk:
                         [rows[i][2].encode() for i in idxs],
                     )
                     flights.append(
-                        (idxs, end.call("EngineShardKV.firehose", blob))
+                        (idxs, end.call(f"{self.service}.firehose", blob))
                     )
                 for idxs, fut in flights:
                     reply = yield self.sched.with_timeout(fut, 10.0)
@@ -513,8 +544,15 @@ class EngineFleetClerk:
     # an unbounded inner loop.
     CONFIG_DEADLINE_S = 30.0
 
-    def __init__(self, sched, ends_by_gid: dict, make_end=None) -> None:
+    def __init__(
+        self, sched, ends_by_gid: dict, make_end=None, space=None
+    ) -> None:
+        from ..services.shardctrler import ShardSpace
+
         self.sched = sched
+        # The fleet's shard space (every process's --shards); default
+        # the reference's.
+        self.space = space if space is not None else ShardSpace.of()
         self.ends = dict(ends_by_gid)  # gid -> TcpClientEnd
         self._all = list(dict.fromkeys(self.ends.values()))
         self.client_id = unique_client_id(next(EngineClerk._next))
@@ -588,8 +626,8 @@ class EngineFleetClerk:
 
     def _command(self, op: str, key: str, value: str = ""):
         from ..engine.shardkv import ERR_WRONG_GROUP
-        from ..services.shardkv import key2shard
 
+        key2shard = self.space.shard_of
         if op != "Get":
             self.command_id += 1
         args = EngineCmdArgs(
@@ -687,8 +725,7 @@ class PipelinedFleetClerk(EngineFleetClerk):
         return out
 
     def _one_window(self, ops):
-        from ..services.shardkv import key2shard
-
+        key2shard = self.space.shard_of
         frame_args = []
         for op, key, value in ops:
             if op != "Get":

@@ -381,12 +381,10 @@ class ShardWalReplay:
         write acked just before its shard went BEPULLING must land in
         that (now non-serving) slot so a peer's later pull sees it, and
         a subsequent WAL delete record clears it in order."""
-        from ..services.shardkv import key2shard
-
         rep = self.skv.reps.get(gid)
         if rep is None:
             return  # record from a gid this process no longer hosts
-        sh = rep.shards[key2shard(key)]
+        sh = rep.slot(self.skv.space.shard_of(key))
         if sh.latest.get(cid, -1) >= cmd:
             return  # already in the checkpoint / an earlier redo
         if op == "Put":

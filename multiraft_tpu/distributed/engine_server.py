@@ -35,7 +35,7 @@ from typing import Optional, Sequence
 from ..engine.core import EngineConfig
 from ..engine.firehose import MAX_FIREHOSE_ROWS
 from ..engine.host import EngineDriver
-from ..engine.instrument import count_compiles
+from ..engine.instrument import ReadyStages, count_compiles
 from ..engine.kv import BatchedKV, KVOp
 from ..porcupine.kv import OP_GET
 from .engine_durability import (
@@ -511,17 +511,10 @@ def serve_engine_kv(
     # compile or cache load where it falls: the 5-tick program in
     # ``elect``, both single-tick variants and the served fused program
     # in ``warm``.
-    ready = dict.fromkeys(
-        ("restore", "elect", "warm", "replay", "checkpoint"), 0.0
-    )
-
-    def lap(stage: str, t0: float) -> float:
-        now = time.perf_counter()
-        ready[stage] = now - t0
-        return now
+    ready = ReadyStages("restore", "elect", "warm", "replay", "checkpoint")
 
     def build():
-        t = time.perf_counter()
+        ready.start()
         mesh = make_mesh(mesh_devices) if mesh_devices else None
         driver = None
         if data_dir:
@@ -536,7 +529,7 @@ def serve_engine_kv(
             blob = driver.restored_extra.get("service")
             if blob:
                 kv.load_state_dict(blob)
-            t = lap("restore", t)
+            ready.lap("restore")
         else:
             # Shape knobs for throughput deployments (the firehose
             # bench serves G=256 at INGEST=24; defaults match the
@@ -550,7 +543,7 @@ def serve_engine_kv(
             driver = EngineDriver(cfg, seed=seed, mesh=mesh)
             kv = BatchedKV(driver, record_groups=list(record_groups or []))
             driver.run_until_quiet_leaders(2000)
-            t = lap("elect", t)
+            ready.lap("elect")
         # Warm-up BEFORE the readiness line: elect leaders and compile
         # both tick variants (quiet + loaded).  The first jit compile
         # takes tens of seconds and runs on the scheduler loop — doing
@@ -581,15 +574,15 @@ def serve_engine_kv(
                 os.environ.get("MULTIRAFT_SERVE_TICKS_PER_PUMP", "2")
             ),
         )
-        t = lap("warm", t)
+        ready.lap("warm")
         if dur is not None:
             svc.replay_wal()  # recovery completes before readiness
-            t = lap("replay", t)
+            ready.lap("replay")
             # Fold the replayed state into a fresh checkpoint and
             # rotate: bounds the next recovery, and discards the
             # duplicate records the replay's own apply hooks appended.
             dur.checkpoint()
-            lap("checkpoint", t)
+            ready.lap("checkpoint")
         return svc
 
     try:
@@ -597,8 +590,7 @@ def serve_engine_kv(
     except BaseException:
         node.close()  # a refused start leaves no listener behind
         raise
-    for stage, secs in ready.items():
-        metrics.set(f"ready.{stage}_s", secs)
+    ready.publish(metrics)
     metrics.set("engine.mesh_devices", float(mesh_devices))
     metrics.set("engine.replicas", float(svc.kv.driver.cfg.P))
     node.add_service("EngineKV", svc)

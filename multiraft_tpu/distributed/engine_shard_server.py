@@ -22,6 +22,8 @@ from typing import Optional, Sequence
 from ..engine.core import EngineConfig
 from ..engine.firehose import MAX_FIREHOSE_ROWS
 from ..engine.host import EngineDriver
+from ..engine.instrument import ReadyStages, count_compiles
+from ..services.shardctrler import ShardSpace
 from ..sim.scheduler import TIMEOUT
 from .engine_durability import (
     EngineDurability,
@@ -618,10 +620,24 @@ class EngineShardKVService:
             peers[g] = end
         self.peers = peers
 
+    def info(self, _args=None) -> dict:
+        """Topology, as ``EngineKV.info``, and the shard space: a clerk
+        routes by ``shards`` and ``partitioner`` as THIS server states
+        them (``ShardSpace(shards, partitioner)``), never by its own
+        process's constants."""
+        state = self.skv.driver.state.term.addressable_shards
+        return {
+            "G": self.skv.driver.cfg.G,
+            "P": self.skv.driver.cfg.P,
+            "state_devices": len({s.device for s in state}),
+            "shards": self.skv.space.count,
+            "partitioner": self.skv.space.partitioner,
+        }
+
     def config(self, args):
         """Latest committed config as ``(num, shards, groups)`` — the
         fleet clerk's routing source (shardctrler Query analog)."""
-        cfg = self.skv.query_latest()
+        cfg = self.skv.configs[-1]
         return (
             cfg.num,
             list(cfg.shards),
@@ -650,7 +666,8 @@ class EngineShardKVService:
             pack_reply,
         )
         from ..engine.shardkv import ERR_NO_KEY, ERR_WRONG_GROUP, OK
-        from ..services.shardkv import key2shard
+
+        key2shard = self.skv.space.shard_of
 
         def run():
             raw = bytes(blob)
@@ -688,6 +705,7 @@ class EngineShardKVService:
                 t = self.skv.get_fast(f.keys[r])
                 if t.err == ERR_WRONG_GROUP:
                     err[r] = FH_WRONG_GROUP
+                    self.m.inc("shard.wrong_group")
                 elif t.err == ERR_NO_KEY:
                     err[r] = FH_NO_KEY
                 else:
@@ -744,7 +762,8 @@ class EngineShardKVService:
         process owns answer ErrWrongGroup per-op so the fleet clerk
         re-frames them to the owner."""
         from ..engine.shardkv import ERR_WRONG_GROUP
-        from ..services.shardkv import key2shard
+
+        key2shard = self.skv.space.shard_of
 
         if len(args_list) > self.MAX_BATCH:
             return [
@@ -763,8 +782,7 @@ class EngineShardKVService:
                 ).append(i)
 
             def submit(a):
-                cfg = self.skv.query_latest()
-                gid = cfg.shards[key2shard(a.key)]
+                gid = self.skv.owner_of(a.key)
                 if gid not in self.skv.reps:
                     return None  # peer-owned (or unassigned) shard
                 if self._fleet and self.skv.is_sealed(gid):
@@ -844,7 +862,6 @@ class EngineShardKVService:
 
     def command(self, args: EngineCmdArgs):
         from ..engine.shardkv import ERR_WRONG_GROUP
-        from ..services.shardkv import key2shard
 
         if args.op == "Get":
             self.m.inc("kv.gets")
@@ -858,6 +875,7 @@ class EngineShardKVService:
                 while self.sched.now < deadline:
                     t = self.skv.get_fast(args.key)
                     if t.err == ERR_WRONG_GROUP:
+                        self.m.inc("shard.wrong_group")
                         # Fleet: the owner is (probably) another
                         # process — answer so the clerk re-routes.
                         if self._fleet:
@@ -884,11 +902,11 @@ class EngineShardKVService:
             deadline = t_start + self.DEADLINE_S
             t_parked = 0.0
             while self.sched.now < deadline:
-                cfg = self.skv.query_latest()
-                gid = cfg.shards[key2shard(args.key)]
+                gid = self.skv.owner_of(args.key)
                 if gid not in self.skv.reps:
                     if self._fleet:
                         # Hosted by a peer process: tell the clerk.
+                        self.m.inc("shard.wrong_group")
                         return EngineCmdReply(err=ERR_WRONG_GROUP)
                     # Shard unassigned: as above, waits for an admin
                     # op's RPC, not for a pump end.
@@ -1015,6 +1033,7 @@ def serve_engine_shardkv(
     mesh_devices: int = 0,
     spare_slots: int = 0,
     replicas: int = 3,
+    shards: Optional[int] = None,  # the shard space (ShardSpace.of)
     voters: Optional[Sequence[int]] = None,
     fleet_addrs: Optional[dict] = None,  # proc -> (host, port), all procs
     me: Optional[int] = None,  # this process's index in fleet_addrs
@@ -1034,11 +1053,27 @@ def serve_engine_shardkv(
     client writes, admin ops, and migration inserts/deletes); a
     restarted process recovers every acknowledged op, and in a fleet
     the GC handshake is gated so a migrated-in blob is never the only
-    un-fsynced copy."""
+    un-fsynced copy.
+
+    ``shards`` states the shard space (``ShardSpace.of``: the
+    reference's ten by first byte, any other count by crc32 of the
+    whole key); ``EngineShardKV.info`` returns it, the checkpoint
+    records it, and a ``data_dir`` written at another is refused
+    (ValueError naming both).  ``join_gids`` join in ONE ``join``
+    operation (config 1), as the reference's ``Join`` takes a map of
+    many groups."""
     from ..engine.shardkv import BatchedShardKV
 
+    space = ShardSpace.of(shards)
     node = RpcNode(listen=True, host=host, port=port)
     sched = node.sched
+    metrics = node.obs.metrics
+    count_compiles(metrics)  # engine.compiles / engine.compile_s
+    # Time to ``ready`` by stage, as serve_engine_kv's gauges; ``join``
+    # is the bootstrap join from its proposal to every group at rest.
+    ready = ReadyStages(
+        "restore", "elect", "warm", "join", "replay", "checkpoint"
+    )
     local_gids = list(gids) if gids is not None else None
     # spare_slots: extra idle engine groups the placement controller
     # can adopt migrated gids into (distributed/placement.py).
@@ -1063,6 +1098,7 @@ def serve_engine_shardkv(
     n_replicas = max(3, int(replicas))
 
     def build():
+        ready.start()
         mesh = make_mesh(mesh_devices) if mesh_devices else None
         driver = None
         if data_dir:
@@ -1073,7 +1109,7 @@ def serve_engine_shardkv(
                 )
         restored = driver is not None
         if restored:
-            node.obs.metrics.inc("engine.restores")
+            metrics.inc("engine.restores")
         if not restored:
             cfg = EngineConfig(
                 G=G_local, P=n_replicas, L=64, E=8, INGEST=8
@@ -1092,11 +1128,16 @@ def serve_engine_shardkv(
             # client traffic.
             ok = driver.run_until_quiet_leaders(2000)
             assert ok, "engine groups failed to elect"
-        skv = BatchedShardKV(driver, gids=local_gids)
+        # The scrapeable registry from here on: the bootstrap join's
+        # counters and the shard gauges belong in every scrape (tick
+        # SPANS stay gated on the diagnostic tracer below).
+        driver.metrics = metrics
+        skv = BatchedShardKV(driver, gids=local_gids, space=space)
         if restored:
             blob = driver.restored_extra.get("service")
             if blob:
                 skv.load_state_dict(blob)
+        ready.lap("restore" if restored else "elect")
         # Warm the LOADED tick variant before the readiness line (the
         # jit compile takes tens of seconds on CPU and would otherwise
         # land under the first admin/client RPC and time it out).  A
@@ -1105,20 +1146,28 @@ def serve_engine_shardkv(
         # fleet mode, where every process's history must stay aligned.
         skv.driver.start(0, None)
         skv.pump(8)
-        if not restored:
+        ready.lap("warm")
+        if not restored and join_gids:
             # A restored process's config history lives in its
-            # checkpoint + WAL — re-running the bootstrap joins would
-            # allocate fresh ctrler ids the dedup table can't absorb
+            # checkpoint + WAL — re-running the bootstrap join would
+            # allocate a fresh ctrler id the dedup table can't absorb
             # and append a spurious config per restart.
-            for gid in join_gids or []:
-                skv.admin_sync("join", [gid])
+            skv.admin_sync("join", list(join_gids))
+            # To quiescence: every group has applied config 1 and the
+            # sweep has nothing left to visit.
+            for _ in range(400):
+                if skv.at_rest():
+                    break
+                skv.pump(5)
+            assert skv.at_rest(), "bootstrap join did not settle"
+            ready.lap("join")
+        skv._set_gauges()
         dur = (
             EngineDurability(data_dir, driver, skv,
                              checkpoint_every_s=checkpoint_every_s,
-                             metrics=node.obs.metrics)
+                             metrics=metrics)
             if data_dir else None
         )
-        driver.metrics = node.obs.metrics  # scrapeable tick counter
         if node.tracer is not None:
             driver.tracer = node.tracer  # ticks + RPCs on one timeline
         svc = EngineShardKVService(sched, skv, peers=peers, durability=dur,
@@ -1132,10 +1181,19 @@ def serve_engine_shardkv(
                                    ship_window_s=ship_window_s)
         if dur is not None:
             svc.replay_wal()  # recovery completes before readiness
+            ready.lap("replay")
             dur.checkpoint()  # fold replay into a fresh checkpoint
+            ready.lap("checkpoint")
         return svc
 
-    svc = sched.run_call(build, timeout=600.0)
+    try:
+        svc = sched.run_call(build, timeout=600.0)
+    except BaseException:
+        node.close()  # a refused start leaves no listener behind
+        raise
+    ready.publish(metrics)
+    metrics.set("engine.mesh_devices", float(mesh_devices))
+    metrics.set("engine.replicas", float(svc.skv.driver.cfg.P))
     node.add_service("EngineShardKV", svc)
     node.engine_service = svc
     # Overload watch: stage-p99/queue-gauge bounds → OVERLOAD records.

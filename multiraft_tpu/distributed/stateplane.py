@@ -57,7 +57,7 @@ import zlib
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..services.shardkv import SERVING, key2shard
+from ..services.shardkv import SERVING
 from ..utils.knobs import knob_bool, knob_float, knob_int
 from ..transport import codec
 
@@ -741,7 +741,7 @@ def redo_record(skv, gid: int, rec: tuple) -> None:
     rep = skv.reps.get(gid)
     if rep is None:
         return
-    sh = rep.shards[key2shard(key)]
+    sh = rep.slot(skv.space.shard_of(key))
     if sh.latest.get(cid, -1) >= cmd:
         return
     if op == "Put":

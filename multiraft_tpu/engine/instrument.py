@@ -37,7 +37,7 @@ from jax.profiler import TraceAnnotation
 
 from ..utils.metrics import Hist, Metrics
 
-__all__ = ["pump_phase", "count_compiles"]
+__all__ = ["pump_phase", "count_compiles", "ReadyStages"]
 
 
 @contextlib.contextmanager
@@ -57,6 +57,29 @@ def pump_phase(
             metrics.observe(
                 hist or f"pump.{phase}_s", time.perf_counter() - t0
             )
+
+
+class ReadyStages:
+    """Time to ``ready`` by stage, published once as gauges
+    ``ready.<stage>_s`` (0.0: the stage did not run).  The first use of
+    a program pays its compile or cache load where it falls."""
+
+    def __init__(self, *stages: str) -> None:
+        self.secs = dict.fromkeys(stages, 0.0)
+        self._t = time.perf_counter()
+
+    def start(self) -> None:
+        self._t = time.perf_counter()
+
+    def lap(self, stage: str) -> None:
+        """``stage`` ends now; the next one starts."""
+        now = time.perf_counter()
+        self.secs[stage] = now - self._t
+        self._t = now
+
+    def publish(self, metrics: Metrics) -> None:
+        for stage, secs in self.secs.items():
+            metrics.set(f"ready.{stage}_s", secs)
 
 
 def count_compiles(metrics: Metrics) -> None:

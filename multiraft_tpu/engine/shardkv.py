@@ -40,7 +40,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional
+import types
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,17 +49,12 @@ import numpy as np
 
 from ..porcupine.kv import OP_APPEND, OP_GET, OP_PUT, KvInput, KvOutput
 from ..porcupine.model import Operation
-from ..services.shardctrler import NSHARDS, Config, rebalance
-from ..services.shardkv import (
-    BEPULLING,
-    GCING,
-    PULLING,
-    SERVING,
-    key2shard,
-)
+from ..services.shardctrler import Config, ShardSpace, rebalance
+from ..services.shardkv import BEPULLING, GCING, PULLING, SERVING
 from .firehose import FH_OK, FH_WRONG_GROUP, FirehoseFrame
 from .frontier import FrontierService
 from .host import EngineDriver, PayloadSlice
+from .instrument import pump_phase
 
 __all__ = [
     "ShardTicket",
@@ -151,17 +147,47 @@ class _ShardSlot:
     latest: Dict[int, int] = dataclasses.field(default_factory=dict)
 
 
+class _NoSlot(_ShardSlot):
+    """What a shard with no slot reads as: empty and ``SERVING``.
+    Read-only (its maps are proxies), so a write through a slot that
+    was never made fails loudly instead of vanishing."""
+
+    def __init__(self) -> None:
+        object.__setattr__(self, "state", SERVING)
+        object.__setattr__(self, "data", types.MappingProxyType({}))
+        object.__setattr__(self, "latest", types.MappingProxyType({}))
+
+    def __setattr__(self, name: str, value: Any) -> None:
+        raise AttributeError(f"no slot here: make one with slot() ({name})")
+
+
+_NO_SLOT = _NoSlot()
+
+
+class _Slots(dict):
+    """A replica's shard slots, sparse: shard -> :class:`_ShardSlot`
+    only for shards the group owns or is migrating.  Indexing a shard
+    without one reads :data:`_NO_SLOT` and stores nothing."""
+
+    def __missing__(self, shard: int) -> _ShardSlot:
+        return _NO_SLOT
+
+
 class _Replica:
     """Host-side applied state of one replica group (gid = engine
     group index)."""
 
-    def __init__(self, gid: int) -> None:
+    def __init__(self, gid: int, config0: Config) -> None:
         self.gid = gid
-        self.cur = Config(num=0, shards=[0] * NSHARDS, groups={})
+        # Configs are shared between groups and never written after the
+        # config RSM appended them.
+        self.cur = config0
         self.prev = self.cur
-        self.shards: Dict[int, _ShardSlot] = {
-            s: _ShardSlot() for s in range(NSHARDS)
-        }
+        # Slots exist for the shards this group owns or is migrating
+        # (made at the config that gives it the shard, dropped by
+        # Challenge 1's deletion); a group at G x NSHARDS scale holds
+        # three or four.
+        self.shards: Dict[int, _ShardSlot] = _Slots()
         # Outstanding internal proposals (ticket per kind/shard).
         self.pending_config: Optional[ShardTicket] = None
         self.pending_insert: Dict[int, ShardTicket] = {}
@@ -180,6 +206,35 @@ class _Replica:
         # risk two serving copies (unseal_group enforces this).
         self.export_dispatched = False
 
+    def slot(self, shard: int) -> _ShardSlot:
+        """The shard's slot to WRITE through: made on first use."""
+        sh = self.shards.get(shard)
+        if sh is None:
+            sh = self.shards[shard] = _ShardSlot()
+        return sh
+
+    def settled(self) -> bool:
+        """No shard of this group is mid-migration."""
+        return all(sh.state == SERVING for sh in self.shards.values())
+
+    def copy(self) -> "_Replica":
+        """A copy that shares nothing mutable with this one: the slots'
+        maps are copied, the (immutable) configs shared, the internal
+        proposals dropped (their tickets belong to the live driver's
+        payload bindings; re-proposal is idempotent)."""
+        new = object.__new__(_Replica)
+        new.__dict__.update(self.__dict__)
+        new.shards = _Slots(
+            (s, _ShardSlot(sh.state, dict(sh.data), dict(sh.latest)))
+            for s, sh in self.shards.items()
+        )
+        new.pending_config = None
+        new.pending_insert = {}
+        new.pending_delete = {}
+        new.pending_confirm = {}
+        new.pending_since = 0
+        return new
+
     def can_serve(self, shard: int) -> bool:
         """Challenge 2 gate (mirror of services/shardkv.py:225-232).
         ``getattr``: checkpoints pickled before the placement layer
@@ -195,12 +250,12 @@ class _Replica:
 def route_keys(table: jnp.ndarray, key_hashes: jnp.ndarray) -> jnp.ndarray:
     """Vectorized client-op routing: key hash → shard → engine group.
 
-    ``table`` is the i32[NSHARDS] shard→gid array maintained by
+    ``table`` is the i32[shards] shard→gid array maintained by
     :meth:`BatchedShardKV.shard_table`; this is the device half of the
     reference's ``key2shard`` + config lookup
     (reference: shardkv/client.go:22-29, 68-129) for batched firehoses.
     """
-    return table[key_hashes % NSHARDS]
+    return table[key_hashes % table.shape[0]]
 
 
 class BatchedShardKV(FrontierService):
@@ -230,11 +285,15 @@ class BatchedShardKV(FrontierService):
     """
 
     def __init__(
-        self, driver: EngineDriver, gids: Optional[List[int]] = None
+        self, driver: EngineDriver, gids: Optional[List[int]] = None,
+        space: Optional[ShardSpace] = None,
     ) -> None:
         if driver.cfg.G < 2:
             raise ValueError("BatchedShardKV needs G >= 2 (ctrler + >=1 group)")
         super().__init__(driver)
+        # The deployment's shard space (count + partitioner): default
+        # the reference's ten shards by first byte.
+        self.space = space if space is not None else ShardSpace.of()
         G = driver.cfg.G
         if gids is None:
             self.gids = list(range(1, G))
@@ -250,12 +309,23 @@ class BatchedShardKV(FrontierService):
         self._g2l = {gid: i + 1 for i, gid in enumerate(self.gids)}
         self._l2g = {i + 1: gid for i, gid in enumerate(self.gids)}
         # Config RSM applied state (group 0).
-        self.configs: List[Config] = [
-            Config(num=0, shards=[0] * NSHARDS, groups={})
-        ]
+        self.configs: List[Config] = [self.space.empty_config()]
         self._ctrl_latest: Dict[int, int] = {}
-        self.reps: Dict[int, _Replica] = {g: _Replica(g) for g in self.gids}
-        self._route = jnp.zeros((NSHARDS,), jnp.int32)
+        self.reps: Dict[int, _Replica] = {
+            g: _Replica(g, self.configs[0]) for g in self.gids
+        }
+        # The gids the orchestration sweep has work for: behind the
+        # latest config, holding a slot that is not SERVING, or with an
+        # internal proposal outstanding.  Grown where those change
+        # (_apply_ctrl, load_state_dict, adopt_gid, unseal_group, the
+        # durable replay's direct proposals), shrunk by the sweep itself
+        # when it finds a group at rest.  Empty under a settled config.
+        self._active: set = set()
+        # Shards whose owner changed between config n-1 and n, by the
+        # group that gained or lost them: worked out once a config,
+        # shared by every group's apply.
+        self._diffs: Dict[int, Tuple[dict, dict]] = {}
+        self._route = jnp.zeros((self.space.count,), jnp.int32)
         self._ctrl_cmd = 0
         # Ctrler session identity for admin proposals.  Single-instance
         # deployments use 0; split-group deployments (engine/
@@ -304,14 +374,15 @@ class BatchedShardKV(FrontierService):
     # -- checkpoint (pairs with EngineDriver.save/restore) ----------------
 
     def state_dict(self) -> Dict[str, Any]:
-        import copy
-
         blob = super().state_dict()
-        # Deep-copy: the checkpoint must not alias live host state
-        # (tickets inside reps resolve after the snapshot is taken).
-        blob["configs"] = copy.deepcopy(self.configs)
+        # The checkpoint must not alias live MUTABLE host state: slots
+        # are copied map by map (``_Replica.copy``), configs are never
+        # written once appended and are shared, so one pickle holds
+        # each config once however many groups stand at it.
+        blob["space"] = (self.space.count, self.space.partitioner)
+        blob["configs"] = list(self.configs)
         blob["ctrl_latest"] = dict(self._ctrl_latest)
-        blob["reps"] = copy.deepcopy(self.reps)
+        blob["reps"] = {g: r.copy() for g, r in self.reps.items()}
         blob["route"] = np.asarray(self._route)
         blob["ctrl_cmd"] = self._ctrl_cmd
         blob["orchestrate"] = self._orchestrate_enabled
@@ -322,25 +393,43 @@ class BatchedShardKV(FrontierService):
         return blob
 
     def load_state_dict(self, blob: Dict[str, Any]) -> None:
-        import copy
-
+        # A checkpoint of another shard space is refused by name: its
+        # keys sit in the slots ITS partitioner chose, and every config
+        # in it is that long.  (Blobs from before the space was
+        # recorded are the reference's: first byte, as many shards as
+        # their config 0 has.)
+        saved = blob.get("space") or (
+            len(blob["configs"][0].shards), "first_byte"
+        )
+        if tuple(saved) != (self.space.count, self.space.partitioner):
+            raise ValueError(
+                f"checkpoint was written with {ShardSpace(*saved)}, this "
+                f"server was asked for {self.space}: start it with "
+                f"--shards {saved[0]} or on a fresh --data-dir"
+            )
         super().load_state_dict(blob)
         self.configs = list(blob["configs"])
         self._ctrl_latest = dict(blob["ctrl_latest"])
         # Copy (never alias) so re-loading the same blob starts from the
-        # checkpoint, not from this incarnation's later mutations.
-        self.reps = copy.deepcopy(blob["reps"])
-        # Pending-op tickets in the checkpoint are deepcopy clones — the
-        # driver's payload bindings hold *different* ticket objects, so
-        # an eviction after restore would resolve the payload's clone
-        # while rep.pending_* stayed live forever, wedging orchestration.
-        # Clear them: re-proposal is idempotent (config-num and
-        # shard-state gates make duplicates no-ops).
+        # checkpoint, not from this incarnation's later mutations.  The
+        # copies carry no internal proposals: the driver's payload
+        # bindings hold *different* ticket objects than a checkpoint's,
+        # so an eviction after restore would resolve the payload's
+        # while rep.pending_* stayed live forever, wedging orchestration;
+        # re-proposal is idempotent (config-num and shard-state gates
+        # make duplicates no-ops).
+        self.reps = {g: r.copy() for g, r in blob["reps"].items()}
         for rep in self.reps.values():
-            rep.pending_config = None
-            rep.pending_insert.clear()
-            rep.pending_delete.clear()
-            rep.pending_confirm.clear()
+            # Dense slots of an older checkpoint: keep what is owned,
+            # migrating or holds anything.
+            owned = rep.cur.shards
+            rep.shards = _Slots(
+                (s, sh) for s, sh in rep.shards.items()
+                if sh.state != SERVING or sh.data or sh.latest
+                or owned[s] == rep.gid
+            )
+        self._diffs.clear()
+        self._active = set(self.reps)  # one sweep sorts them out
         # copy=True: never alias the unpickled buffer (host.py restore
         # explains the donation hazard).
         self._route = jnp.array(blob["route"], copy=True)
@@ -374,6 +463,19 @@ class BatchedShardKV(FrontierService):
                 "ORDER diverges from this instance's; restart with the "
                 "checkpoint's gid order"
             )
+        self._set_gauges()
+
+    def _set_gauges(self) -> None:
+        """``shard.count``, ``shard.slots`` (live slot objects, every
+        group's), ``shard.config_num``: set where they can change — a
+        sweep in which a group came to rest, a load — never on a quiet
+        pump."""
+        m = self.driver.metrics
+        m.set("shard.count", float(self.space.count))
+        m.set("shard.slots", float(
+            sum(len(rep.shards) for rep in self.reps.values())
+        ))
+        m.set("shard.config_num", float(self.configs[-1].num))
 
     # -- client/admin surface ---------------------------------------------
 
@@ -462,6 +564,12 @@ class BatchedShardKV(FrontierService):
         RSM — the in-process form of the clerk's Query)."""
         return self.configs[-1].clone()
 
+    def owner_of(self, key: str) -> int:
+        """The gid the latest committed config gives ``key``'s shard (0:
+        unassigned) — the handlers' routing read: one hash and one
+        index, no copy of the config."""
+        return self.configs[-1].shards[self.space.shard_of(key)]
+
     def get_fast(self, key: str) -> ShardTicket:
         """Linearizable read served from the applied frontier WITHOUT a
         log entry — the sharded form of ``BatchedKV.get``'s ReadIndex
@@ -472,7 +580,7 @@ class BatchedShardKV(FrontierService):
         config owns the shard in a serving state may answer
         (`_apply_client` above; Challenge 2 gate).  During migration the
         caller sees ``ErrWrongGroup`` and retries, as with logged ops."""
-        shard = key2shard(key)
+        shard = self.space.shard_of(key)
         # Host-side routing: configs[-1].shards and _route are assigned
         # together in _apply_ctrl, and a device readback here would put
         # a sync on the zero-device-work path.
@@ -633,7 +741,7 @@ class BatchedShardKV(FrontierService):
         if not getattr(rep, "sealed", False):
             if self._live(rep.pending_config):
                 return None
-            if any(sh.state != SERVING for sh in rep.shards.values()):
+            if not rep.settled():
                 return None
             if self.configs[-1].num > rep.cur.num:
                 return None  # catching up; export the settled state
@@ -664,7 +772,7 @@ class BatchedShardKV(FrontierService):
             return None
         if self._live(rep.pending_config):
             return None
-        if any(sh.state != SERVING for sh in rep.shards.values()):
+        if not rep.settled():
             return None
         if self.configs[-1].num > rep.cur.num:
             return None
@@ -698,6 +806,9 @@ class BatchedShardKV(FrontierService):
             )
         rep.sealed = False
         rep.export_dispatched = False
+        # The sweep skipped it while sealed.  A set of hosted gids:
+        # bounded by the engine's G-1 slots.
+        self._active.add(gid)  # graftlint: disable=unbounded-queue
 
     def adopt_gid(self, gid: int, blob: Optional[Dict[str, Any]] = None) -> int:
         """Host ``gid`` in a spare engine slot.  ``blob`` is a frozen
@@ -724,7 +835,7 @@ class BatchedShardKV(FrontierService):
                 f"(G={self.driver.cfg.G}, hosting {sorted(self._g2l)})"
             )
         loc = free[0]
-        rep = _Replica(gid)
+        rep = _Replica(gid, self.configs[0])
         if blob is not None:
             rep.cur = blob["cur"].clone()
             rep.prev = blob["prev"].clone()
@@ -733,8 +844,7 @@ class BatchedShardKV(FrontierService):
                     state=state, data=dict(data), latest=dict(latest)
                 )
         else:
-            latest = self.query_latest()
-            rep.cur = latest.clone()
+            rep.cur = self.configs[-1]
             rep.prev = rep.cur
         # Bounded by construction: the free-slot check above caps
         # hosted groups at the engine's fixed G-1 slots.
@@ -742,6 +852,8 @@ class BatchedShardKV(FrontierService):
         self._g2l[gid] = loc
         self._l2g[loc] = gid
         self.reps[gid] = rep
+        # Bounded as self.gids above: a set of hosted gids.
+        self._active.add(gid)  # graftlint: disable=unbounded-queue
         return loc
 
     def group_quiesced(self, gid: int) -> bool:
@@ -766,6 +878,7 @@ class BatchedShardKV(FrontierService):
         del self._l2g[loc]
         self.gids.remove(gid)
         del self.reps[gid]
+        self._active.discard(gid)
 
     # -- admin convenience (pump until the ctrler op commits) -------------
 
@@ -804,7 +917,11 @@ class BatchedShardKV(FrontierService):
 
     def _post_pump(self) -> None:
         if self._orchestrate_enabled:
-            self._orchestrate()
+            # Nested in pump.apply_s; one sample a pump.
+            with pump_phase(
+                self.driver.metrics, "orchestrate", hist="shard.orchestrate_s"
+            ):
+                self._orchestrate()
 
     def _on_evicted(self, payload: Any) -> None:
         if isinstance(payload, PayloadSlice):
@@ -834,12 +951,13 @@ class BatchedShardKV(FrontierService):
         wr = f.write_rows
         if not len(wr):
             return f
-        gids = f.groups[wr]
-        local = np.full(len(gids), -1, np.int64)
-        for gid, loc in self._g2l.items():
-            local[gids == gid] = loc
+        g2l = self._g2l
+        local = np.fromiter(
+            (g2l.get(g, -1) for g in f.groups[wr].tolist()), np.int64, len(wr)
+        )
         bad = wr[local < 0]
         if len(bad):
+            self.driver.metrics.inc("shard.wrong_group", len(bad))
             f.rows_done(bad, np.full(len(bad), FH_WRONG_GROUP, np.uint8))
         good_rows = wr[local >= 0]
         good_local = local[local >= 0]
@@ -874,13 +992,16 @@ class BatchedShardKV(FrontierService):
         clients_l = f.clients_l
         commands_l = f.commands_l
         on_write = self.on_write
+        shard_of = self.space.shard_of
+        wrong = 0
         for j, r in enumerate(sl.rows.tolist()):
             key = keys[r]
-            shard = key2shard(key)
+            shard = shard_of(key)
             if not rep.can_serve(shard):
                 errs[j] = FH_WRONG_GROUP
+                wrong += 1
                 continue
-            sh = rep.shards[shard]
+            sh = rep.slot(shard)
             cid = clients_l[r]
             cmd = commands_l[r]
             if cmd > 0 and sh.latest.get(cid, -1) >= cmd:
@@ -898,6 +1019,8 @@ class BatchedShardKV(FrontierService):
                     key=key, value=vals[r], client_id=cid, command_id=cmd,
                 ))
             errs[j] = FH_OK
+        if wrong:
+            self.driver.metrics.inc("shard.wrong_group", wrong)
         f.rows_done(sl.rows, errs)
 
     # -- apply path --------------------------------------------------------
@@ -946,9 +1069,29 @@ class BatchedShardKV(FrontierService):
             cfg.shards[shard] = gid
         self.configs.append(cfg)
         self._route = jnp.asarray(np.array(cfg.shards, np.int32))
+        self._active.update(self.reps)  # every group is a config behind
         if self.on_ctrl is not None:
             self.on_ctrl(op)
         self._resolve(op, now)
+
+    def _diff(self, old: Config, new: Config) -> Tuple[dict, dict]:
+        """The shards whose owner changed from ``old`` to ``new`` (the
+        config one number on): ``(gained, lost)``, gid -> its shards,
+        ascending.  One pass a config, kept for the groups still to
+        apply it (configs of one number are equal wherever they were
+        built: the rebalance is deterministic)."""
+        hit = self._diffs.get(new.num)
+        if hit is None:
+            gained: Dict[int, List[int]] = {}
+            lost: Dict[int, List[int]] = {}
+            was, now = np.asarray(old.shards), np.asarray(new.shards)
+            for s in np.flatnonzero(was != now).tolist():
+                gained.setdefault(int(now[s]), []).append(s)
+                lost.setdefault(int(was[s]), []).append(s)
+            while len(self._diffs) >= 4:  # groups lag by a config or two
+                del self._diffs[min(self._diffs)]
+            hit = self._diffs[new.num] = (gained, lost)
+        return hit
 
     def _apply_replica(self, rep: _Replica, op: Any, now: int) -> None:
         if isinstance(op, _ClientOp):
@@ -960,21 +1103,21 @@ class BatchedShardKV(FrontierService):
             if (
                 not getattr(rep, "sealed", False)
                 and op.config.num == rep.cur.num + 1
-                and all(
-                    sh.state == SERVING for sh in rep.shards.values()
-                )
+                and rep.settled()
             ):
+                # Only the shards whose owner changed flip; a shard
+                # gained gets its slot here, one lost keeps it until
+                # Challenge 1's deletion.
+                gained, lost = self._diff(rep.cur, op.config)
                 rep.prev = rep.cur
                 rep.cur = op.config
-                for s in range(NSHARDS):
-                    was = rep.prev.shards[s] == rep.gid
-                    mine = op.config.shards[s] == rep.gid
-                    if mine and not was:
-                        rep.shards[s].state = (
-                            SERVING if rep.prev.shards[s] == 0 else PULLING
-                        )
-                    elif was and not mine:
-                        rep.shards[s].state = BEPULLING
+                for s in gained.get(rep.gid, ()):
+                    rep.slot(s).state = (
+                        SERVING if rep.prev.shards[s] == 0 else PULLING
+                    )
+                for s in lost.get(rep.gid, ()):
+                    rep.slot(s).state = BEPULLING
+                self.driver.metrics.inc("shard.config_applies")
             rep.pending_config = None
             self._resolve(op, now)
         elif isinstance(op, _InsertOp):
@@ -983,6 +1126,7 @@ class BatchedShardKV(FrontierService):
                 sh.data = dict(op.data)
                 sh.latest = dict(op.latest)
                 sh.state = GCING  # serve before the old copy is deleted
+                self.driver.metrics.inc("shard.inserts")
                 if self.on_insert is not None:
                     self.on_insert(rep.gid, op.shard, op.config_num,
                                    sh.data, sh.latest)
@@ -997,7 +1141,8 @@ class BatchedShardKV(FrontierService):
             if op.config_num == rep.cur.num:
                 sh = rep.shards[op.shard]
                 if sh.state == BEPULLING:
-                    rep.shards[op.shard] = _ShardSlot()  # Challenge 1
+                    del rep.shards[op.shard]  # Challenge 1: the slot goes
+                    self.driver.metrics.inc("shard.deletes")
                     if self.on_delete is not None:
                         self.on_delete(rep.gid, op.shard, op.config_num)
             self._resolve(op, now)  # < cur.num: already gone, idempotent
@@ -1005,19 +1150,21 @@ class BatchedShardKV(FrontierService):
             sh = rep.shards[op.shard]
             if op.config_num == rep.cur.num and sh.state == GCING:
                 sh.state = SERVING
+                self.driver.metrics.inc("shard.confirms")
                 if self.on_confirm is not None:
                     self.on_confirm(rep.gid, op.shard, op.config_num)
             rep.pending_confirm.pop(op.shard, None)
             self._resolve(op, now)
 
     def _apply_client(self, rep: _Replica, op: _ClientOp, now: int) -> None:
-        shard = key2shard(op.key)
-        sh = rep.shards[shard]
+        shard = self.space.shard_of(op.key)
         # Ownership re-checked at apply time: the config may have moved
         # between proposal and commit (reference: shardkv apply path).
         if not rep.can_serve(shard):
+            self.driver.metrics.inc("shard.wrong_group")
             self._resolve(op, now, err=ERR_WRONG_GROUP)
             return
+        sh = rep.slot(shard)
         if op.op != GET and sh.latest.get(op.client_id, -1) >= op.command_id:
             self._resolve(op, now)  # duplicate write: already applied
             return
@@ -1056,124 +1203,162 @@ class BatchedShardKV(FrontierService):
     PROPOSAL_STALL_TICKS = 200
 
     def _orchestrate(self) -> None:
+        """One sweep of the migration pipeline, over the groups that
+        have work (``_active``): under a settled config, none.  What a
+        visit does is the reference's three tickers, statement for
+        statement; a group found at rest leaves the set."""
+        active = self._active
+        m = self.driver.metrics
+        # What the sweep skips, so that visited / (visited + skipped) is
+        # the share of the groups a pump walks (1 before the active set).
+        m.inc("shard.orchestrate_skipped", len(self.reps) - len(active))
+        if not active:
+            return
+        n_active = len(active)
+        m.inc("shard.orchestrate_groups", n_active)
         latest = self.configs[-1]
-        for gid in list(self.gids):
-            rep = self.reps[gid]
+        for gid in sorted(active):
+            rep = self.reps.get(gid)
+            if rep is None:
+                active.discard(gid)
+                continue
             if getattr(rep, "sealed", False):
                 continue  # frozen for export: no proposals of any kind
-            pend = [rep.pending_config,
-                    *rep.pending_insert.values(),
-                    *rep.pending_delete.values(),
-                    *rep.pending_confirm.values()]
-            if not any(self._live(t) for t in pend):
-                rep.pending_since = 0
-            elif getattr(rep, "pending_since", 0) == 0:
-                rep.pending_since = self.driver.tick
-            elif (
-                self.driver.tick - rep.pending_since
-                > self.PROPOSAL_STALL_TICKS
-            ):
-                rep.pending_config = None
-                rep.pending_insert.clear()
-                rep.pending_delete.clear()
-                rep.pending_confirm.clear()
-                rep.pending_since = 0
-            # (a) config advance — only participating (or about to
-            # participate) groups need to track configs.
+            self._orchestrate_group(gid, rep, latest)
             if (
-                latest.num > rep.cur.num
-                and not self._live(rep.pending_config)
-                and all(sh.state == SERVING for sh in rep.shards.values())
+                rep.cur.num == latest.num
+                and rep.pending_since == 0
+                and not rep.pending_delete
+                and rep.settled()
             ):
-                nxt = self.configs[rep.cur.num + 1].clone()
-                t = ShardTicket(group=gid)
-                rep.pending_config = t
-                self.driver.start(self._g2l[gid], _ConfigOp(config=nxt, ticket=t))
+                active.discard(gid)
+        if len(active) != n_active:  # a group came to rest: slots moved
+            self._set_gauges()
+
+    def at_rest(self) -> bool:
+        """No group is behind the latest config, mid-migration or
+        waiting on an internal proposal: the sweep has nobody to visit."""
+        return not self._active
+
+    def _orchestrate_group(self, gid: int, rep: _Replica,
+                           latest: Config) -> None:
+        pend = [rep.pending_config,
+                *rep.pending_insert.values(),
+                *rep.pending_delete.values(),
+                *rep.pending_confirm.values()]
+        if not any(self._live(t) for t in pend):
+            rep.pending_since = 0
+        elif getattr(rep, "pending_since", 0) == 0:
+            rep.pending_since = self.driver.tick
+        elif (
+            self.driver.tick - rep.pending_since
+            > self.PROPOSAL_STALL_TICKS
+        ):
+            rep.pending_config = None
+            rep.pending_insert.clear()
+            rep.pending_delete.clear()
+            rep.pending_confirm.clear()
+            rep.pending_since = 0
+        # (a) config advance — only participating (or about to
+        # participate) groups need to track configs.  The config is the
+        # config RSM's own object: shared, never written.
+        if (
+            latest.num > rep.cur.num
+            and not self._live(rep.pending_config)
+            and rep.settled()
+        ):
+            t = ShardTicket(group=gid)
+            rep.pending_config = t
+            self.driver.start(
+                self._g2l[gid],
+                _ConfigOp(config=self.configs[rep.cur.num + 1], ticket=t),
+            )
+        # Only this group's slots: a shard without one is SERVING.
+        for s, sh in sorted(rep.shards.items()):
             # (b) shard pull: read the source group's applied state once
             # it has applied the same config (the ErrNotReady gate).  A
             # source gid hosted by another fleet process goes through
             # the remote_fetch hook instead of the direct host read.
-            for s in range(NSHARDS):
-                sh = rep.shards[s]
-                if sh.state == PULLING and not self._live(
-                    rep.pending_insert.get(s)
-                ):
-                    if self.migration_paused:
-                        continue  # recovery: no pulls until redo completes
+            if sh.state == PULLING and not self._live(
+                rep.pending_insert.get(s)
+            ):
+                if self.migration_paused:
+                    continue  # recovery: no pulls until redo completes
+                src_gid = rep.prev.shards[s]
+                src = self.reps.get(src_gid)
+                if src is not None:
+                    if src.cur.num < rep.cur.num:
+                        continue  # source hasn't caught up; retry later
+                    pull_data = dict(src.shards[s].data)
+                    pull_latest = dict(src.shards[s].latest)
+                elif self.remote_fetch is not None:
+                    got = self.remote_fetch(src_gid, s, rep.cur.num)
+                    if got is None:
+                        continue  # RPC in flight / source not ready
+                    pull_data, pull_latest = dict(got[0]), dict(got[1])
+                else:
+                    continue  # source unknown and no fleet hook
+                t = ShardTicket(group=gid)
+                rep.pending_insert[s] = t
+                self.driver.metrics.inc("shard.pulls")
+                self.driver.start(
+                    self._g2l[gid],
+                    _InsertOp(
+                        config_num=rep.cur.num,
+                        shard=s,
+                        data=pull_data,
+                        latest=pull_latest,
+                        ticket=t,
+                    ),
+                )
+            # (c) GC handshake: delete at the old owner, then
+            # confirm locally (Challenge 1).  A remote old owner is
+            # deleted through the remote_delete hook — Challenge 1
+            # crosses process boundaries too.
+            elif sh.state == GCING:
+                if self.migration_paused:
+                    continue  # recovery: WAL confirm records stand in
+                dt = rep.pending_delete.get(s)
+                if dt is None or (dt.done and (dt.failed or dt.err != OK)):
                     src_gid = rep.prev.shards[s]
-                    src = self.reps.get(src_gid)
-                    if src is not None:
-                        if src.cur.num < rep.cur.num:
-                            continue  # source hasn't caught up; retry later
-                        pull_data = dict(src.shards[s].data)
-                        pull_latest = dict(src.shards[s].latest)
-                    elif self.remote_fetch is not None:
-                        got = self.remote_fetch(src_gid, s, rep.cur.num)
-                        if got is None:
-                            continue  # RPC in flight / source not ready
-                        pull_data, pull_latest = dict(got[0]), dict(got[1])
+                    if src_gid in self.reps:
+                        t = ShardTicket(group=src_gid)
+                        rep.pending_delete[s] = t
+                        self.driver.start(
+                            self._g2l[src_gid],
+                            _DeleteOp(config_num=rep.cur.num, shard=s,
+                                      ticket=t),
+                        )
+                    elif self.remote_delete is not None:
+                        st = self.remote_delete(src_gid, s, rep.cur.num)
+                        if st is not None:
+                            # Done ticket carries the outcome; a
+                            # not-ready outcome re-enters this branch
+                            # next sweep and re-asks the hook.
+                            rep.pending_delete[s] = ShardTicket(
+                                group=src_gid, done=True,
+                                err=OK if st else ERR_NOT_READY,
+                            )
                     else:
-                        continue  # source unknown and no fleet hook
+                        # No fleet: an unknown source was never
+                        # joined here — nothing to delete.
+                        rep.pending_delete[s] = ShardTicket(
+                            group=0, done=True, err=OK
+                        )
+                elif (
+                    dt.done
+                    and dt.err == OK
+                    and not self._live(rep.pending_confirm.get(s))
+                ):
                     t = ShardTicket(group=gid)
-                    rep.pending_insert[s] = t
+                    rep.pending_confirm[s] = t
                     self.driver.start(
                         self._g2l[gid],
-                        _InsertOp(
-                            config_num=rep.cur.num,
-                            shard=s,
-                            data=pull_data,
-                            latest=pull_latest,
-                            ticket=t,
-                        ),
+                        _ConfirmOp(config_num=rep.cur.num, shard=s,
+                                   ticket=t),
                     )
-                # (c) GC handshake: delete at the old owner, then
-                # confirm locally (Challenge 1).  A remote old owner is
-                # deleted through the remote_delete hook — Challenge 1
-                # crosses process boundaries too.
-                elif sh.state == GCING:
-                    if self.migration_paused:
-                        continue  # recovery: WAL confirm records stand in
-                    dt = rep.pending_delete.get(s)
-                    if dt is None or (dt.done and (dt.failed or dt.err != OK)):
-                        src_gid = rep.prev.shards[s]
-                        if src_gid in self.reps:
-                            t = ShardTicket(group=src_gid)
-                            rep.pending_delete[s] = t
-                            self.driver.start(
-                                self._g2l[src_gid],
-                                _DeleteOp(config_num=rep.cur.num, shard=s,
-                                          ticket=t),
-                            )
-                        elif self.remote_delete is not None:
-                            st = self.remote_delete(src_gid, s, rep.cur.num)
-                            if st is not None:
-                                # Done ticket carries the outcome; a
-                                # not-ready outcome re-enters this branch
-                                # next sweep and re-asks the hook.
-                                rep.pending_delete[s] = ShardTicket(
-                                    group=src_gid, done=True,
-                                    err=OK if st else ERR_NOT_READY,
-                                )
-                        else:
-                            # No fleet: an unknown source was never
-                            # joined here — nothing to delete.
-                            rep.pending_delete[s] = ShardTicket(
-                                group=0, done=True, err=OK
-                            )
-                    elif (
-                        dt.done
-                        and dt.err == OK
-                        and not self._live(rep.pending_confirm.get(s))
-                    ):
-                        t = ShardTicket(group=gid)
-                        rep.pending_confirm[s] = t
-                        self.driver.start(
-                            self._g2l[gid],
-                            _ConfirmOp(config_num=rep.cur.num, shard=s,
-                                       ticket=t),
-                        )
-                elif sh.state == SERVING:
-                    rep.pending_delete.pop(s, None)
+            elif sh.state == SERVING:
+                rep.pending_delete.pop(s, None)
 
 
 class BatchedShardClerk:
@@ -1222,8 +1407,7 @@ class BatchedShardClerk:
 
         def _submit(self) -> None:
             self.submit_tick = self.clerk.skv.driver.tick
-            cfg = self.clerk.skv.query_latest()
-            gid = cfg.shards[key2shard(self.key)]
+            gid = self.clerk.skv.owner_of(self.key)
             if gid not in self.clerk.skv.reps:
                 self.ticket = None  # shard unassigned; retry after pump
                 return
@@ -1258,7 +1442,7 @@ class BatchedShardClerk:
         return self.Session(self, op, key, value, self.command_id)
 
     def _record_op(self, s: "Session") -> None:
-        shard = key2shard(s.key)
+        shard = self.skv.space.shard_of(s.key)
         if shard in self._record:
             self.histories[shard].append(
                 Operation(
@@ -1282,7 +1466,7 @@ class BatchedShardClerk:
             t = self.skv.get_fast(key)
             if t.err in (OK, ERR_NO_KEY):
                 value = t.value if t.err == OK else ""
-                shard = key2shard(key)
+                shard = self.skv.space.shard_of(key)
                 if shard in self._record:
                     self.histories[shard].append(
                         Operation(

@@ -48,7 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..services.shardctrler import NSHARDS, Config
+from ..services.shardctrler import Config
 from ..services.shardkv import BEPULLING, GCING, PULLING, SERVING
 from .host import EngineDriver
 from .shardkv import (
@@ -61,6 +61,7 @@ from .shardkv import (
     _DeleteOp,
     _InsertOp,
     _ShardSlot,
+    _Slots,
 )
 from .split import SplitFrontierMixin
 
@@ -281,13 +282,13 @@ class SplitShardKV(SplitFrontierMixin, BatchedShardKV):
             rep = self.reps[self._l2g[g]]
             rep.cur = _config_from_wire(blob["cur"])
             rep.prev = _config_from_wire(blob["prev"])
-            rep.shards = {
+            rep.shards = _Slots({
                 int(s): _ShardSlot(
                     state=st, data=dict(data),
                     latest={int(k): int(v) for k, v in lat.items()},
                 )
                 for s, (st, data, lat) in blob["shards"].items()
-            }
+            })
             rep.pending_config = None
             rep.pending_insert.clear()
             rep.pending_delete.clear()
@@ -395,7 +396,7 @@ class SplitShardKV(SplitFrontierMixin, BatchedShardKV):
                 self.driver.start(
                     self._g2l[gid], _ConfigOp(config=nxt, ticket=t)
                 )
-            for s in range(NSHARDS):
+            for s in range(self.space.count):
                 sh = rep.shards[s]
                 # (b) pull: from the LOCAL applied copy of the source
                 # group (every process materializes all groups), gated
@@ -456,7 +457,7 @@ class SplitShardKV(SplitFrontierMixin, BatchedShardKV):
             if led_slot[self._g2l[src_gid]] < 0 or self.migration_paused:
                 continue
             src = self.reps[src_gid]
-            for s in range(NSHARDS):
+            for s in range(self.space.count):
                 if src.shards[s].state != BEPULLING:
                     continue
                 new_gid = src.cur.shards[s]

@@ -24,7 +24,9 @@ fires.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+import heapq
+import zlib
+from typing import ClassVar, Dict, List, Optional, Tuple
 
 from ..raft.messages import ApplyMsg
 from ..raft.node import RaftNode
@@ -35,6 +37,7 @@ from ..transport.network import ClientEnd
 
 __all__ = [
     "NSHARDS",
+    "ShardSpace",
     "Config",
     "ShardCtrler",
     "CtrlerClerk",
@@ -82,6 +85,58 @@ class Config:
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class ShardSpace:
+    """A deployment's shard space, stated once: how many shards there
+    are and which shard a key falls in.  One object reaches the config
+    RSM, the replica groups, the handlers, WAL replay and the state
+    plane, and ``EngineShardKV.info`` tells clerks, so a client and a
+    server cannot disagree silently.
+
+    ``first_byte`` is the reference's ``key2shard`` (first byte mod the
+    count, shardkv/client.go:22-29): every ``user...`` key falls in ONE
+    shard.  ``crc32`` hashes the whole key (the stable hash
+    ``engine_wire.route_group`` already uses)."""
+
+    count: int = NSHARDS
+    partitioner: str = "first_byte"
+
+    PARTITIONERS: ClassVar[Tuple[str, ...]] = ("first_byte", "crc32")
+    REFERENCE_COUNT: ClassVar[int] = 10  # shardctrler/common.go:23
+
+    def __post_init__(self) -> None:
+        if self.count < 1:
+            raise ValueError(f"a shard space needs >= 1 shard, not {self.count}")
+        if self.partitioner not in self.PARTITIONERS:
+            raise ValueError(
+                f"partitioner {self.partitioner!r}: one of {self.PARTITIONERS}"
+            )
+
+    @classmethod
+    def of(cls, count: Optional[int] = None) -> "ShardSpace":
+        """The space ``--shards COUNT`` names: the reference's ten
+        shards keep its first-byte partitioner, any other count hashes
+        the whole key.  ``None``: this process's reference space
+        (``NSHARDS``, first byte)."""
+        if count is None:
+            return cls()
+        if int(count) == cls.REFERENCE_COUNT:
+            return cls(cls.REFERENCE_COUNT, "first_byte")
+        return cls(int(count), "crc32")
+
+    def shard_of(self, key: str) -> int:
+        if self.partitioner == "crc32":
+            return zlib.crc32(key.encode()) % self.count
+        return (ord(key[0]) if key else 0) % self.count
+
+    def empty_config(self) -> "Config":
+        """Config 0: every shard unassigned (gid 0)."""
+        return Config(num=0, shards=[0] * self.count, groups={})
+
+    def __str__(self) -> str:
+        return f"{self.count} shards ({self.partitioner})"
+
+
 def rebalance(shards: List[int], groups: Dict[int, List[str]]) -> List[int]:
     """Minimal-movement shard rebalance
     (reference: shardctrler/common.go:53-132).
@@ -92,38 +147,60 @@ def rebalance(shards: List[int], groups: Dict[int, List[str]]) -> List[int]:
        to the least-loaded group.
 
     Deterministic tie-breaks (sorted gids) because this runs inside the
-    replicated apply path on every replica."""
+    replicated apply path on every replica.
+
+    O((S + G) log G) for S shards over G groups: the least- and
+    most-loaded group come off two heaps with stale entries skipped,
+    and a group gives up its lowest-numbered shard off a heap of its
+    own, where the reference scans every group and every shard a move
+    (same picks, same order: ``tests/test_shard_space.py`` holds it to
+    the scan on seeded histories)."""
     if not groups:
-        return [0] * NSHARDS
-    counts = {gid: 0 for gid in sorted(groups)}
+        return [0] * len(shards)
+    counts = {gid: 0 for gid in groups}
     out = list(shards)
+    held: Dict[int, List[int]] = {gid: [] for gid in groups}
+    unassigned = []
     for s, g in enumerate(out):
         if g in counts:
             counts[g] += 1
+            held[g].append(s)  # ascending: already a heap
         else:
             out[s] = 0
+            unassigned.append(s)
+    # (count, gid): least loaded, lowest gid first.  (-count, gid): most
+    # loaded, lowest gid first.  An entry is live while it states the
+    # group's current count.
+    least = [(c, g) for g, c in counts.items()]
+    heapq.heapify(least)
 
-    def min_gid() -> int:
-        return min(counts, key=lambda g: (counts[g], g))
+    def give(s: int) -> int:
+        while least[0][0] != counts[least[0][1]]:
+            heapq.heappop(least)
+        c, g = least[0]
+        out[s] = g
+        counts[g] = c + 1
+        heapq.heappush(held[g], s)
+        heapq.heapreplace(least, (c + 1, g))
+        return g
 
-    def max_gid() -> int:
-        return max(counts, key=lambda g: (counts[g], -g))
-
-    for s in range(NSHARDS):
-        if out[s] == 0:
-            g = min_gid()
-            out[s] = g
-            counts[g] += 1
+    for s in unassigned:
+        give(s)
+    most = [(-c, g) for g, c in counts.items()]
+    heapq.heapify(most)
     while True:
-        mx, mn = max_gid(), min_gid()
-        if counts[mx] - counts[mn] <= 1:
+        while -most[0][0] != counts[most[0][1]]:
+            heapq.heappop(most)
+        while least[0][0] != counts[least[0][1]]:
+            heapq.heappop(least)
+        mx = most[0][1]
+        if counts[mx] - least[0][0] <= 1:
             break
-        for s in range(NSHARDS):
-            if out[s] == mx:
-                out[s] = mn
-                counts[mx] -= 1
-                counts[mn] += 1
-                break
+        counts[mx] -= 1
+        heapq.heapreplace(most, (-counts[mx], mx))
+        heapq.heappush(least, (counts[mx], mx))
+        mn = give(heapq.heappop(held[mx]))
+        heapq.heappush(most, (-counts[mn], mn))
     return out
 
 

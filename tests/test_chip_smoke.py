@@ -49,6 +49,22 @@ def test_rehearsal_runs_every_single_chip_leg_on_the_cpu():
     assert "served5: --replicas 3 on the same --data-dir refused" in out.stdout
 
 
+def test_rehearsal_runs_the_sharded_leg_on_the_cpu():
+    """``serve-shardkv --shards 103 --join all`` at 31 replica groups:
+    one join, the dict model, a leave and the join back under clerks,
+    ``kill -9`` + restart."""
+    out = _smoke("--rehearse-cpu", "--legs", "sharded")
+    _check_rehearsal(out, {"sharded": "ran"})
+    assert "103 shards (crc32), config 1 = ONE join of 31 groups" in out.stdout
+    assert "shards a group {4: 10, 3: 21}, shard.slots=103" in out.stdout
+    assert "sharded: leave of 3 groups (12 shards change owner)" in out.stdout
+    assert "sharded: join of 3 groups (" in out.stdout
+    assert "porcupine ok over 48 concurrent" in out.stdout
+    assert "after kill -9 + restart: 100 sampled Gets match the model" in out.stdout
+    assert "present exactly once after restart" in out.stdout
+    assert "SIGTERM -> final checkpoint, exit 0" in out.stdout
+
+
 @pytest.mark.slow
 def test_rehearsal_mesh4_leg_on_four_virtual_devices():
     out = _smoke("--rehearse-cpu", "--legs", "mesh4")

@@ -9,6 +9,7 @@ sim scheduler.
 """
 
 import numpy as np
+import pytest
 
 from multiraft_tpu.engine.core import EngineConfig
 from multiraft_tpu.engine.host import EngineDriver
@@ -105,7 +106,11 @@ def test_leave_returns_shards_with_data():
         assert clerk.get(k) == f"w{shard}"
 
 
-def test_challenge1_old_owner_deletes_migrated_shards():
+@pytest.mark.parametrize("form", ["reads-as-empty", "sparse"])
+def test_challenge1_old_owner_deletes_migrated_shards(form):
+    """``reads-as-empty``: the check as it always was, through ``rep.shards[s]``
+    (a shard without a slot reads empty and SERVING).  ``sparse``: the slot
+    itself is gone, and only owned shards have one."""
     skv = make(G=3, seed=3)
     skv.admin_sync("join", [1])
     clerk = BatchedShardClerk(skv, client_id=1)
@@ -119,10 +124,18 @@ def test_challenge1_old_owner_deletes_migrated_shards():
     for s in range(NSHARDS):
         if cfg.shards[s] == 2:
             # Shard moved 1 -> 2: group 1 must hold no data for it.
+            if form == "sparse":
+                assert s not in rep1.shards, f"shard {s}: slot kept at old owner"
+                continue
             assert rep1.shards[s].data == {}, f"shard {s} leaked at old owner"
             assert rep1.shards[s].state == SERVING
         elif cfg.shards[s] == 1 and s in kmap:
             assert kmap[s] in rep1.shards[s].data
+    if form == "sparse":
+        for gid in (1, 2):
+            assert sorted(skv.reps[gid].shards) == [
+                s for s in range(NSHARDS) if cfg.shards[s] == gid
+            ]
 
 
 def test_challenge2_unaffected_shards_serve_during_stalled_migration():

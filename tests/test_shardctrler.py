@@ -3,6 +3,7 @@ property tests for the pure rebalancer."""
 
 import random
 
+import pytest
 
 from multiraft_tpu.harness.ctrler_harness import CtrlerHarness
 from multiraft_tpu.services.shardctrler import NSHARDS, Config, rebalance
@@ -58,9 +59,12 @@ def test_rebalance_leave_moves_only_orphans():
             assert after[s] == three[s], f"shard {s} moved unnecessarily"
 
 
-def test_rebalance_deterministic_and_balanced():
+@pytest.mark.parametrize("n_shards", [NSHARDS, 110], ids=["reference", "110-shards"])
+def test_rebalance_deterministic_and_balanced(n_shards):
+    """At the reference's count and at one that is not the module's
+    constant: the length is the input's, never ``NSHARDS``."""
     rng = random.Random(7)
-    shards = [0] * NSHARDS
+    shards = [0] * n_shards
     live = {}
     next_gid = 1
     for step in range(200):
@@ -79,7 +83,9 @@ def test_rebalance_deterministic_and_balanced():
             for g in shards:
                 counts[g] = counts.get(g, 0) + 1
             assert set(counts) <= set(live)
-            assert max(counts.values()) - min(counts.values()) <= 1
+            load = [counts.get(g, 0) for g in live]  # a group may hold none
+            assert max(load) - min(load) <= 1
+        assert len(shards) == n_shards
 
 
 # -- service tests --------------------------------------------------------
