@@ -228,7 +228,6 @@ class EngineDriver:
         self.inbox = shard_arrays(self.cfg, mesh, self.inbox)
         self._mesh_tick = make_sharded_tick(self.cfg, mesh)
         self._groups_sharding = NamedSharding(mesh, PartitionSpec("groups"))
-        self._n_shards = int(mesh.devices.size)
         # Every device reads the key every tick: put it there once.
         self.key = jax.device_put(
             self.key, NamedSharding(mesh, PartitionSpec())
@@ -284,7 +283,6 @@ class EngineDriver:
         self.mesh = None
         self._mesh_tick = None
         self._groups_sharding = None  # the [G] vectors' sharding, on a mesh
-        self._n_shards = 1
         # Structured counters (utils/metrics.py): ticks always; per-tick
         # wall latency samples when the tracer (diagnostic mode) is on.
         self.metrics = Metrics()
@@ -944,9 +942,7 @@ class EngineDriver:
             self._count_ticks(n)
             tick0 = self.tick
             bl = np.minimum(self.backlog, np.int64(2**31 - 1)).astype(np.int32)
-            if self.mesh is None:
-                bl = jnp.asarray(bl)
-            else:
+            if self.mesh is not None:
                 bl = jax.device_put(bl, self._groups_sharding)
             for p in self._inflight:
                 # Batches already dispatched will consume part of the
@@ -958,34 +954,31 @@ class EngineDriver:
                 bl = jnp.maximum(bl - p.accepts_dev, 0)
             with_drop = self.drop_prob > 0.0
             with_edges = not bool(self.edge_up.all())
-            edge_mask = self._edge_mask() if with_edges else None
+            # The scalars (and, on one chip, the backlog) go as numpy:
+            # each value made by a device program of its own costs the
+            # loop as much as a copy does (PERF.md §6, PR 39).  The edge
+            # mask is a static-dead operand on the clean path.
+            args = (
+                bl, np.float32(self.drop_prob),
+                self._edge_mask() if with_edges else np.zeros((), np.bool_),
+                np.int32(tick0), self.key,
+            )
             if self.mesh is None:
-                if edge_mask is None:
-                    edge_mask = jnp.zeros((), jnp.bool_)  # static-dead operand
-                state, inbox, _bl_left, rec = step_ticks(
-                    cfg, self.state, self.inbox, n, with_drop, with_edges,
-                    bl, jnp.float32(self.drop_prob), edge_mask,
-                    jnp.int32(tick0), self.key,
+                state, inbox, _bl_left, buf, accepts = step_ticks(
+                    cfg, self.state, self.inbox, n, with_drop, with_edges, *args
                 )
             else:
-                # The same scan, each device on its groups; the scalars
-                # go as numpy so no program runs to make them.
-                if edge_mask is None:
-                    edge_mask = np.zeros((), np.bool_)
-                state, inbox, _bl_left, rec = sharded_step_ticks(
+                # The same scan, each device on its groups.
+                state, inbox, _bl_left, buf, accepts = sharded_step_ticks(
                     cfg, self.mesh, n, with_drop, with_edges
-                )(
-                    self.state, self.inbox, bl, np.float32(self.drop_prob),
-                    edge_mask, np.int32(tick0), self.key,
-                )
+                )(self.state, self.inbox, *args)
             self.state, self.inbox = state, inbox
             self.tick = tick0 + n
             pending = PendingTicks(
-                n=n, tick0=tick0, rec=rec,
-                accepts_dev=jnp.sum(rec["accepted"], axis=0),
+                n=n, tick0=tick0, buf=buf, accepts_dev=accepts,
                 t_dispatch=t_dispatch,
                 pump=self.metrics.counters.get("pump.count", 0),
-                shards=self._n_shards,
+                mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             )
             self._inflight.append(pending)  # graftlint: disable=unbounded-queue
         pending.t_dispatched = time.perf_counter()

@@ -34,6 +34,19 @@ makes the returned arrays futures), a dedicated pump thread blocks in
 :meth:`PendingTicks.fetch`, and the loop folds the fetched record back
 in :meth:`EngineDriver.complete_ticks` — so socket I/O, decode and
 acks proceed during device compute (distributed/engine_pump.py).
+
+The record leaves the device as ONE flat ``int32`` buffer per chip
+(:func:`_pack`), not seven arrays: each ``np.asarray`` of a device
+buffer is a latency-bound copy (0.4-0.8 ms on a v5e whether it moves
+320 KB or 3.2 MB; PERF.md §6, PR 39), so ``fetch`` pays one copy per
+chip (seven copies started at once, ``jax.device_get``, measured 0.2-0.5
+ms a pump slower).  Layout, per chip and tick-major: the three scalar lanes
+``commits``, ``leaders``, ``max_term`` (``[n]`` each), then the four
+per-group fields ``accepted``, ``start_index``, ``accept_term``,
+``commit_index`` (``[n, G]`` each, raveled; ``G / n_devices`` groups a
+chip on a mesh) — ``METRIC_KEYS`` order.  :func:`unpack_record` turns
+the fetched buffer back into the per-field record
+``complete_ticks`` reads.
 """
 
 from __future__ import annotations
@@ -50,11 +63,20 @@ from jax.profiler import TraceAnnotation
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .core import METRIC_KEYS, EngineConfig, EngineState, Mailbox, tick_impl
+from .core import (
+    METRIC_KEYS,
+    SCALAR_METRIC_KEYS,
+    EngineConfig,
+    EngineState,
+    Mailbox,
+    tick_impl,
+)
 from .host import drop_messages, mask_active
 from .mesh import INBOX_SPECS, STATE_SPECS, local_cfg, local_shard
 
-__all__ = ["step_ticks", "sharded_step_ticks", "PendingTicks"]
+__all__ = ["step_ticks", "sharded_step_ticks", "unpack_record", "PendingTicks"]
+
+GROUP_METRIC_KEYS = METRIC_KEYS[len(SCALAR_METRIC_KEYS):]
 
 
 def _scan_ticks(
@@ -87,6 +109,35 @@ def _scan_ticks(
     return state, inbox, backlog, rec
 
 
+def _pack(rec):
+    """The stacked record as the one buffer ``fetch`` copies, plus the
+    batch's accepted commands a group (``accepts``, i32[G]: what a
+    later dispatch subtracts from the backlog it ships) — both made in
+    the program that ran the scan, so no program of their own runs."""
+    buf = jnp.concatenate([rec[k].reshape(-1) for k in METRIC_KEYS])
+    return buf, jnp.sum(rec["accepted"], axis=0)
+
+
+def unpack_record(
+    buf: np.ndarray, n: int, shards: int, lanes: bool
+) -> Dict[str, np.ndarray]:
+    """A fetched buffer of :func:`_pack`'s layout as the per-field
+    record: ``[n]`` scalars and ``[n, G]`` fields, or on a mesh
+    (``lanes``) ``[n, shards]`` scalar lanes, one a device.  Views of
+    ``buf`` on one device; a mesh's fields are its ``shards`` chips'
+    group ranges side by side, which one reshape copies into ``[n, G]``."""
+    rows = buf.reshape(shards, -1)
+    head = len(SCALAR_METRIC_KEYS) * n
+    scalars = rows[:, :head].reshape(shards, len(SCALAR_METRIC_KEYS), n)
+    fields = rows[:, head:].reshape(shards, len(GROUP_METRIC_KEYS), n, -1)
+    rec = {}
+    for i, k in enumerate(SCALAR_METRIC_KEYS):
+        rec[k] = scalars[:, i].T if lanes else scalars[0, i]
+    for i, k in enumerate(GROUP_METRIC_KEYS):
+        rec[k] = fields[:, i].transpose(1, 0, 2).reshape(n, -1)
+    return rec
+
+
 @functools.partial(
     jax.jit, static_argnums=(0, 3, 4, 5), donate_argnums=(1, 2)
 )
@@ -105,16 +156,18 @@ def step_ticks(
 ):
     """``n_ticks`` consensus rounds fused under one scan, with the
     backlog/new_cmds computation in the carry and every per-tick metric
-    stacked (``rec[k]`` has a leading ``[n_ticks]`` axis).
+    stacked (``rec[k]`` has a leading ``[n_ticks]`` axis) and packed
+    into one buffer (:func:`_pack`, :func:`unpack_record`).
 
-    Returns ``(state, inbox, backlog_left, rec)``.  ``with_drop`` /
-    ``with_edges`` are static so the clean path compiles none of the
-    fault machinery; ``tick0`` and ``backlog`` are device values so a
-    moving tick counter never retraces."""
-    return _scan_ticks(
+    Returns ``(state, inbox, backlog_left, buf, accepts)``.
+    ``with_drop`` / ``with_edges`` are static so the clean path
+    compiles none of the fault machinery; ``tick0`` and ``backlog`` are
+    device values so a moving tick counter never retraces."""
+    state, inbox, backlog, rec = _scan_ticks(
         cfg, state, inbox, n_ticks, with_drop, with_edges,
         backlog, drop_prob, edge_mask, tick0, key,
     )
+    return (state, inbox, backlog) + _pack(rec)
 
 
 @functools.lru_cache(maxsize=None)
@@ -125,12 +178,14 @@ def sharded_step_ticks(
     under ``shard_map`` each device scans its ``G / n`` groups for
     ``n_ticks``, zero collectives.  Returns the jitted
     ``run(state, inbox, backlog, drop_prob, edge_mask, tick0, key)``
-    with the same results, where per-group records keep their global
-    ``[n_ticks, G]`` shape sharded on the groups axis and scalar records
-    come back as ``[n_ticks, n_devices]`` lanes, one per device and not
-    ``psum``-ed: the host sums them (``EngineDriver.complete_ticks``).
-    Bit-equal to :func:`step_ticks` on one device: the random draws are
-    the unsharded ones' rows (``core.shard_rows``)."""
+    with the same results, where each device packs its own share of the
+    record (its scalar lanes and its ``G / n`` groups, :func:`_pack`'s
+    layout) into one global buffer sharded on the groups axis — one
+    buffer, so one copy, a chip — and ``accepts`` keeps the groups
+    sharding.  The scalar lanes are not ``psum``-ed: the host sums them
+    (``EngineDriver.complete_ticks``).  Bit-equal to :func:`step_ticks`
+    on one device: the random draws are the unsharded ones' rows
+    (``core.shard_rows``)."""
     lcfg = local_cfg(cfg, mesh)
 
     def step_ticks_mesh(state, inbox, backlog, drop_prob, edge_mask, tick0, key):
@@ -139,8 +194,7 @@ def sharded_step_ticks(
             backlog, drop_prob, edge_mask, tick0, key,
             local_shard(cfg, lcfg),
         )
-        rec = {k: (v[:, None] if v.ndim == 1 else v) for k, v in rec.items()}
-        return state, inbox, backlog, rec
+        return (state, inbox, backlog) + _pack(rec)
 
     groups, whole = P("groups"), P()
     return jax.jit(
@@ -151,10 +205,7 @@ def sharded_step_ticks(
                 STATE_SPECS, INBOX_SPECS, groups, whole,
                 groups if with_edges else whole, whole, whole,
             ),
-            out_specs=(
-                STATE_SPECS, INBOX_SPECS, groups,
-                {k: P(None, "groups") for k in METRIC_KEYS},
-            ),
+            out_specs=(STATE_SPECS, INBOX_SPECS, groups, groups, groups),
         ),
         donate_argnums=(0, 1),
     )
@@ -164,10 +215,11 @@ class PendingTicks:
     """A dispatched, not-yet-completed fused tick batch.
 
     Created by :meth:`EngineDriver.dispatch_ticks` (scheduler loop,
-    non-blocking); :meth:`fetch` blocks until the stacked metrics are
-    on host and is the ONE call safe to run off the loop thread (the
-    engine-pump thread's whole job); the result then goes back to the
-    loop for :meth:`EngineDriver.complete_ticks`.
+    non-blocking); :meth:`fetch` blocks until the packed record (``buf``,
+    one flat ``int32`` buffer a chip, :func:`_pack`) is on host and is
+    the ONE call safe to run off the loop thread (the engine-pump
+    thread's whole job); the result then goes back to the loop for
+    :meth:`EngineDriver.complete_ticks`.
 
     ``accepts_dev`` stays on device: later dispatches subtract it from
     the host backlog so an in-flight batch's accepted commands are
@@ -175,49 +227,51 @@ class PendingTicks:
     """
 
     __slots__ = (
-        "n", "tick0", "rec", "accepts_dev", "t_dispatch", "t_loop_cpu",
-        "pump", "shards", "t_dispatched", "t_fetch", "t_fetched", "nbytes",
-        "ncopies",
+        "n", "tick0", "buf", "accepts_dev", "t_dispatch", "t_loop_cpu",
+        "pump", "mesh_devices", "t_dispatched", "t_fetch", "t_fetched",
+        "nbytes", "ncopies",
     )
 
     def __init__(
         self,
         n: int,
         tick0: int,
-        rec: Dict[str, jnp.ndarray],
+        buf: jnp.ndarray,
         accepts_dev: jnp.ndarray,
         t_dispatch: float,
         pump: int = 0,
-        shards: int = 1,
+        mesh_devices: int = 0,
     ) -> None:
         self.n = n
         self.tick0 = tick0
-        self.rec = rec
+        self.buf = buf
         self.accepts_dev = accepts_dev
         self.t_dispatch = t_dispatch
         self.pump = pump  # pumps completed at dispatch: the trace tag
-        # Devices every array of ``rec`` is spread over: 1, or the mesh
-        # driver's device count (each then holds one shard of each).
-        self.shards = shards
+        # The mesh driver's device count, each holding one shard of
+        # ``buf`` and one scalar lane of the record; 0 without a mesh.
+        self.mesh_devices = mesh_devices
         # Loop-side CPU the dispatch burned (the serving loop's share
         # of this pump; completion adds its own) — set by the caller.
         self.t_loop_cpu = 0.0
 
     def fetch(self) -> Dict[str, np.ndarray]:
-        """Block until the batch's stacked metrics are host-resident.
-        Pure device wait + copy: touches no driver state, so it is
-        safe off the scheduler loop by construction.  It stamps its
-        own entry, return, the bytes it brought over and the device
-        buffers it copied them from (arrays x shards: a mesh driver's
-        record is read back chip by chip) on the batch;
-        ``complete_ticks`` turns those into ``pump.handoff_s`` (since
-        ``dispatch_ticks`` returned), ``pump.fetch_s``, ``pump.post_s``,
-        ``pump.readback_bytes`` and ``pump.readback_copies`` on the
-        loop."""
+        """Block until the batch's packed record is host-resident and
+        return it per field (:func:`unpack_record`).  Pure device wait +
+        copy: touches no driver state, so it is safe off the scheduler
+        loop by construction.  It stamps its own entry, return, the
+        bytes it brought over and the device buffers it copied them
+        from (one a chip: a mesh driver's buffer is read back shard by
+        shard) on the batch; ``complete_ticks`` turns those into
+        ``pump.handoff_s`` (since ``dispatch_ticks`` returned),
+        ``pump.fetch_s``, ``pump.post_s``, ``pump.readback_bytes`` and
+        ``pump.readback_copies`` on the loop."""
         self.t_fetch = time.perf_counter()
+        shards = max(self.mesh_devices, 1)
         with TraceAnnotation("mrt.pump.fetch", pump=self.pump):
-            out = {k: np.asarray(v) for k, v in self.rec.items()}
-        self.nbytes = sum(v.nbytes for v in out.values())
-        self.ncopies = len(out) * self.shards
+            flat = np.asarray(self.buf)
+            out = unpack_record(flat, self.n, shards, self.mesh_devices > 0)
+        self.nbytes = flat.nbytes
+        self.ncopies = shards
         self.t_fetched = time.perf_counter()
         return out

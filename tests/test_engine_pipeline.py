@@ -116,6 +116,61 @@ def test_overlapped_dispatch_depth2_matches_serial():
     assert_same_world(serial, piped)
 
 
+def _root(a):
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a
+
+
+@pytest.mark.parametrize("n", [1, 2])
+@pytest.mark.parametrize("faults", ["clean", "drop", "edges"])
+def test_fetch_unpacks_the_record_the_scan_produced(faults, n):
+    """The record leaves the device as one packed buffer: ``fetch``
+    hands ``complete_ticks`` what the scan stacked, field by field —
+    same keys, shapes, dtypes and values, and as many bytes — from ONE
+    device copy, and ``accepts_dev`` is the record's ``accepted``
+    summed over the batch's ticks."""
+    from multiraft_tpu.engine.core import METRIC_KEYS
+    from multiraft_tpu.engine.pipeline import _scan_ticks
+
+    d = make_driver(seed=7)
+    assert d.run_until_quiet_leaders(500)
+    rng = np.random.default_rng(n)
+    for g in range(d.cfg.G):
+        for i in range(int(rng.integers(1, 9))):
+            d.start(g, ("w", g, i))
+    if faults == "drop":
+        d.drop_prob = 0.3
+    elif faults == "edges":
+        d.partition_replica(1, 0, False)
+        d.set_edge(2, 1, 2, False)
+    with_edges = not bool(d.edge_up.all())
+    edge = d._edge_mask() if with_edges else jax.numpy.zeros((), bool)
+    # The reference: the scan's own per-field record on the same inputs
+    # (not donated, so the driver can step the same state after it).
+    ref = jax.jit(_scan_ticks, static_argnums=(0, 3, 4, 5))(
+        d.cfg, d.state, d.inbox, n, d.drop_prob > 0.0, with_edges,
+        jax.numpy.asarray(d.backlog.astype(np.int32)),
+        jax.numpy.float32(d.drop_prob), edge, jax.numpy.int32(d.tick), d.key,
+    )[3]
+    ref = {k: np.asarray(v) for k, v in ref.items()}
+    assert ref["accepted"].any()  # the batch did bind something
+
+    p = d.dispatch_ticks(n)
+    got = p.fetch()
+    assert list(got) == list(METRIC_KEYS)
+    for k in METRIC_KEYS:
+        assert got[k].shape == ref[k].shape, k
+        assert got[k].dtype == ref[k].dtype, k
+        assert np.array_equal(got[k], ref[k]), k
+        # one device: every field is a view of the one fetched buffer
+        assert _root(got[k]) is _root(got["commits"]), k
+    assert np.array_equal(np.asarray(p.accepts_dev), ref["accepted"].sum(0))
+    assert p.ncopies == 1
+    assert p.nbytes == sum(v.nbytes for v in ref.values())
+    d.complete_ticks(p, got)
+
+
 def test_complete_out_of_dispatch_order_asserts():
     d = make_driver()
     d.start(0, ("x",))

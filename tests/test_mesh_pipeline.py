@@ -111,7 +111,7 @@ def test_fused_sharded_pump_is_the_single_device_serial_loop(
     # The state never left the mesh, and the readback was per device.
     assert len(sharded.state.term.addressable_shards) == DEVICES
     c = sharded.metrics.counters
-    assert c["pump.readback_copies"] == 14 * 7 * DEVICES
+    assert c["pump.readback_copies"] == 14 * DEVICES  # one buffer a chip
     for k in SCALAR_METRIC_KEYS:
         assert np.ndim(sharded.last_metrics[k]) == 0, k
 
@@ -151,6 +151,79 @@ def test_overlapped_dispatch_on_a_mesh_never_ingests_twice(mesh):
     sharded.complete_ticks(p2, p2.fetch())
     plain._step_serial(4)
     assert_same_world(sharded, plain, "depth 2")
+
+
+@pytest.mark.parametrize("n", [1, 2])
+@pytest.mark.parametrize("faults", ["clean", "drop", "edges"])
+def test_mesh_fetch_unpacks_the_record_the_scan_produced(mesh, faults, n):
+    """Each chip packs its share of the record into its shard of one
+    buffer: ``fetch`` copies one buffer a chip and hands
+    ``complete_ticks`` the per-field record the sharded scan stacked —
+    ``[n, G]`` fields, ``[n, DEVICES]`` scalar lanes, same dtypes and
+    values, as many bytes — and ``accepts_dev`` is its ``accepted``
+    summed over ticks, still sharded on the groups axis."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from multiraft_tpu.engine.core import METRIC_KEYS
+    from multiraft_tpu.engine.mesh import (
+        INBOX_SPECS, STATE_SPECS, local_cfg, local_shard,
+    )
+    from multiraft_tpu.engine.pipeline import _scan_ticks
+
+    d = EngineDriver(CFG, seed=5, mesh=mesh)
+    d.step(40)  # leaders
+    rng = np.random.default_rng(n)
+    for g in range(CFG.G):
+        for j in range(int(rng.integers(1, 9))):
+            d.start(g, (g, j))
+    if faults == "drop":
+        d.drop_prob = 0.3
+    elif faults == "edges":
+        d.partition_replica(1, 0, False)
+        d.set_edge(13, 0, 1, False)
+    with_drop, with_edges = d.drop_prob > 0.0, not bool(d.edge_up.all())
+    edge = d._edge_mask() if with_edges else np.zeros((), np.bool_)
+
+    # The reference: the same scan under shard_map, its record per field
+    # (scalars as one lane a device), not donated.
+    lcfg = local_cfg(CFG, mesh)
+
+    def scan(state, inbox, backlog, drop_prob, edge_mask, tick0, key):
+        rec = _scan_ticks(
+            lcfg, state, inbox, n, with_drop, with_edges, backlog,
+            drop_prob, edge_mask, tick0, key, local_shard(CFG, lcfg),
+        )[3]
+        return {k: (v[:, None] if v.ndim == 1 else v) for k, v in rec.items()}
+
+    groups, whole = P("groups"), P()
+    ref = jax.jit(shard_map(
+        scan, mesh=mesh,
+        in_specs=(STATE_SPECS, INBOX_SPECS, groups, whole,
+                  groups if with_edges else whole, whole, whole),
+        out_specs={k: P(None, "groups") for k in METRIC_KEYS},
+    ))(
+        d.state, d.inbox,
+        jax.device_put(d.backlog.astype(np.int32), d._groups_sharding),
+        np.float32(d.drop_prob), edge, np.int32(d.tick), d.key,
+    )
+    ref = {k: np.asarray(v) for k, v in ref.items()}
+    assert ref["accepted"].any()
+
+    p = d.dispatch_ticks(n)
+    assert len(p.buf.addressable_shards) == DEVICES
+    assert len(p.accepts_dev.addressable_shards) == DEVICES
+    got = p.fetch()
+    assert list(got) == list(METRIC_KEYS)
+    for k in METRIC_KEYS:
+        want = (n, DEVICES) if k in SCALAR_METRIC_KEYS else (n, CFG.G)
+        assert got[k].shape == ref[k].shape == want, k
+        assert got[k].dtype == ref[k].dtype, k
+        assert np.array_equal(got[k], ref[k]), k
+    assert np.array_equal(np.asarray(p.accepts_dev), ref["accepted"].sum(0))
+    assert p.ncopies == DEVICES
+    assert p.nbytes == sum(v.nbytes for v in ref.values())
+    d.complete_ticks(p, got)
 
 
 @pytest.mark.parametrize("with_faults", [False, True], ids=["clean", "faults"])
@@ -312,7 +385,7 @@ def test_cli_mesh_devices_4_serves_a_linearizable_store(tmp_path):
         snap = node.sched.wait(end.call("Obs.snapshot", None), 60.0)["metrics"]
         assert snap["engine.mesh_devices"] == 4
         assert snap["pump.fetch_s_count"] > 0, "the mesh server pumped synchronously"
-        assert snap["pump.readback_copies"] == 7 * 4 * snap["pump.fetch_s_count"]
+        assert snap["pump.readback_copies"] == 4 * snap["pump.fetch_s_count"]
         info = node.sched.wait(end.call("EngineKV.info", None), 60.0)
         assert info["state_devices"] == 4
     finally:

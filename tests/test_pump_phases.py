@@ -31,7 +31,11 @@ from multiraft_tpu.distributed.engine_server import (  # noqa: E402
     serve_engine_shardkv,
 )
 from multiraft_tpu.distributed.tcp import RpcNode  # noqa: E402
-from multiraft_tpu.engine.core import EngineConfig  # noqa: E402
+from multiraft_tpu.engine.core import (  # noqa: E402
+    METRIC_KEYS,
+    SCALAR_METRIC_KEYS,
+    EngineConfig,
+)
 from multiraft_tpu.engine.host import EngineDriver  # noqa: E402
 from multiraft_tpu.engine.kv import BatchedKV  # noqa: E402
 from multiraft_tpu.engine.state_planes import content_fingerprint  # noqa: E402
@@ -162,18 +166,22 @@ def test_phases_tile_the_durable_pump_cycle(served):
             # every phase took one sample a pump
             assert abs((n1 - n0) - pumps) <= 1, (name, n1 - n0, pumps)
     assert abs(total - wall) <= TILE_TOL * wall, (total, wall)
-    # Readback: what fetch brought over is what the record's shapes say
-    # (on a mesh the scalar records are one lane a device), in one copy
-    # for every array and device that holds a shard of it.
+    # Readback: what fetch brought over is what the per-field record's
+    # shapes say ([n] scalars, one lane a device on a mesh, and [n, G]
+    # fields, all int32), in one copy a device: the record is one
+    # packed buffer with a shard on every device.
     twin = EngineDriver(driver.cfg, seed=1, mesh=driver.mesh)
-    p = twin.dispatch_ticks(svc.cycle.ticks)
-    per_pump = sum(v.size * v.dtype.itemsize for v in p.rec.values())
-    for v in p.rec.values():
-        assert len(v.addressable_shards) == shards
-    twin.complete_ticks(p, p.fetch())
-    assert per_pump > 0
+    n = svc.cycle.ticks
+    p = twin.dispatch_ticks(n)
+    assert len(p.buf.addressable_shards) == shards
+    rec = p.fetch()
+    twin.complete_ticks(p, rec)
+    lanes = shards if driver.mesh is not None else 1
+    n_fields = len(METRIC_KEYS) - len(SCALAR_METRIC_KEYS)
+    per_pump = 4 * n * (len(SCALAR_METRIC_KEYS) * lanes + n_fields * driver.cfg.G)
+    assert sum(v.nbytes for v in rec.values()) == per_pump
     assert b["bytes"] - a["bytes"] == pumps * per_pump
-    assert b["copies"] - a["copies"] == pumps * len(p.rec) * shards
+    assert b["copies"] - a["copies"] == pumps * shards
 
 
 @pytest.mark.timeout_s(240)
