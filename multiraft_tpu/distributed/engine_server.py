@@ -35,7 +35,7 @@ from typing import Optional, Sequence
 from ..engine.core import EngineConfig
 from ..engine.firehose import MAX_FIREHOSE_ROWS
 from ..engine.host import EngineDriver
-from ..engine.instrument import ReadyStages, count_compiles
+from ..engine.instrument import ReadyStages, count_compiles, count_gc, trace_loop
 from ..engine.kv import BatchedKV, KVOp
 from ..porcupine.kv import OP_GET
 from .engine_durability import (
@@ -506,6 +506,10 @@ def serve_engine_kv(
     # (engine.compiles / engine.compile_s): one inside a serving window
     # is a stall someone has to explain.
     count_compiles(metrics)
+    # The collector's pauses (gc.pause_s, loop.gc_s) and the loop's turns
+    # on a profiler's line (mrt.loop.*).
+    count_gc(metrics, sched._thread)
+    trace_loop(sched)
     # Time to ``ready`` by stage, as gauges ``ready.<stage>_s`` set once
     # (0.0: the stage did not run).  The first use of a program pays its
     # compile or cache load where it falls: the 5-tick program in

@@ -22,7 +22,7 @@ from typing import Optional, Sequence
 from ..engine.core import EngineConfig
 from ..engine.firehose import MAX_FIREHOSE_ROWS
 from ..engine.host import EngineDriver
-from ..engine.instrument import ReadyStages, count_compiles
+from ..engine.instrument import ReadyStages, count_compiles, count_gc, trace_loop
 from ..services.shardctrler import ShardSpace
 from ..sim.scheduler import TIMEOUT
 from .engine_durability import (
@@ -1069,6 +1069,8 @@ def serve_engine_shardkv(
     sched = node.sched
     metrics = node.obs.metrics
     count_compiles(metrics)  # engine.compiles / engine.compile_s
+    count_gc(metrics, sched._thread)  # gc.pause_s, loop.gc_s
+    trace_loop(sched)  # mrt.loop.* on the loop's line
     # Time to ``ready`` by stage, as serve_engine_kv's gauges; ``join``
     # is the bootstrap join from its proposal to every group at rest.
     ready = ReadyStages(

@@ -159,9 +159,32 @@ def _rss_mb() -> Optional[float]:
 # ``ckpt.save_s``) tile a durable pump cycle, and ``pump.readback_bytes``
 # counts what each fetch copied; ``loop.timer_s`` / ``loop.io_s`` /
 # ``loop.idle_s`` / ``loop.polls`` are the loop thread's own cumulative
-# account, set at scrape time in ``Obs.snapshot``.  The engine modules
-# observe the phases; nothing here imports them, so a pure client node
-# still pulls in no jax.
+# account, with ``loop.timer_s`` split by owner (``loop.pump_s``,
+# ``loop.handlers_s``, ``loop.watch_s``, ``loop.other_s``) and by callee
+# (``loop.cb.<qualname>_s``) and the turns over 0.1 s
+# (``loop.long_turns`` / ``loop.long_turn_s``), all set at scrape time
+# in ``Obs.snapshot``; so are the kernel's run-queue seconds of the
+# loop and pump threads (``loop.oncpu_s``, ``loop.runq_s``,
+# ``pump.runq_s``: ``/proc/self/task/<tid>/schedstat``, left out where
+# it is missing).  The engine modules observe the phases; nothing here
+# imports them, so a pure client node still pulls in no jax.
+#
+# Per-thread scheduler statistics: nanoseconds on a CPU, nanoseconds
+# runnable on a run queue, timeslices.
+_SCHEDSTAT = "/proc/self/task/{}/schedstat"
+
+
+def _schedstat(tid: Optional[int]) -> Optional[Dict[str, float]]:
+    """``{"oncpu": s, "runq": s}`` of thread ``tid`` since it started,
+    or None where the kernel keeps no such file (or no thread)."""
+    if tid is None:
+        return None
+    try:
+        with open(_SCHEDSTAT.format(tid)) as f:
+            oncpu, runq = f.read().split()[:2]
+        return {"oncpu": int(oncpu) * 1e-9, "runq": int(runq) * 1e-9}
+    except (OSError, ValueError):
+        return None
 
 STAGES = ("wire", "dispatch", "handler", "engine", "ack", "flush", "total")
 
@@ -291,14 +314,26 @@ class ObsControl:
     def snapshot(self, args: Any = None) -> Dict[str, Any]:
         obs = self._node.obs
         sched = getattr(self._node, "sched", None)
-        if hasattr(sched, "idle_s"):
+        if hasattr(sched, "loop_account"):
             # The loop's own account (IoScheduler): cumulative, set at
             # scrape time, so two scrapes difference into the window's
-            # seconds in timers, in socket work and blocked in the poll.
-            obs.metrics.set("loop.timer_s", sched.timer_s)
-            obs.metrics.set("loop.io_s", sched.io_s)
-            obs.metrics.set("loop.idle_s", sched.idle_s)
-            obs.metrics.set("loop.polls", float(sched.polls))
+            # seconds in timers (by owner and by callee), in socket work
+            # and blocked in the poll.
+            for name, value in sched.loop_account().items():
+                obs.metrics.set(name, value)
+        # What the kernel says of the serving threads: seconds on a CPU
+        # and runnable but waiting for one (another tenant, or more
+        # runnable threads than cores).
+        pipe = getattr(getattr(self._node, "engine_service", None), "cycle", None)
+        pipe = getattr(pipe, "pipe", None)
+        for prefix, thread, keys in (
+            ("loop", getattr(sched, "_thread", None), ("oncpu", "runq")),
+            ("pump", getattr(pipe, "_thread", None), ("runq",)),
+        ):
+            times = _schedstat(getattr(thread, "native_id", None))
+            if times is not None:
+                for key in keys:
+                    obs.metrics.set(f"{prefix}.{key}_s", times[key])
         out: Dict[str, Any] = {
             "name": obs.name,
             "pid": os.getpid(),

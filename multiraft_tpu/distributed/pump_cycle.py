@@ -76,6 +76,9 @@ class PumpCycle:
         # No-op off the IoScheduler: sim tests drive handlers with the
         # virtual-time Scheduler.
         self._flush_io = getattr(sched, "flush_io", None)
+        # The IoScheduler's loop account charges the wake at a cycle's
+        # end to the handlers it resumes (``loop.cb.PumpCycle.wake_s``).
+        self._run_as = getattr(sched, "run_as", None)
         # Black box: tick boundaries + consensus frontier transitions
         # land in the crash-surviving ring (flightrec.py).  The
         # frontier triple is only recorded when it CHANGES — a quiet
@@ -302,4 +305,7 @@ class PumpCycle:
         # inside ``pump.gap_s``.  The fresh future goes in first: a
         # handler that parks again waits for the NEXT cycle's end.
         ended, self._ended = self._ended, Future()
-        ended.resolve()
+        if self._run_as is not None:
+            self._run_as("PumpCycle.wake", ended.resolve)
+        else:
+            ended.resolve()

@@ -35,7 +35,23 @@ __all__ = [
     "PumpCadence",
     "Backoff",
     "service_busy",
+    "LOOP_OWNERS",
 ]
+
+# Who a timer callback's turn of the loop is charged to, by the class its
+# callee belongs to (the first part of its ``__qualname__``): the pump
+# cycle, the watches, and ``Future.resolve`` (a sleep or a wait's timeout:
+# it resumes a parked coroutine inline).  A coroutine step is
+# ``handlers`` whatever its generator; everything else is ``other``.
+LOOP_OWNERS = ("pump", "handlers", "watch", "other")
+_OWNER_OF_CLASS = {
+    "PumpCycle": "pump",
+    "WedgeWatch": "watch",
+    "OverloadWatch": "watch",
+    "Future": "handlers",
+}
+# A turn of the loop longer than this counts in ``loop.long_turns``.
+LONG_TURN_S = 0.1
 
 
 class Backoff:
@@ -100,7 +116,6 @@ class RealtimeScheduler:
         self._lock = threading.Lock()
         self._wakeup = threading.Condition(self._lock)
         self._stopped = False
-        self.fired_events = 0
         # Runtime sanitizer (MRT_SANITIZE=1): every callback the loop
         # runs goes through its duration-budget shim.  None = off =
         # one `is None` check per dispatch.
@@ -279,7 +294,6 @@ class RealtimeScheduler:
                 timer._fn, timer._args = None, ()
             if fn is None:  # cancelled between pop and dispatch
                 continue
-            self.fired_events += 1
             try:
                 if self._san is not None:
                     self._san.run_callback(fn, *args)
@@ -289,6 +303,15 @@ class RealtimeScheduler:
                 import traceback
 
                 traceback.print_exc()
+
+
+# The closure ``spawn`` schedules for each step of a coroutine: the loop
+# charges such a turn to the generator it steps.
+_STEP_CODE = next(
+    c for c in RealtimeScheduler.spawn.__code__.co_consts
+    if isinstance(c, types.CodeType) and c.co_name == "step"
+)
+_STEP_GEN = _STEP_CODE.co_freevars.index("gen")
 
 
 class IoScheduler(RealtimeScheduler):
@@ -334,6 +357,24 @@ class IoScheduler(RealtimeScheduler):
     ``loop.polls``, so two scrapes say where the loop's time went:
     busy in timers (the pump's phases, coroutine steps), busy with
     sockets, or waiting for either.
+
+    A timer turn is also charged to its callee's account (one dict
+    lookup and one float add a turn; the account is resolved once per
+    code object, or per generator name for a coroutine step), and each
+    account to one of :data:`LOOP_OWNERS`: the accounts tile
+    ``timer_s`` exactly, and so do the owners, published as
+    ``loop.<owner>_s`` beside ``loop.cb.<qualname>_s``
+    (:meth:`loop_account`).  :meth:`run_as` moves a block inside a
+    callback to another account: the pump cycle's inline wake of the
+    handlers parked on it is theirs.  A turn of any kind over
+    :data:`LONG_TURN_S` counts in ``long_turns`` / ``long_turn_s``.
+
+    With a span factory installed (:meth:`trace_with`; the engine
+    servers install jax's ``TraceAnnotation``), a profiler session holds
+    every timer turn as ``mrt.loop.<owner>`` and a poll's socket work as
+    ``mrt.loop.io`` on the loop thread's line, so the line's only holes
+    are the blocking ``io_poll``.  With no session a turn pays one flag
+    test.
     """
 
     def __init__(
@@ -352,7 +393,87 @@ class IoScheduler(RealtimeScheduler):
         self._idle_max = idle_max
         self.timer_s = self.io_s = self.idle_s = 0.0
         self.polls = 0
+        self.long_turns = 0
+        self.long_turn_s = 0.0
+        # Account cells ``[seconds, owner, name]``, by code object or
+        # generator name (``_accounts``) and by name (``_by_name``: two
+        # keys of one name share a cell); ``_cur`` is the running timer
+        # callback's, which :meth:`run_as` lends from.
+        self._accounts: dict = {}
+        self._by_name: dict = {}
+        self._cur: Optional[list] = None
+        self._span: Optional[Callable[[str], Any]] = None
+        self._span_on: Callable[[], bool] = bool
         super().__init__(name=name)
+
+    def trace_with(
+        self, span: Callable[[str], Any], enabled: Callable[[], bool]
+    ) -> None:
+        """Hold the loop's turns on a profiler's line: ``span(name)`` is
+        a context manager (jax's ``TraceAnnotation``), made only while
+        ``enabled()``.  Any thread, before or after the loop starts."""
+        self._span_on = enabled
+        self._span = span
+
+    def _cell(self, key: Any, name: str, owner: str) -> list:
+        cell = self._by_name.get(name)
+        if cell is None:
+            cell = self._by_name[name] = [0.0, owner, name]
+        self._accounts[key] = cell
+        return cell
+
+    def _account_of(self, fn: Callable) -> list:
+        """The account of a timer callback off the memo (the slow path:
+        a callee seen for the first time, a ``functools.partial``, a
+        callable without code)."""
+        inner = getattr(fn, "func", fn)  # functools.partial
+        code = getattr(inner, "__code__", None)
+        cell = self._accounts.get(code) if code is not None else None
+        if cell is not None:
+            return cell
+        name = getattr(inner, "__qualname__", None) or type(inner).__qualname__
+        owner = _OWNER_OF_CLASS.get(name.split(".", 1)[0], "other")
+        return self._cell(code if code is not None else name, name, owner)
+
+    def run_as(self, name: str, fn: Callable, *args: Any) -> Any:
+        """Run ``fn(*args)`` inside the timer callback running now and
+        charge its time to the account ``name``, owner ``handlers``, not
+        to the callback's: coroutines ``fn`` resumes inline (a pump
+        cycle's wake) are the handlers' time.  Loop thread only."""
+        cell = self._accounts.get(name) or self._cell(name, name, "handlers")
+        tm = self._span("mrt.loop.handlers") if (
+            self._span is not None and self._span_on()) else None
+        if tm is not None:
+            tm.__enter__()
+        t0 = time.perf_counter()
+        try:
+            return fn(*args)
+        finally:
+            dt = time.perf_counter() - t0
+            if tm is not None:
+                tm.__exit__(None, None, None)
+            lender = self._cur
+            if lender is not None:
+                lender[0] -= dt
+                cell[0] += dt
+
+    def loop_account(self) -> dict:
+        """The loop's cumulative account under the names a scrape
+        publishes.  Loop thread (``Obs.snapshot`` runs there)."""
+        out = {
+            "loop.timer_s": self.timer_s,
+            "loop.io_s": self.io_s,
+            "loop.idle_s": self.idle_s,
+            "loop.polls": float(self.polls),
+            "loop.long_turns": float(self.long_turns),
+            "loop.long_turn_s": self.long_turn_s,
+        }
+        for owner in LOOP_OWNERS:
+            out[f"loop.{owner}_s"] = 0.0
+        for secs, owner, name in self._by_name.values():
+            out[f"loop.{owner}_s"] += secs
+            out[f"loop.cb.{name}_s"] = secs
+        return out
 
     def flush_io(self) -> None:
         """Run the io_flush hook forced, from the loop thread.  The
@@ -405,9 +526,22 @@ class IoScheduler(RealtimeScheduler):
                     else:
                         delay = min(d, self._idle_max)
                     break
+            span = self._span
+            if span is not None and not self._span_on():
+                span = None
             if popped:
                 if fn is not None:  # else cancelled between push and pop
-                    self.fired_events += 1
+                    code = getattr(fn, "__code__", None)
+                    if code is _STEP_CODE:
+                        key = fn.__closure__[_STEP_GEN].cell_contents.__qualname__
+                        cell = self._accounts.get(key) or self._cell(
+                            key, key, "handlers")
+                    else:
+                        cell = self._accounts.get(code) or self._account_of(fn)
+                    self._cur = cell
+                    tm = span("mrt.loop." + cell[1]) if span else None
+                    if tm is not None:
+                        tm.__enter__()
                     try:
                         if self._san is not None:
                             self._san.run_callback(fn, *args)
@@ -428,24 +562,39 @@ class IoScheduler(RealtimeScheduler):
                             import traceback
 
                             traceback.print_exc()
+                    if tm is not None:
+                        tm.__exit__(None, None, None)
+                    self._cur = None
                     now = time.perf_counter()
-                    self.timer_s += now - t_last
+                    dt = now - t_last
+                    self.timer_s += dt
+                    cell[0] += dt
+                    if dt > LONG_TURN_S:
+                        self.long_turns += 1
+                        self.long_turn_s += dt
                     t_last = now
                 continue
             if self._io_flush is not None:
+                tm = span("mrt.loop.io") if span else None
+                if tm is not None:
+                    tm.__enter__()
                 try:
                     self._io_flush(True)
                 except Exception:  # pragma: no cover - keep the loop alive
                     import traceback
 
                     traceback.print_exc()
+                if tm is not None:
+                    tm.__exit__(None, None, None)
             t1 = time.perf_counter()
             ev = self._io_poll(delay)
             t2 = time.perf_counter()
             self.polls += 1
             self.idle_s += t2 - t1
             if ev is not None:
-                self.fired_events += 1
+                tm = span("mrt.loop.io") if span else None
+                if tm is not None:
+                    tm.__enter__()
                 try:
                     if self._san is not None:
                         self._san.run_callback(self._io_handle, ev)
@@ -455,8 +604,14 @@ class IoScheduler(RealtimeScheduler):
                     import traceback
 
                     traceback.print_exc()
+                if tm is not None:
+                    tm.__exit__(None, None, None)
             now = time.perf_counter()
-            self.io_s += (now - t_last) - (t2 - t1)
+            dt = (now - t_last) - (t2 - t1)
+            self.io_s += dt
+            if dt > LONG_TURN_S:
+                self.long_turns += 1
+                self.long_turn_s += dt
             t_last = now
 
 

@@ -743,7 +743,11 @@ class RpcNode:
             # Guard the coroutine body too: a handler that raises mid-wait
             # must still produce a reply (None = "RPC failed"), or the
             # caller retries the same failing request forever.
-            reply_fut = self.sched.spawn(_guarded(result))
+            guarded = _guarded(result)
+            # The loop charges a coroutine's turns by its name: the
+            # handler's, not the wrapper's.
+            guarded.__qualname__ = result.__qualname__
+            reply_fut = self.sched.spawn(guarded)
             reply_fut.add_done_callback(
                 lambda f: _done(conn, req_id, f.value)
             )

@@ -903,9 +903,6 @@ class EngineDriver:
             self.last_metrics = metrics
             if self.tracer:
                 commits = int(metrics["commits"])  # forces the sync
-                self.metrics.observe(
-                    "tick_wall_s", time.perf_counter() - t_wall
-                )
                 now_us = time.perf_counter() * 1e6
                 self.tracer.span(
                     "tick",
@@ -999,6 +996,8 @@ class EngineDriver:
         m = self.metrics
         m.observe("pump.handoff_s", pending.t_fetch - pending.t_dispatched)
         m.observe("pump.fetch_s", pending.t_fetched - pending.t_fetch)
+        m.observe("pump.wait_s", pending.t_ready - pending.t_fetch)
+        m.observe("pump.copy_s", pending.t_fetched - pending.t_ready)
         m.observe("pump.post_s", time.perf_counter() - pending.t_fetched)
         m.inc("pump.readback_bytes", pending.nbytes)
         m.inc("pump.readback_copies", pending.ncopies)
@@ -1040,7 +1039,6 @@ class EngineDriver:
         t = pending.t_dispatch
         commits_total = int(rec["commits"].sum())
         for i in range(n):
-            self.metrics.observe("tick_wall_s", per)
             self.tracer.span(
                 "tick",
                 t * 1e6,

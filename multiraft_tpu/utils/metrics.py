@@ -10,8 +10,8 @@ gauges, and histogram-ish timers.  Live consumers:
   registry (``get_total_count``/``get_total_bytes`` read through it);
 * ``harness.raft_harness.RaftHarness`` — shares the network's registry
   and records ``one()`` agreement counts + virtual-time latency;
-* ``engine.host.EngineDriver`` — tick counter, plus wall-clock per-tick
-  latency samples under the tracer;
+* ``engine.host.EngineDriver`` — tick counter and the pump cycle's
+  phase clocks (engine/instrument.py);
 * ``bench.py`` — percentile computation over run samples.
 
 ``trace`` is the DPrintf equivalent (reference: raft/utility.go:55-72),

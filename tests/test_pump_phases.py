@@ -11,6 +11,7 @@ tile hides time, and the next optimisation is chosen from these numbers.
 
 from __future__ import annotations
 
+import gc
 import glob
 import os
 import subprocess
@@ -30,6 +31,8 @@ from multiraft_tpu.distributed.engine_server import (  # noqa: E402
     serve_engine_kv,
     serve_engine_shardkv,
 )
+from multiraft_tpu.distributed.observe import ObsControl  # noqa: E402
+from multiraft_tpu.distributed.realtime import LOOP_OWNERS, IoScheduler  # noqa: E402
 from multiraft_tpu.distributed.tcp import RpcNode  # noqa: E402
 from multiraft_tpu.engine.core import (  # noqa: E402
     METRIC_KEYS,
@@ -148,11 +151,34 @@ def test_phases_tile_the_durable_pump_cycle(served):
     shards = driver.mesh.devices.size if driver.mesh is not None else 1
     assert svc.cycle.depth == 1 and svc.cycle.pipe is not None
     assert driver.fused_eligible()  # a mesh server pipelines like any other
+    # The fetch splits at the device's completion: wait, then copy, in
+    # every pump, and the two histograms sum to the fetch's.
+    out_of_order = []
+    complete = driver.complete_ticks
+
+    def checked(pending, rec):
+        if not pending.t_fetch <= pending.t_ready <= pending.t_fetched:
+            out_of_order.append(pending.pump)
+        return complete(pending, rec)
+
+    driver.complete_ticks = checked
+    split = ("pump.fetch_s", "pump.wait_s", "pump.copy_s")
     writer = threading.Thread(target=_put_some, args=(node, client, 25))
     writer.start()
-    a, b = _cycle_snapshots(svc, pumps=90)
+    try:
+        before = node.sched.run_call(_hist_state, svc.m, split)
+        a, b = _cycle_snapshots(svc, pumps=90)
+        after = node.sched.run_call(_hist_state, svc.m, split)
+    finally:
+        driver.complete_ticks = complete
     writer.join(60.0)
     assert not writer.is_alive()
+    assert not out_of_order, out_of_order
+    (fn, fetch), (wn, wait), (cn, copy) = (
+        (after[k][0] - before[k][0], after[k][1] - before[k][1]) for k in split
+    )
+    assert fn == wn == cn >= 90
+    assert wait + copy == pytest.approx(fetch, rel=1e-6)
     pumps = b["pumps"] - a["pumps"]
     assert pumps >= 90
     wall = b["t"] - a["t"]
@@ -208,6 +234,23 @@ def test_loop_account_tiles_the_loop_threads_wall(served):
     m = snap["metrics"]
     assert m["loop.timer_s"] >= timer1 and m["loop.idle_s"] >= idle1
     assert m["loop.io_s"] >= io1 and m["loop.polls"] >= polls1
+    # The owners, and the callees under them, tile loop.timer_s.
+    owners = [m[f"loop.{o}_s"] for o in LOOP_OWNERS]
+    assert sum(owners) == pytest.approx(m["loop.timer_s"], rel=1e-9)
+    callees = sum(v for k, v in m.items() if k.startswith("loop.cb."))
+    assert callees == pytest.approx(m["loop.timer_s"], rel=1e-9)
+    assert all(o > 0.0 for o in owners), owners  # the watches run every 0.25 s
+    assert m["loop.cb.PumpCycle._pump_done_s"] > 0.0
+    assert m["loop.cb.PumpCycle.wake_s"] > 0.0  # the parked updates' wake
+    # The kernel's account of the two serving threads, and the
+    # collector's, only grow.
+    again = client.sched.wait(end.call("Obs.snapshot", None), 30.0)["metrics"]
+    grows = ["gc.collections", "gc.gen2", "loop.gc_s", "loop.long_turns"]
+    if os.path.exists(f"/proc/self/task/{threading.get_native_id()}/schedstat"):
+        grows += ["loop.oncpu_s", "loop.runq_s", "pump.runq_s"]
+    for k in grows:
+        assert again[k] >= m[k], k
+    assert again["loop.oncpu_s"] > m["loop.oncpu_s"] or "loop.oncpu_s" not in grows
     if hasattr(node.engine_service, "skv"):
         return  # serve_engine_kv alone publishes what follows
     # the compile counter and time to ready ride the same scrape
@@ -251,14 +294,109 @@ def test_phases_are_on_the_profilers_clock(served, tmp_path):
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
         _cycle_snapshots(svc, pumps=8)
+        node.sched.run_call(gc.collect)  # a collection on the loop's line
     finally:
         jax.profiler.stop_trace()
-    found = _host_lines_with(trace_dir, "mrt.pump.")
-    for phase in ("dispatch", "fetch", "complete", "apply", "sync"):
+    found = _host_lines_with(trace_dir, "mrt.")
+    for phase in ("dispatch", "fetch", "wait", "copy", "complete", "apply", "sync"):
         assert f"mrt.pump.{phase}" in found, sorted(found)
     loop_lines = found["mrt.pump.dispatch"]
     assert found["mrt.pump.apply"] == loop_lines
     assert found["mrt.pump.fetch"].isdisjoint(loop_lines), found
+    # The device wait and the copy sit inside the fetch, on its line.
+    assert found["mrt.pump.wait"] == found["mrt.pump.copy"] == found["mrt.pump.fetch"]
+    # The loop's line: every turn under a span of its own.
+    for span in ("mrt.loop.io", "mrt.loop.pump", "mrt.gc"):
+        assert loop_lines <= found[span], (span, found)
+
+
+def _bare_loop():
+    """An IoScheduler whose poll only sleeps: nothing but the timers a
+    test schedules runs on it."""
+    return IoScheduler(
+        lambda delay: time.sleep(min(delay, 0.001)), lambda ev: None,
+        lambda: None, name="multiraft-loop/bare",
+    )
+
+
+def test_loop_charges_each_turn_to_its_owner_and_counts_long_ones():
+    sched = _bare_loop()
+
+    def parked():
+        yield 0.01  # the step after it is a timer turn of its own
+        return 1
+
+    def cycle_end():
+        # What PumpCycle._end_cycle does with the parked handlers' wake.
+        sched.run_as("test.wake", time.sleep, 0.03)
+
+    try:
+        t0 = sched.run_call(sched.loop_account)
+        assert sched.wait(sched.spawn(parked()), 5.0) == 1
+        sched.run_call(time.sleep, 0.15)  # a long turn, charged to other
+        sched.run_call(cycle_end)
+        t1 = sched.run_call(sched.loop_account)
+    finally:
+        sched.stop()
+    for acct in (t0, t1):
+        owners = [acct[f"loop.{o}_s"] for o in LOOP_OWNERS]
+        assert sum(owners) == pytest.approx(acct["loop.timer_s"], rel=1e-9)
+    assert t1["loop.long_turns"] - t0["loop.long_turns"] >= 1
+    assert t1["loop.long_turn_s"] - t0["loop.long_turn_s"] >= 0.15
+    assert t1["loop.other_s"] - t0["loop.other_s"] >= 0.15
+    name = parked.__qualname__
+    assert t1[f"loop.cb.{name}_s"] > 0.0  # a coroutine, by its generator
+    # The lent block is the handlers', not its callback's.
+    assert t1["loop.cb.test.wake_s"] >= 0.03
+    assert t1["loop.handlers_s"] - t0["loop.handlers_s"] >= 0.03
+    invoke = "RealtimeScheduler.run_call.<locals>._invoke"
+    assert t1[f"loop.cb.{invoke}_s"] - t0[f"loop.cb.{invoke}_s"] < 0.15 + 0.03
+
+
+def test_gc_clock_charges_the_loop_only_its_own_collections():
+    from multiraft_tpu.engine.instrument import count_gc
+    from multiraft_tpu.utils.metrics import Metrics
+
+    m = Metrics()
+    sched = _bare_loop()
+    try:
+        count_gc(m, sched._thread)
+        keys = (set(m.counters), set(m.hists))
+        gen2, pauses = m.counters["gc.gen2"], m.hists["gc.pause_s"].count
+        sched.run_call(gc.collect, 2)
+        assert m.counters["gc.gen2"] > gen2
+        assert m.hists["gc.pause_s"].count > pauses
+        on_loop = m.counters["loop.gc_s"]
+        assert on_loop > 0.0
+        gen2 = m.counters["gc.gen2"]
+        worker = threading.Thread(target=gc.collect, args=(2,))
+        worker.start()
+        worker.join()
+        assert m.counters["gc.gen2"] > gen2
+        assert m.counters["loop.gc_s"] == on_loop
+        assert (set(m.counters), set(m.hists)) == keys
+    finally:
+        sched.stop()
+
+
+def test_run_queue_seconds_are_left_out_without_schedstat(monkeypatch):
+    node = RpcNode(listen=True)
+    try:
+        ctl = ObsControl(node)
+        with monkeypatch.context() as patch:
+            patch.setattr(
+                "multiraft_tpu.distributed.observe._SCHEDSTAT", "/nonexistent/{}"
+            )
+            m = node.sched.run_call(ctl.snapshot)["metrics"]
+        assert "loop.timer_s" in m
+        assert not {"loop.oncpu_s", "loop.runq_s", "pump.runq_s"} & set(m)
+        tid = node.sched._thread.native_id
+        if os.path.exists(f"/proc/self/task/{tid}/schedstat"):
+            m = node.sched.run_call(ctl.snapshot)["metrics"]
+            assert m["loop.runq_s"] >= 0.0 and m["loop.oncpu_s"] > 0.0
+            assert "pump.runq_s" not in m  # no engine, no pump thread
+    finally:
+        node.close()
 
 
 def test_sync_pump_gets_apply_and_sync_and_nothing_else(tmp_path, monkeypatch):
