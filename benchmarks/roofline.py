@@ -6,7 +6,8 @@ Two parts (BENCHMARKS.md "Roofline" section reports both):
 * ARITHMETIC: bytes touched per tick from the tensor shapes.  The
   dominant arrays at the bench shape (G=10k, P=3) are the log ring
   ``log_term [G,P,L] i32`` and the append-channel mailbox
-  ``ar_terms [G,P,P,E] i32`` (+ ~20 [G,P,P] lane fields).  The tick
+  ``ar_terms [G,P,P,E] i32`` (+ 15 [G,P,P] lane fields per edge and
+  11 [G,P] sender lanes, ``core.SENDER_LANES``).  The tick
   reads state+inbox and writes state+outbox; ring reads appear in
   several phases, so a fusion-count multiplier is reported as a range.
 
@@ -44,7 +45,8 @@ def bytes_per_tick(G: int, P: int, L: int, E: int, passes_log: float = 2.0):
     i32 = 4
     log = G * P * L * i32
     ar_terms = G * P * P * E * i32
-    lanes = 20 * G * P * P * i32  # vr/vp/ar/ap scalar lane fields
+    # vr/vp/ar/ap scalar lane fields: per edge, and per sender
+    lanes = 15 * G * P * P * i32 + 11 * G * P * i32
     gp = 14 * G * P * i32        # term/vote/role/commit/... columns
     gpp = 3 * G * P * P * i32    # next/match/votes
     state = log + gp + gpp

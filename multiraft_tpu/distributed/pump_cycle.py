@@ -219,7 +219,11 @@ class PumpCycle:
             self.pipe.submit(
                 pending.fetch, functools.partial(self._pump_done, pending)
             )
-        self._arm_pump()
+        if len(d._inflight) < self.depth:
+            self._arm_pump()
+        # else the pipeline is full: the completion re-arms
+        # (_pump_done), and a timer that only found it full again every
+        # hot interval would take loop turns from the sockets.
 
     def _pump_sync(self) -> None:
         """Synchronous pump (MRT_ENGINE_PIPELINE=0, reorder chaos in
@@ -246,7 +250,11 @@ class PumpCycle:
             raise rec  # device failure: surface on the owning loop
         d = self.engine.driver
         if pending not in d._inflight:
-            return  # already drained (final_checkpoint) or torn down
+            # Already drained (final_checkpoint, or a synchronous step
+            # that completed it) or torn down.
+            if not self._stopped:
+                self._arm_pump()
+            return
         cp0 = time.thread_time()
         d.complete_ticks(pending, rec)
         self._after_step(pending.n)

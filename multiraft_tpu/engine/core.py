@@ -53,12 +53,13 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils.knobs import knob_bool
 
 __all__ = [
-    "EngineConfig", "EngineState", "Mailbox", "init_state",
-    "empty_mailbox", "tick", "METRIC_KEYS", "SCALAR_METRIC_KEYS",
+    "EngineConfig", "EngineState", "Mailbox", "SENDER_LANES", "init_state",
+    "empty_mailbox", "per_edge", "tick", "METRIC_KEYS", "SCALAR_METRIC_KEYS",
 ]
 
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
@@ -234,14 +235,25 @@ class EngineState(NamedTuple):
 
 
 class Mailbox(NamedTuple):
-    """Dense per-edge messages, all ``[G, src, dst]`` (+ trailing dims)."""
+    """Dense messages, ``[G, src, dst]`` (+ trailing dims) per edge.
+
+    The :data:`SENDER_LANES` hold a value of the sender's alone (its
+    term, commit, last log position, config view), the same for every
+    destination, so the tick writes them once per sender, ``[G, src]``:
+    a ``[G, P, P]`` copy is a kernel of its own on every tick, since the
+    mailbox is the carry of the fused scan (engine/pipeline.py).  A
+    receiver broadcasts such a lane over destinations inside its own
+    fusion.  A host path that rewrites one edge of a sender lane
+    (reorder redelivery, split staging) first expands it to
+    ``[G, src, dst]``; the tick reads either form, and writes
+    ``[G, src]`` again."""
 
     # RequestVote (reference: raft/raft_rpc.go RequestVote args/reply);
     # the ``pre`` bits mark non-binding PreVote rounds.
     vr_active: jnp.ndarray  # bool[G,P,P]
-    vr_term: jnp.ndarray  # i32[G,P,P]
-    vr_last_idx: jnp.ndarray  # i32[G,P,P]
-    vr_last_term: jnp.ndarray  # i32[G,P,P]
+    vr_term: jnp.ndarray  # i32[G,P] (sender lane)
+    vr_last_idx: jnp.ndarray  # i32[G,P] (sender lane)
+    vr_last_term: jnp.ndarray  # i32[G,P] (sender lane)
     vr_pre: jnp.ndarray  # bool[G,P,P]
     vp_active: jnp.ndarray  # bool[G,P,P]  src=voter, dst=candidate
     vp_term: jnp.ndarray  # i32[G,P,P]
@@ -249,27 +261,49 @@ class Mailbox(NamedTuple):
     vp_pre: jnp.ndarray  # bool[G,P,P]
     # AppendEntries / InstallSnapshot (snap flag)
     ar_active: jnp.ndarray  # bool[G,P,P]
-    ar_term: jnp.ndarray  # i32[G,P,P]
+    ar_term: jnp.ndarray  # i32[G,P] (sender lane)
     ar_prev_idx: jnp.ndarray  # i32[G,P,P]
     ar_prev_term: jnp.ndarray  # i32[G,P,P]
     ar_n: jnp.ndarray  # i32[G,P,P] entries carried (<= E)
     ar_terms: jnp.ndarray  # i32[G,P,P,E]
-    ar_commit: jnp.ndarray  # i32[G,P,P] leader commit
+    ar_commit: jnp.ndarray  # i32[G,P] (sender lane) leader commit
     ar_snap: jnp.ndarray  # bool[G,P,P] InstallSnapshot fast-forward
     ap_active: jnp.ndarray  # bool[G,P,P]  src=follower, dst=leader
-    ap_term: jnp.ndarray  # i32[G,P,P]
+    ap_term: jnp.ndarray  # i32[G,P] (sender lane)
     ap_success: jnp.ndarray  # bool[G,P,P]
     ap_match: jnp.ndarray  # i32[G,P,P]
     ap_conflict: jnp.ndarray  # i32[G,P,P]
-    # Leader config view, broadcast with every append: a follower whose
+    # Leader config view, carried with every append: a follower whose
     # log provably covers ``ar_cfg_idx`` mirrors the leader's view
     # (effect-on-append without per-entry payload plumbing — see the
     # phase-3 adoption note in tick_impl).
-    ar_cfg_epoch: jnp.ndarray  # i32[G,P,P]
-    ar_cfg_idx: jnp.ndarray  # i32[G,P,P]
-    ar_cfg_old: jnp.ndarray  # i32[G,P,P] voter bitmask
-    ar_cfg_new: jnp.ndarray  # i32[G,P,P] voter bitmask
-    ar_cfg_joint: jnp.ndarray  # bool[G,P,P]
+    ar_cfg_epoch: jnp.ndarray  # i32[G,P] (sender lane)
+    ar_cfg_idx: jnp.ndarray  # i32[G,P] (sender lane)
+    ar_cfg_old: jnp.ndarray  # i32[G,P] (sender lane) voter bitmask
+    ar_cfg_new: jnp.ndarray  # i32[G,P] (sender lane) voter bitmask
+    ar_cfg_joint: jnp.ndarray  # bool[G,P] (sender lane)
+
+
+# The Mailbox fields whose value is the sender's alone: stored [G, src]
+# (see the Mailbox docstring).
+SENDER_LANES = (
+    "vr_term", "vr_last_idx", "vr_last_term",
+    "ar_term", "ar_commit", "ap_term",
+    "ar_cfg_epoch", "ar_cfg_idx", "ar_cfg_old", "ar_cfg_new", "ar_cfg_joint",
+)
+
+
+def per_edge(lane):
+    """A mailbox lane in the per-edge form ``[G, src, dst]``: a sender
+    lane ``[G, src]`` broadcast over destinations (a writable copy for
+    a numpy lane), any other lane as it is.  What a path that writes
+    one edge of a sender lane expands it with first."""
+    if lane.ndim != 2:
+        return lane
+    G, P = lane.shape
+    if isinstance(lane, np.ndarray):
+        return np.broadcast_to(lane[:, :, None], (G, P, P)).copy()
+    return jnp.broadcast_to(lane[:, :, None], (G, P, P))
 
 
 def init_state(cfg: EngineConfig, key: jax.Array) -> EngineState:
@@ -311,20 +345,20 @@ def empty_mailbox(cfg: EngineConfig) -> Mailbox:
     b = lambda *s: jnp.zeros(s, bool)
     z = lambda *s: jnp.zeros(s, jnp.int32)
     return Mailbox(
-        vr_active=b(G, P, P), vr_term=z(G, P, P),
-        vr_last_idx=z(G, P, P), vr_last_term=z(G, P, P),
+        vr_active=b(G, P, P), vr_term=z(G, P),
+        vr_last_idx=z(G, P), vr_last_term=z(G, P),
         vr_pre=b(G, P, P),
         vp_active=b(G, P, P), vp_term=z(G, P, P), vp_granted=b(G, P, P),
         vp_pre=b(G, P, P),
-        ar_active=b(G, P, P), ar_term=z(G, P, P),
+        ar_active=b(G, P, P), ar_term=z(G, P),
         ar_prev_idx=z(G, P, P), ar_prev_term=z(G, P, P),
-        ar_n=z(G, P, P), ar_terms=z(G, P, P, E), ar_commit=z(G, P, P),
+        ar_n=z(G, P, P), ar_terms=z(G, P, P, E), ar_commit=z(G, P),
         ar_snap=b(G, P, P),
-        ap_active=b(G, P, P), ap_term=z(G, P, P), ap_success=b(G, P, P),
+        ap_active=b(G, P, P), ap_term=z(G, P), ap_success=b(G, P, P),
         ap_match=z(G, P, P), ap_conflict=z(G, P, P),
-        ar_cfg_epoch=z(G, P, P), ar_cfg_idx=z(G, P, P),
-        ar_cfg_old=z(G, P, P), ar_cfg_new=z(G, P, P),
-        ar_cfg_joint=b(G, P, P),
+        ar_cfg_epoch=z(G, P), ar_cfg_idx=z(G, P),
+        ar_cfg_old=z(G, P), ar_cfg_new=z(G, P),
+        ar_cfg_joint=b(G, P),
     )
 
 
@@ -580,18 +614,22 @@ def _tick_phases(
     # voted_for, no timer reset.
     # View [G, voter(dst), cand(src)] — matches out.vp's [G,src,dst].
     vT = lambda x: jnp.swapaxes(x, 1, 2)
+    # A sender lane's view (Mailbox, SENDER_LANES): stored [G, src], it
+    # broadcasts over dst here, inside the reading fusion; expanded to
+    # [G, src, dst] by a host path, it reads as any lane does.
+    vS = lambda x: x[:, None, :] if x.ndim == 2 else vT(x)
     arrived = vT(inbox.vr_active) & state.alive[:, :, None]
     is_pre = vT(inbox.vr_pre)
     active = arrived & ~is_pre
-    m_term = vT(inbox.vr_term)
+    m_term = vS(inbox.vr_term)
     higher_lane = active & (m_term > state.term[..., None])
     adopt = jnp.max(jnp.where(higher_lane, m_term, -1), axis=2)
     state = _step_down(cfg, state, jnp.any(higher_lane, axis=2), adopt)
     last_idx = _last_index(state)
     last_term = _term_at(cfg, state, last_idx)
-    up_to_date = (vT(inbox.vr_last_term) > last_term[..., None]) | (
-        (vT(inbox.vr_last_term) == last_term[..., None])
-        & (vT(inbox.vr_last_idx) >= last_idx[..., None])
+    up_to_date = (vS(inbox.vr_last_term) > last_term[..., None]) | (
+        (vS(inbox.vr_last_term) == last_term[..., None])
+        & (vS(inbox.vr_last_idx) >= last_idx[..., None])
     )
     eligible = active & (m_term == state.term[..., None]) & up_to_date
     cand_ids = jnp.arange(P, dtype=jnp.int32)
@@ -797,14 +835,14 @@ def _tick_phases(
     # messages and is equivalent to an at-most-once drop for the rare
     # lower-term-processed-first interleaving.
     act_in = vT(inbox.ar_active) & state.alive[:, :, None]  # [G,dst,src]
-    m_term_all = vT(inbox.ar_term)
+    m_term_all = vS(inbox.ar_term)
     term_key = jnp.where(act_in, m_term_all, -1)
     max_term_in = jnp.max(term_key, axis=2)  # [G,dst]
     is_max = act_in & (term_key == max_term_in[..., None])
     src_ids = jnp.arange(P, dtype=jnp.int32)
     win_src = jnp.min(jnp.where(is_max, src_ids, P), axis=2)  # [G,dst]
     sel = src_ids == win_src[..., None]  # [G,dst,src] one-hot (or none)
-    pick = lambda x: jnp.sum(jnp.where(sel, vT(x), 0), axis=2)
+    pick = lambda x: jnp.sum(jnp.where(sel, vS(x), 0), axis=2)
     active = win_src < P  # [G,P] a message arrived at dst
     m_term = pick(inbox.ar_term)
     stale = active & (m_term < state.term)
@@ -919,7 +957,7 @@ def _tick_phases(
         m_cfg_idx = pick(inbox.ar_cfg_idx)
         covered = m_cfg_idx <= (prev + n_ent)
         adopt_cfg = (match & covered) | do_snap
-        m_joint = jnp.any(sel & vT(inbox.ar_cfg_joint), axis=2)
+        m_joint = jnp.any(sel & vS(inbox.ar_cfg_joint), axis=2)
         state = state._replace(
             voters_old=jnp.where(
                 adopt_cfg, pick(inbox.ar_cfg_old), state.voters_old
@@ -948,7 +986,7 @@ def _tick_phases(
     reply_match_w = jnp.where(snap_handled, prev, msg_last)
     out = out._replace(
         ap_active=act_in,
-        ap_term=jnp.broadcast_to(state.term[..., None], (G, P, P)),
+        ap_term=state.term,
         ap_success=sel & success[..., None],
         ap_match=jnp.where(sel, reply_match_w[..., None], msg_last_all),
         ap_conflict=conflict_all,
@@ -962,7 +1000,7 @@ def _tick_phases(
     # whole phase is one elementwise pass over the
     # [G, leader(dst), src] view (fused r04).
     active = vT(inbox.ap_active) & state.alive[:, :, None]
-    m_term = vT(inbox.ap_term)
+    m_term = vS(inbox.ap_term)
     higher_lane = active & (m_term > state.term[..., None])
     adopt = jnp.max(jnp.where(higher_lane, m_term, -1), axis=2)
     state = _step_down(cfg, state, jnp.any(higher_lane, axis=2), adopt)
@@ -1156,10 +1194,10 @@ def _tick_phases(
     vr_term_per = jnp.where(send_pre, state.term + 1, state.term)
     out = out._replace(
         vr_active=vr_act,
-        vr_term=jnp.broadcast_to(vr_term_per[:, :, None], (G, P, P)),
-        vr_last_idx=jnp.broadcast_to(last_idx[:, :, None], (G, P, P)),
-        vr_last_term=jnp.broadcast_to(last_term[:, :, None], (G, P, P)),
-        vr_pre=jnp.broadcast_to(send_pre[:, :, None], (G, P, P)) & vr_act,
+        vr_term=vr_term_per,
+        vr_last_idx=last_idx,
+        vr_last_term=last_term,
+        vr_pre=send_pre[:, :, None] & vr_act,
     )
 
     scope("tick.5a_membership")
@@ -1273,25 +1311,19 @@ def _tick_phases(
     ar_terms = jnp.where(jnp.arange(E) < n_send[..., None], t, 0)
     out = out._replace(
         ar_active=send,
-        ar_term=jnp.broadcast_to(state.term[:, :, None], (G, P, P)),
+        ar_term=state.term,
         ar_prev_idx=prev,
         ar_prev_term=prev_term,
         ar_n=n_send,
         ar_terms=ar_terms,
-        ar_commit=jnp.broadcast_to(state.commit[:, :, None], (G, P, P)),
+        ar_commit=state.commit,
         ar_snap=need_snap & send,
         # Leader config view rides every append (phase-3 mirroring).
-        ar_cfg_epoch=jnp.broadcast_to(
-            state.cfg_epoch[:, :, None], (G, P, P)
-        ),
-        ar_cfg_idx=jnp.broadcast_to(state.cfg_idx[:, :, None], (G, P, P)),
-        ar_cfg_old=jnp.broadcast_to(
-            state.voters_old[:, :, None], (G, P, P)
-        ),
-        ar_cfg_new=jnp.broadcast_to(
-            state.voters_new[:, :, None], (G, P, P)
-        ),
-        ar_cfg_joint=jnp.broadcast_to(state.joint[:, :, None], (G, P, P)),
+        ar_cfg_epoch=state.cfg_epoch,
+        ar_cfg_idx=state.cfg_idx,
+        ar_cfg_old=state.voters_old,
+        ar_cfg_new=state.voters_new,
+        ar_cfg_joint=state.joint,
     )
     state = state._replace(
         hb_due=jnp.where(hb_fire, now + cfg.HB_TICKS, state.hb_due),

@@ -35,8 +35,10 @@ from .core import (
     LEADER,
     EngineConfig,
     EngineState,
+    SENDER_LANES,
     Mailbox,
     empty_mailbox,
+    per_edge,
     init_state,
     shard_rows,
     tick,
@@ -114,6 +116,18 @@ _CHANNELS = {
     )
     for f in _ACTIVE_FIELDS
 }
+
+
+def _collapse_lanes(lanes: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """Checkpointed mailbox lanes with every sender lane written per
+    edge, ``[G, src, dst]``, and equal across destinations collapsed to
+    the ``[G, src]`` form the tick writes (core.SENDER_LANES)."""
+    out = dict(lanes)
+    for f in SENDER_LANES:
+        v = out[f]
+        if v.ndim == 3 and (v == v[:, :, :1]).all():
+            out[f] = v[:, :, 0]
+    return out
 
 
 def mask_active(mb: Mailbox, fn) -> Mailbox:
@@ -399,7 +413,11 @@ class EngineDriver:
                     release = self.tick + int(
                         rng.integers(self.reorder_min, self.reorder_max + 1)
                     )
-                    payload = {f: host[f][g, s, dst].copy() for f in fields}
+                    payload = {
+                        f: (host[f][g, s] if host[f].ndim == 2  # sender lane
+                            else host[f][g, s, dst]).copy()
+                        for f in fields
+                    }
                     # Chaos reorder buffer: every entry carries a
                     # release tick ≤ tick+reorder_max, so occupancy is
                     # bounded by reorder_max windows of traffic.
@@ -415,6 +433,10 @@ class EngineDriver:
                     continue  # partitioned while in flight: message dies
                 if release <= self.tick and not host[prefix + "active"][g, s, dst]:
                     for f, v in payload.items():
+                        # The held message may carry an older value than
+                        # its sender's lane holds now (an older term):
+                        # the lane goes per edge to take it.
+                        host[f] = per_edge(host[f])
                         host[f][g, s, dst] = v
                 else:
                     held.append(item)
@@ -1019,8 +1041,11 @@ class EngineDriver:
                     int(g), k, int(starts[i, g]),
                     int(terms[i, g]) if terms is not None else None,
                 )
+            # A host int from here on: the serial loop (warm-up,
+            # elections) leaves a device scalar, which a sum would keep
+            # on the device — a program launched on the loop every pump.
             self._commits_dev = (
-                getattr(self, "_commits_dev", 0)
+                int(getattr(self, "_commits_dev", 0))
                 + int(host_rec["commits"].sum())
             )
             self.last_metrics = {k: v[-1] for k, v in host_rec.items()}
@@ -1087,6 +1112,11 @@ class EngineDriver:
     # cfg_idx and Mailbox gained the ar_cfg_* lanes (joint-consensus
     # membership change) — config state rides the generic _asdict()
     # path, so an in-flight reconfig survives checkpoint/restore.
+    # Still v4, same fields: the SENDER_LANES are saved as they are,
+    # [G, src] (per edge only where a host path left them so), and a
+    # bundle written when they were always [G, src, dst] restores by
+    # collapsing each lane equal across destinations (_collapse_lanes);
+    # a lane that is not stays per edge, which the tick reads too.
     CKPT_VERSION = 4
 
     def save(self, path: str, extra: Optional[Dict[str, Any]] = None) -> str:
@@ -1212,7 +1242,10 @@ class EngineDriver:
             **{k: jnp.array(v, copy=True) for k, v in blob["state"].items()}
         )
         d.inbox = Mailbox(
-            **{k: jnp.array(v, copy=True) for k, v in blob["inbox"].items()}
+            **{
+                k: jnp.array(v, copy=True)
+                for k, v in _collapse_lanes(blob["inbox"]).items()
+            }
         )
         d.tick = blob["tick"]
         d.key = jnp.array(blob["key"], copy=True)

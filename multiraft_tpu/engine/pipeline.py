@@ -66,6 +66,7 @@ from jax.sharding import PartitionSpec as P
 from .core import (
     METRIC_KEYS,
     SCALAR_METRIC_KEYS,
+    SENDER_LANES,
     EngineConfig,
     EngineState,
     Mailbox,
@@ -103,9 +104,19 @@ def _scan_ticks(
         bl = bl - m["accepted"]
         return (st, mb, bl), m
 
+    carry, first = (state, inbox, backlog), 0
+    if any(getattr(inbox, f).ndim != 2 for f in SENDER_LANES):
+        # A host path left a sender lane per edge (reorder, split
+        # staging, an old checkpoint): the first tick reads that form
+        # outside the scan, so the carry keeps the [G, src] form the
+        # tick writes.
+        carry, m0 = body(carry, jnp.int32(0))
+        first = 1
     (state, inbox, backlog), rec = jax.lax.scan(
-        body, (state, inbox, backlog), jnp.arange(n_ticks, dtype=jnp.int32)
+        body, carry, jnp.arange(first, n_ticks, dtype=jnp.int32)
     )
+    if first:
+        rec = {k: jnp.concatenate([m0[k][None], v]) for k, v in rec.items()}
     return state, inbox, backlog, rec
 
 

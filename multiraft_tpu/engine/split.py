@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .core import per_edge
 from .host import EngineDriver
 from .kv import BatchedKV, KVOp, Ticket, apply_kv_op
 from ..porcupine.kv import OP_APPEND, OP_GET, OP_PUT
@@ -222,7 +223,9 @@ class SplitPeering:
                 for f in _MB._fields:
                     if not f.startswith(prefix):
                         continue
-                    a = new.get(f, getattr(mb, f))
+                    # Staged lanes are per edge: a sender lane takes
+                    # them expanded (core.per_edge).
+                    a = new.get(f, per_edge(getattr(mb, f)))
                     sub = a[g_index]
                     mm = m[..., None] if sub.ndim == 4 else m
                     a = a.at[g_index].set(jnp.where(mm, vals[f], sub))
@@ -327,7 +330,11 @@ class SplitPeering:
                         if not sub[prefix + "active"][gi, src, dst]:
                             continue
                         fields = {
-                            f: _to_py(sub[f][gi, src, dst])
+                            f: _to_py(
+                                sub[f][gi, src]  # a sender lane
+                                if sub[f].ndim == 2
+                                else sub[f][gi, src, dst]
+                            )
                             for f in mb._fields
                             if f.startswith(prefix)
                         }

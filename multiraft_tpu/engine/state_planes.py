@@ -97,7 +97,9 @@ STATE_PLANES: Dict[str, str] = {
 # Mailbox fields are all in-flight message state: volatile by
 # construction (restart/reset mask the edges via _mask_edges rather
 # than per-field), including the config piggyback lanes — the CONFIG
-# *planes* live in EngineState; the ar_cfg_* lanes merely carry them.
+# *planes* live in EngineState; the ar_cfg_* lanes merely carry them,
+# once per sender ([G, src], core.SENDER_LANES, as the term, commit and
+# last-log lanes), since a leader's view is the same for every peer.
 MAILBOX_PLANES: Dict[str, str] = {
     "vr_active": VOLATILE,
     "vr_term": VOLATILE,
