@@ -27,6 +27,8 @@ import numpy as np
 
 from traffic import LOADER, TAG, Records, code
 
+NO_VALUE = "<a reply that is no value of ours>"
+
 
 class History:
     """Flat arrays of every recorded operation: the load, the closed
@@ -146,14 +148,59 @@ def porcupine_sample(h: History, loop, keys, extra_reads, timeout_s: float
             ops.append(Operation(c, KvInput(OP_PUT, key, records.value(c, n)),
                                  rec.call[c, n], KvOutput(""), ret))
         elif acked[c, n]:
+            # A read kept no value where its reply was no value of ours
+            # (``rec.bad_value``): it stands as one nobody wrote.
             ops.append(Operation(c, KvInput(OP_GET, key), rec.call[c, n],
-                                 KvOutput(rec.kept[(c, n)]), rec.ret[c, n]))
+                                 KvOutput(rec.kept.get((c, n), NO_VALUE)), rec.ret[c, n]))
     for k, call, ret, value in extra_reads:
         if keyset[k]:
             ops.append(Operation(LOADER, KvInput(OP_GET, records.keys[k]), call,
                                  KvOutput(value), ret))
     verdict = check_operations(kv_model, ops, timeout=timeout_s)
     return verdict.value, len(ops)
+
+
+def before_the_kill(loop, t_kill: float, at_least: int, recent_s: float
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """The keys whose last acknowledged update was acknowledged before
+    ``t_kill``: every one acknowledged in the ``recent_s`` before it (past
+    the last checkpoint, so only the WAL holds it) and at least the
+    ``at_least`` latest.  Returns the keys, sorted, and the call time of
+    each one's last acknowledged update."""
+    rec = loop.rec
+    cl, nn = np.nonzero(loop.is_update & ~np.isnan(rec.ret))
+    key, call, ret = loop.key_index[cl, nn], rec.call[cl, nn], rec.ret[cl, nn]
+    order = np.lexsort((ret, key))
+    key, call, ret = key[order], call[order], ret[order]
+    last = np.append(key[1:] != key[:-1], True)      # each key's last acknowledged update
+    before = last & (ret < t_kill)
+    key, call, ret = key[before], call[before], ret[before]
+    n = max(int((ret >= t_kill - recent_s).sum()), min(at_least, len(key)))
+    pick = np.sort(np.argsort(ret)[::-1][:n])
+    return key[pick], call[pick]
+
+
+def lost_at_the_kill(h: History, loop, keys: np.ndarray, last_call: np.ndarray,
+                     tags: np.ndarray) -> Tuple[List[str], Dict[str, int]]:
+    """Read back after a ``kill -9`` and restart: a key whose last
+    acknowledged update came before the kill (``before_the_kill``) must
+    read back that update, or a write that was not acknowledged before it
+    was called.  A key that reads back an older value, or one nobody
+    wrote to it, lost an acknowledged update.  Returns what is wrong and
+    the number compared (limit 0)."""
+    rec, cap = loop.rec, loop.key_index.shape[1]
+    writer, n = tags // 10 ** (TAG - 2), tags % 10 ** (TAG - 2)
+    ret = np.full(len(keys), np.nan)
+    loaded = (tags >= 0) & (writer == LOADER) & (n == keys)
+    ret[loaded] = h.w_ret[0][0]
+    ours = (tags >= 0) & (writer < loop.key_index.shape[0]) & (n < cap)
+    c, m = writer[ours], n[ours]
+    wrote = loop.is_update[c, m] & ~np.isnan(rec.call[c, m]) & (loop.key_index[c, m] == keys[ours])
+    ret[np.flatnonzero(ours)[wrote]] = np.where(np.isnan(rec.ret[c, m]), np.inf, rec.ret[c, m])[wrote]
+    lost = np.isnan(ret) | (ret < last_call)       # nobody's write, or one acknowledged before
+    wrong = [f"{h.records.keys[k]}: read back tag {t} after the restart, not its update acknowledged "
+             f"before the kill" for k, t in zip(keys[lost][:3].tolist(), tags[lost][:3].tolist())]
+    return wrong, {"acked_before_kill_lost": int(lost.sum())}
 
 
 def durability_counters(before: Dict[str, Any], after: Dict[str, Any],

@@ -19,6 +19,9 @@ Readers (``"reader": {"kind": ..., ...}``):
                    (0 where ``num`` is one of ``den`` and only the rest grew)
 ``client``         ``field``: a number the load generator measured
 ``trace``          ``field``: a number from the reduced trace
+``restart_gauge``  ``gauge``: a gauge of the server started again after a
+                   ``kill -9`` inside the window, from its first scrape after
+                   ``ready`` (a run with no such restart reads nothing)
 """
 
 from __future__ import annotations
@@ -34,11 +37,13 @@ class Gathered:
     def __init__(self, window_s: float, counters0: Dict[str, float],
                  counters1: Dict[str, float], hists0: Dict[str, Any],
                  hists1: Dict[str, Any], client: Dict[str, float],
-                 trace: Dict[str, float]) -> None:
+                 trace: Dict[str, float],
+                 restarted: Optional[Dict[str, float]] = None) -> None:
         self.window_s = window_s
         self.c0, self.c1 = counters0, counters1
         self.h0, self.h1 = hists0, hists1
         self.client, self.trace = client, trace
+        self.restarted = restarted
 
     def counter(self, name: str) -> Optional[float]:
         if name not in self.c1:
@@ -106,6 +111,14 @@ def _field(source: str) -> Callable[[Gathered, Dict[str, Any]], Reading]:
     return read
 
 
+def _restart_gauge(g: Gathered, r: Dict[str, Any]) -> Reading:
+    if g.restarted is None:
+        return None, "the server was not started again inside the window"
+    if r["gauge"] not in g.restarted:
+        return None, f"the restarted server has no gauge {r['gauge']}"
+    return float(g.restarted[r["gauge"]]), ""
+
+
 READERS: Dict[str, Callable[[Gathered, Dict[str, Any]], Reading]] = {
     "hist_mean": _hist_mean,
     "hist_sum": _hist_sum,
@@ -114,6 +127,7 @@ READERS: Dict[str, Callable[[Gathered, Dict[str, Any]], Reading]] = {
     "counter_ratio": _counter_ratio,
     "client": _field("client"),
     "trace": _field("trace"),
+    "restart_gauge": _restart_gauge,
 }
 
 

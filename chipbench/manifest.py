@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -31,6 +31,24 @@ def _load(path: str) -> Dict[str, Any]:
 
 def manifest() -> Dict[str, Any]:
     return _load(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def kill_at(traffic: Dict[str, Any], seconds: float) -> Optional[float]:
+    """The fault a mix schedules inside the window, from its ``faults``
+    list: seconds after the window starts at which the server is killed
+    and started again (``{"kind": "kill", "at_s": s}``), or None for a
+    mix with no ``faults``.  ``kill`` is the one kind built, once a run;
+    the entry names its kind so that another can be added beside it."""
+    faults = traffic.get("faults")
+    if not faults:
+        return None
+    if len(faults) != 1 or faults[0].get("kind") != "kill" or set(faults[0]) != {"kind", "at_s"}:
+        raise ManifestError(f"faults {faults!r}: one {{'kind': 'kill', 'at_s': s}} is the "
+                            f"only schedule built")
+    at_s = float(faults[0]["at_s"])
+    if not 0.0 < at_s < seconds:
+        raise ManifestError(f"faults: a kill at {at_s:g}s is outside a window of {seconds:g}s")
+    return at_s
 
 
 def _applies(metric: Dict[str, Any], cell: str) -> bool:

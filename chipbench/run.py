@@ -34,7 +34,7 @@ import shutil
 import signal
 import subprocess
 import sys
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -78,6 +78,13 @@ PROFILER_DRAIN_S = 5.0
 # traced window ends when the profiler has stopped (25 s late on the mesh).
 SCHEDULE_SLACK_S = 60.0
 READBACK_KEYS = 1000     # updated keys read back whole after the window
+# A mix with ``faults`` (manifest.kill_at): the server is killed and started
+# again inside the window.  Recovery has this long from the kill to its first
+# acknowledged operation, or the run gives no result.  After it, every key
+# whose last acknowledged update came in the KILL_RECENT_S before the kill,
+# and at least the KILL_READBACK_KEYS latest, is read back.
+RECOVER_CAP_S = 300.0
+KILL_READBACK_KEYS, KILL_RECENT_S = 500, 10.0
 # Porcupine (a full search, with whole values) runs on a seeded sample of
 # the ranks that see more than a few operations in a window.
 PORCUPINE_KEYS, PORCUPINE_FROM_RANKS, PORCUPINE_TIMEOUT_S = 200, 5000, 20.0
@@ -109,19 +116,44 @@ class Server:
         os.makedirs(self.side)
         self.port = reserve_ports(1, "127.0.0.1")[0]
         self.err_path = os.path.join(work, "server.err")
-        argv = [
+        self.argv = [
             sys.executable, os.path.join(HERE, "server_child.py"), self.side,
             *serve, "--platform", platform, "--data-dir", os.path.join(work, "data"),
             "--seed", str(seed % (2 ** 31 - 1)), "--port", str(self.port),
         ]
-        env = {**os.environ, **env}  # the configuration fixes the deployment; it wins
+        self.env = {**os.environ, **env}  # the configuration fixes the deployment; it wins
         if platform == "cpu":
-            env["JAX_PLATFORMS"] = "cpu"
-        with open(self.err_path, "w") as err:
-            self.proc = subprocess.Popen(
-                argv, cwd=ROOT, env=env, text=True, stdout=subprocess.PIPE, stderr=err,
-            )
+            self.env["JAX_PLATFORMS"] = "cpu"
+        self.proc = self._spawn("w")
         self.reports = 0
+
+    def _spawn(self, mode: str) -> subprocess.Popen:
+        with open(self.err_path, mode) as err:
+            return subprocess.Popen(
+                self.argv, cwd=ROOT, env=self.env, text=True, stdout=subprocess.PIPE, stderr=err,
+            )
+
+    def restart(self) -> None:
+        """``kill -9`` the child and start it again as it was started: the
+        same argv, environment, seed, data directory and port.  The new
+        child numbers its reports from 1; its stderr follows the old's."""
+        self.kill()
+        self.t_dead = time.perf_counter()
+        for name in os.listdir(self.side):
+            if name.startswith("report."):
+                os.remove(os.path.join(self.side, name))
+        self.reports = 0
+        self.proc = self._spawn("a")
+        self.t_spawned = time.perf_counter()
+
+    def child_clock(self) -> Dict[str, float]:
+        """The newest child's ``child clock:`` line (``server_child.py``):
+        when its interpreter was up, jax imported, the program imported."""
+        with open(self.err_path) as f:
+            found = [ln.split() for ln in f.read().splitlines() if ln.startswith("child clock:")]
+        if not found:
+            raise RunFailed(f"{self.label} printed no child clock line")
+        return dict(zip(found[-1][2::2], map(float, found[-1][3::2])))
 
     def stderr_tail(self) -> str:
         with open(self.err_path) as f:
@@ -202,12 +234,21 @@ class Client:
             raise RunFailed("the server did not answer")
         return out
 
-    def scrape(self) -> Dict[str, Any]:
+    def scrape(self, retry_s: float = 0.0) -> Dict[str, Any]:
         """Counters (``Obs.snapshot``; its percentiles are since process
-        start and are not read) and cumulative histograms (``Obs.hist``)."""
-        snap = self.call("Obs.snapshot")
-        hist = self.call("Obs.hist")
-        return {"counters": snap["metrics"], "hists": hist["hists"]}
+        start and are not read) and cumulative histograms (``Obs.hist``).
+        ``retry_s``: ask again for that long where the call fails, as the
+        first call over a connection that a restart broke does."""
+        deadline = time.monotonic() + retry_s
+        while True:
+            try:
+                snap = self.call("Obs.snapshot")
+                hist = self.call("Obs.hist")
+                return {"counters": snap["metrics"], "hists": hist["hists"]}
+            except RunFailed:
+                if time.monotonic() > deadline:
+                    raise
+                time.sleep(0.1)
 
     def firehose(self, ops, cap_s: float):
         from multiraft_tpu.distributed.engine_clerks import FirehoseClerk
@@ -217,6 +258,75 @@ class Client:
                 ops, deadline_s=cap_s),
             cap_s + 30.0,
         )
+
+
+# ---------------------------------------------------------------------------
+# A fault inside the window
+# ---------------------------------------------------------------------------
+
+
+def _sleep_until(t: float) -> None:
+    time.sleep(max(t - time.perf_counter(), 0.0))
+
+
+def profile(server: Server) -> Tuple[float, float]:
+    """The profiler on in the server for TRACE_S.  Returns when it was
+    asked to start, and PROFILER_DRAIN_S after its stop returned."""
+    p0 = time.perf_counter()
+    server.signal_and_await(signal.SIGUSR1, "trace.started", 60.0)
+    time.sleep(TRACE_S)
+    server.signal_and_await(signal.SIGUSR2, "trace.stopped", 120.0)
+    return p0, time.perf_counter() + PROFILER_DRAIN_S
+
+
+def kill_inside(server: Server, client: Client, t0: float, at_s: float,
+                trace: bool) -> Dict[str, Any]:
+    """``kill -9`` of the server ``at_s`` seconds into the window and the
+    same server started again on its data directory and port, while the
+    clerks keep calling on their one node (``tcp.py`` dials again on the
+    next call).  The steady stretch before the kill is what per-layer
+    metrics read: a traced run's profiler traces TRACE_S of it, after the
+    first checkpoint and 4 s before the kill is due; ``after`` is scraped
+    1 s before the kill (``t1``), which waits for the profiler's stop to
+    return (~35 s on the chip: a traced run's kill comes late).  Returns
+    those and the restart's readings: its ``t_kill``, ``t_ready``, first
+    scrape (``restarted``, returned at ``t_restarted``) and the two
+    processes' compile reports."""
+    out: Dict[str, Any] = {"profiler": None}
+    if trace:
+        _sleep_until(t0 + at_s - 4.0 - TRACE_S)
+        out["profiler"] = profile(server)
+    _sleep_until(t0 + at_s - 1.0)
+    out["cpu1"], out["t1"] = time.process_time(), time.perf_counter()
+    if out["t1"] > t0 + at_s - 0.5:
+        say(f"NOTE the profiler's stop returned {out['t1'] - t0:.1f}s into the window: the kill "
+            f"comes {out['t1'] + 1.0 - t0 - at_s:.1f}s late, and the stretch before it is longer")
+    out["after"] = client.scrape()
+    _sleep_until(out["t1"] + 1.0)
+    out["report_killed"] = server.report()
+    out["t_kill"] = time.perf_counter()
+    server.restart()
+    dev = server.wait_ready(RECOVER_CAP_S)
+    out["t_ready"] = time.perf_counter()
+    say(f"killed {out['t_kill'] - t0:.1f}s into the window; ready again "
+        f"{out['t_ready'] - out['t_kill']:.1f}s later; device {dev}")
+    out["restarted"] = client.scrape(retry_s=30.0)
+    out["t_restarted"] = time.perf_counter()
+    out["report_ready"] = server.report()
+    return out
+
+
+def first_ack_after(loop, t: float, deadline: float) -> float:
+    """The earliest acknowledgement at or after ``t`` (perf_counter);
+    waits for one until ``deadline``.  Exact once the loop has stopped."""
+    ret = loop.rec.ret
+    while True:
+        later = ret[ret >= t]
+        if len(later):
+            return float(later.min())
+        if time.perf_counter() > deadline:
+            raise RunFailed(f"no operation acknowledged within {RECOVER_CAP_S:.0f}s of the kill")
+        time.sleep(0.05)
 
 
 # ---------------------------------------------------------------------------
@@ -300,11 +410,12 @@ def reduce_trace(side: str, program: str, rehearse: bool) -> Dict[str, Any]:
 
 def verify(client: Client, loop, history: check.History, keep: np.ndarray,
            rng: np.random.Generator, quiet0: Dict[str, Any], quiet1: Dict[str, Any],
-           compiled: int) -> Dict[str, int]:
+           compiled: int, kill: Optional[Dict[str, Any]] = None) -> Dict[str, int]:
     """What decides ``correct``, outside the window: returns every number
     compared (each has the limit 0: the comparisons are exact).
     ``quiet0``/``quiet1`` are the server's counters before the first and
-    after the last operation of the loop."""
+    after the last operation of the loop.  ``kill``: ``kill_inside``'s
+    readings, where the server was killed and started again."""
     records = history.records
     wrong: List[str] = list(loop.rec.bad_value[:3])
     compared = {"replies_that_are_no_value": len(loop.rec.bad_value), "keys_read_back_wrong": 0}
@@ -313,6 +424,10 @@ def verify(client: Client, loop, history: check.History, keep: np.ndarray,
     updated = np.unique(loop.key_index[acked_update])
     sample = rng.choice(updated, min(READBACK_KEYS, len(updated)), replace=False)
     sample = np.union1d(sample, np.intersect1d(keep, updated))
+    if kill is not None:
+        lost_keys, last_call = check.before_the_kill(loop, kill["t_kill"], KILL_READBACK_KEYS,
+                                                     KILL_RECENT_S)
+        sample = np.union1d(sample, lost_keys)
     c0 = time.perf_counter()
     got = client.firehose([("Get", records.keys[k], "") for k in sample.tolist()], 120.0)
     c1 = time.perf_counter()
@@ -325,10 +440,30 @@ def verify(client: Client, loop, history: check.History, keep: np.ndarray,
             compared["keys_read_back_wrong"] += 1
         tags.append(tag)
     history.add_reads(sample, np.full(len(sample), c0), np.full(len(sample), c1), tags)
-    for lines, counts in (check.register_check(history),
-                          check.durability_counters(quiet0, quiet1, int(acked_update.sum()))):
+    if kill is None:
+        durable = check.durability_counters(quiet0, quiet1, int(acked_update.sum()))
+    else:
+        # The counters cannot span two processes: the old one's from quiet0
+        # to its last scrape, for the updates acknowledged before that
+        # scrape was sent; the new one's from its first scrape to quiet1,
+        # for the updates called after that scrape returned.
+        ret, call = loop.rec.ret, loop.rec.call
+        old = check.durability_counters(quiet0, kill["after"]["counters"],
+                                        int((acked_update & (ret < kill["t1"])).sum()))
+        new = check.durability_counters(kill["restarted"]["counters"], quiet1,
+                                        int((acked_update & (call > kill["t_restarted"])).sum()))
+        durable = (old[0] + new[0], {k: old[1][k] + new[1][k] for k in old[1]})
+    for lines, counts in (check.register_check(history), durable):
         wrong += lines
         compared.update(counts)
+    if kill is not None:
+        at = np.searchsorted(sample, lost_keys)
+        lines, counts = check.lost_at_the_kill(history, loop, lost_keys, last_call,
+                                               np.asarray(tags, np.int64)[at])
+        wrong += lines
+        compared.update(counts)
+        say(f"check across the kill: {len(lost_keys)} keys whose last acknowledged update "
+            f"came before it read back, {counts['acked_before_kill_lost']} of them lost it")
     verdict, n_ops = check.porcupine_sample(
         history, loop, keep.tolist(),
         [(k, c0, c1, v) for k, v in zip(sample.tolist(), got)], PORCUPINE_TIMEOUT_S)
@@ -369,6 +504,7 @@ def run(ns) -> int:
         raise RunFailed(f"traffic {mix!r}: only loop=closed or open, path=command is built")
     platform = "cpu" if ns.rehearse_cpu else "tpu"
     seconds = float(ns.seconds)
+    at_s = manifest.kill_at(mix, seconds)
     work = os.path.join(ROOT, ".chipbench_run", ns.workload)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
@@ -437,21 +573,24 @@ def run(ns) -> int:
         setup_s = time.monotonic() - _T0
         say(f"window of {seconds:.0f}s starts {time.monotonic() - t_ready:.1f}s after ready")
 
-        profiler = None
-        if ns.trace:
+        profiler, kill = None, None
+        if at_s is not None:
+            kill = kill_inside(server, client, t0, at_s, bool(ns.trace))
+            profiler = kill["profiler"]
+        elif ns.trace:
             time.sleep(max((seconds - TRACE_S) / 2.0, 0.0))
-            p0 = time.perf_counter()
-            server.signal_and_await(signal.SIGUSR1, "trace.started", 60.0)
-            time.sleep(TRACE_S)
-            server.signal_and_await(signal.SIGUSR2, "trace.stopped", 120.0)
-            profiler = (p0, time.perf_counter() + PROFILER_DRAIN_S)
-        time.sleep(max(t0 + seconds - time.perf_counter(), 0.0))
+            profiler = profile(server)
+        _sleep_until(t0 + seconds)
 
         cpu1, t1 = time.process_time(), time.perf_counter()
         clerk = client.node.obs.metrics.counters
         say(f"clerks so far: {clerk.get('clerk.calls', 0)} calls, "
             f"{clerk.get('clerk.retries', 0)} retries, {clerk.get('clerk.busy', 0)} shed (ErrBusy)")
-        after = client.scrape()
+        if kill is None:
+            after = client.scrape()
+        else:
+            after = kill["after"]
+            first_ack_after(loop, kill["t_ready"], kill["t_kill"] + RECOVER_CAP_S)
         report1 = server.report()
         loop.stop(DRAIN_S)
         quiet1 = client.scrape()
@@ -466,8 +605,9 @@ def run(ns) -> int:
             if not k.endswith(("_p50", "_p99", "_count"))
             and v != before["counters"].get(k, 0)
         }
-        say(f"server counters over the window: {json.dumps(grew)}")
-        say("server clocks over the window (samples, sum s, longest <= s): "
+        span = "over the window" if kill is None else "from the window's start to 1 s before the kill"
+        say(f"server counters {span}: {json.dumps(grew)}")
+        say(f"server clocks {span} (samples, sum s, longest <= s): "
             + stage_times(before["hists"], after["hists"]))
         say(f"window: {e2e['completed']} ops acknowledged ({e2e['updates']} updates, "
             f"{e2e['reads']} reads), {e2e['failed']} never acknowledged; ms"
@@ -497,14 +637,43 @@ def run(ns) -> int:
                     f"the generator")
 
         compiled = report1["compile_events"] - report0["compile_events"]
+        if kill is not None:
+            # Each process's own count: the old one's up to the kill, the new
+            # one's from its first scrape after `ready` (what it compiled or
+            # loaded before `ready` is recovery) to the window's end.
+            compiled = (kill["report_killed"]["compile_events"] - report0["compile_events"]
+                        + report1["compile_events"] - kill["report_ready"]["compile_events"])
+            t_first = first_ack_after(loop, kill["t_ready"], 0.0)
+            clock = server.child_clock()
+            e2e["recover_s"] = t_first - kill["t_kill"]
+            e2e["reconnect_s"] = t_first - kill["t_ready"]
+            e2e["exit_s"] = server.t_dead - kill["t_kill"]
+            e2e["start_s"] = clock["program"] - server.t_spawned
+            stages = {k: v for k, v in sorted(kill["restarted"]["counters"].items())
+                      if k.startswith("ready.")}
+            parts = e2e["exit_s"] + e2e["start_s"] + sum(stages.values()) + e2e["reconnect_s"]
+            say(f"recovery: recover_s {e2e['recover_s']:.3f} = kill to ready "
+                f"{kill['t_ready'] - kill['t_kill']:.3f} + ready to the first acknowledgement "
+                f"{e2e['reconnect_s']:.3f}; by part: kill to the old process's exit "
+                f"{e2e['exit_s']:.3f}, spawn to the program imported {e2e['start_s']:.3f} "
+                f"(interpreter {clock['start'] - server.t_spawned:.3f}, import jax "
+                f"{clock['jax'] - clock['start']:.3f}), the restarted server's " + ", ".join(
+                    f"{k} {v:.3f}" for k, v in stages.items())
+                + f", the re-dial; those {parts:.3f}, the rest (spawn, the build's imports, the "
+                f"wait for `ready` on the pipe) {e2e['recover_s'] - parts:.3f}; the first "
+                f"start's " + ", ".join(f"{k} {before['counters'][k]:.3f}" for k in stages
+                                        if k in before["counters"]))
         compared = verify(client, loop, history, keep, rng,
-                          quiet0["counters"], quiet1["counters"], compiled)
+                          quiet0["counters"], quiet1["counters"], compiled, kill)
 
         final = server.report()
         server.kill()
 
         # -- the line -------------------------------------------------
-        device = dict(dev, memory_peak_bytes=final["memory_peak_bytes"])
+        peak = final["memory_peak_bytes"]
+        if kill is not None:
+            peak = max(peak, kill["report_killed"]["memory_peak_bytes"])
+        device = dict(dev, memory_peak_bytes=peak)
         out: Dict[str, Any] = {
             "correct": not any(compared.values()),
             "attempted": e2e["completed"] + e2e["failed"],
@@ -523,10 +692,17 @@ def run(ns) -> int:
                 raise RunFailed("the trace shows no operation on the device")
             device["busy_s"], device["window_s"] = traced["busy_s"], traced["window_s"]
             out["breakdown"] = traced["breakdown"]
+            t_end, cpu_end, measured, restarted = t1, cpu1, e2e, None
+            if kill is not None:   # the steady stretch before the kill, and the restart
+                t_end, cpu_end = kill["t1"], kill["cpu1"]
+                measured = dict(end_to_end(loop, t0, t_end), **{
+                    k: e2e[k] for k in ("recover_s", "reconnect_s", "exit_s", "start_s")})
+                restarted = kill["restarted"]["counters"]
             gathered = layers.Gathered(
-                t1 - t0, before["counters"], after["counters"], before["hists"],
+                t_end - t0, before["counters"], after["counters"], before["hists"],
                 after["hists"],
-                {**e2e, "cpu_share": 100.0 * (cpu1 - cpu0) / (t1 - t0)}, traced["metrics"],
+                {**measured, "cpu_share": 100.0 * (cpu_end - cpu0) / (t_end - t0)},
+                traced["metrics"], restarted,
             )
             # Device time of the tick program over the ticks it ran: the
             # trace counts programs, the server's counters ticks per program.

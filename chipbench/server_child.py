@@ -25,6 +25,10 @@ in the parent.
 
 from __future__ import annotations
 
+import time
+
+_T_START = time.monotonic()  # the interpreter is up; its own start is before this
+
 import json
 import os
 import signal
@@ -95,8 +99,13 @@ def main(argv) -> int:
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import jax.monitoring
 
+    t_jax = time.monotonic()
     from multiraft_tpu.__main__ import main as program_main
 
+    # On CLOCK_MONOTONIC, the parent's clock too: where a start's seconds go
+    # before the program's own ``ready.*_s`` gauges begin.
+    print(f"child clock: start {_T_START:.6f} jax {t_jax:.6f} program {time.monotonic():.6f}",
+          file=sys.stderr, flush=True)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
     threading.Thread(target=_serve_signals, args=(side,), daemon=True,
                      name="chipbench-signals").start()

@@ -73,3 +73,36 @@ def test_durability_counters():
     assert lines and counts["acked_updates_not_in_wal"] == 1
     lines, counts = check.durability_counters(before, {"wal.appends": 105, "wal.fsyncs": 2}, 50)
     assert lines and counts["acks_without_fsync"] == 1
+
+
+def test_acknowledged_updates_from_before_a_kill_are_read_back():
+    """Client 0 updates key 3 twice (acked at 2 and 6), client 1 key 4 (acked
+    at 9), key 5 (acked at 12, after the kill at 10) and key 3 again (called
+    at 5.5, never acknowledged)."""
+    from types import SimpleNamespace
+
+    nan = np.nan
+    loop = SimpleNamespace(
+        is_update=np.array([[True, True, False], [True, True, True]]),
+        key_index=np.array([[3, 3, 4], [4, 5, 3]]),
+        rec=SimpleNamespace(call=np.array([[1.0, 5.0, 7.0], [8.0, 9.5, 5.5]]),
+                            ret=np.array([[2.0, 6.0, 7.5], [9.0, 12.0, nan]])),
+    )
+    keys, last_call = check.before_the_kill(loop, 10.0, at_least=1, recent_s=2.0)
+    assert keys.tolist() == [4] and last_call.tolist() == [8.0]
+    keys, last_call = check.before_the_kill(loop, 10.0, at_least=5, recent_s=2.0)
+    assert keys.tolist() == [3, 4] and last_call.tolist() == [5.0, 8.0]
+
+    h = check.History(traffic.Records(CFG, 1), t_loaded=0.0)
+
+    def lost(tag3, tag4):
+        lines, counts = check.lost_at_the_kill(h, loop, keys, last_call, np.array([tag3, tag4]))
+        assert bool(lines) == bool(counts["acked_before_kill_lost"])
+        return counts["acked_before_kill_lost"]
+
+    c = traffic.code
+    assert lost(c(0, 1), c(1, 0)) == 0       # each key's last acknowledged update
+    assert lost(c(1, 2), c(1, 0)) == 0       # an update called before it, never acknowledged
+    assert lost(c(0, 0), c(1, 0)) == 1       # acknowledged before the last was called
+    assert lost(c(0, 1), LOADED(4)) == 1     # the load: the update is gone
+    assert lost(c(1, 0), -1) == 2            # another key's write; no value at all

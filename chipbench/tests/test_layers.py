@@ -47,3 +47,13 @@ def test_a_share_of_a_whole_that_grew_reads_zero_where_its_part_did_not():
     assert rd(**{**share, "num": "wal.appends", "den": ["wal.appends", "wal.fsyncs"]}) == (80.0, "")
     value, why = rd(**{**share, "den": ["shed", "idle"]})
     assert value is None and why                    # the whole did not grow: nothing to read
+
+
+def test_a_gauge_of_the_restarted_server():
+    gauge = dict(kind="restart_gauge", gauge="ready.replay_s")
+    value, why = rd(**gauge)
+    assert value is None and why                    # no restart in this run
+    g = layers.Gathered(10.0, {}, {}, {}, {}, {}, {}, {"ready.replay_s": 2.5, "ready.elect_s": 0.0})
+    assert layers.read({"name": "x", "reader": gauge}, g) == (2.5, "")
+    value, why = layers.read({"name": "x", "reader": {**gauge, "gauge": "ready.nothing_s"}}, g)
+    assert value is None and why

@@ -24,6 +24,7 @@ def test_every_cell_resolves_to_its_files():
         assert "setup_s" in e2e and len(e2e) >= 2
         assert cell["layers"], "every cell reports a per-layer metric"
         assert w["chips"] in (1, 4)
+        manifest.kill_at(cell["traffic"], MAN["run_seconds"])   # a schedule that is built
 
 
 def test_configs_are_files_under_paths_and_state_what_the_manifest_says():
@@ -80,6 +81,37 @@ def test_a_layer_metric_for_every_cell_moves_a_metric_of_every_cell():
     for m in MAN["per_layer"]:
         if "workloads" not in m:
             assert m["moves"] not in listed, m["name"]
+
+
+def test_a_layer_metric_that_moves_a_listed_metric_lists_the_same_cells():
+    """README "Add a cell", rule 5: a cell joins a per-layer metric exactly
+    where it joined the listed end-to-end metric that the layer metric moves."""
+    lists = {m["name"]: set(m["workloads"]) for m in MAN["end_to_end"] if "workloads" in m}
+    for m in MAN["per_layer"]:
+        if m["moves"] in lists:
+            assert set(m.get("workloads", ())) == lists[m["moves"]], m["name"]
+
+
+def test_the_outage_is_read_exactly_where_the_server_is_killed():
+    for w in MAN["workloads"]:
+        cell = manifest.cell(w["name"])
+        killed = manifest.kill_at(cell["traffic"], MAN["run_seconds"]) is not None
+        assert killed == ("recover.outage_s" in {m["name"] for m in cell["layers"]}), w["name"]
+
+
+def test_a_fault_schedule_is_one_kill_inside_the_window():
+    assert manifest.kill_at({"loop": "closed"}, 50.0) is None
+    assert manifest.kill_at({"faults": [{"kind": "kill", "at_s": 20}]}, 50.0) == 20.0
+    for faults in (
+        [{"kind": "call", "at_s": 20}],              # a kind that is not built
+        [{"kind": "kill", "at_s": 20}] * 2,
+        [{"kind": "kill"}],
+        [{"kind": "kill", "at_s": 20, "method": "leave"}],
+        [{"kind": "kill", "at_s": 50}],              # not inside a 50 s window
+        [{"kind": "kill", "at_s": 0}],
+    ):
+        with pytest.raises(manifest.ManifestError):
+            manifest.kill_at({"faults": faults}, 50.0)
 
 
 def test_unknown_workload_is_an_error():

@@ -1,8 +1,10 @@
 """A rehearsal on the CPU prints, last, a line with the contract's keys
 and no other (but the mark that says it is a rehearsal, not a result):
-for a closed-loop cell and for the open-loop one.  Then the rest of a
-run with the timed path broken underneath: ``correct`` comes out false;
-and a generator that cannot offer its schedule gives no result."""
+for a closed-loop cell, for the open-loop one and for one whose server
+is killed and started again inside the window.  Then the rest of a run
+with the timed path broken underneath: ``correct`` comes out false; a
+restart that loses the WAL comes out not correct; and a generator that
+cannot offer its schedule gives no result."""
 
 import copy
 import json
@@ -17,6 +19,10 @@ import manifest
 CELLS = [w["name"] for w in manifest.manifest()["workloads"]]
 CELL = CELLS[0]
 OPEN = [c for c in CELLS if manifest.cell(c)["traffic"]["loop"] == "open"]
+CRASH = [c for c in CELLS if manifest.cell(c)["traffic"].get("faults")]
+RECOVERY = {f"recover.{part}_s" for part in
+            ("outage", "exit", "start", "backend", "restore", "warm", "replay", "checkpoint",
+             "reconnect")}
 SEED = str(2 ** 31 + 5)
 
 
@@ -40,7 +46,7 @@ def check_line(line, cell_name, trace):
 
 
 @pytest.mark.parametrize("cell,trace", [(CELL, 0), (CELL, 1)] + [(c, 1) for c in OPEN]
-                         + [(OPEN[0], 0)])
+                         + [(OPEN[0], 0)] + [(c, t) for c in CRASH for t in (0, 1)])
 def test_rehearsal_prints_the_contract_line(cell, trace):
     out = subprocess.run(
         [sys.executable, os.path.join(manifest.HERE, "run.py"), "--workload", cell,
@@ -57,6 +63,11 @@ def test_rehearsal_prints_the_contract_line(cell, trace):
         if trace:   # what the generator says of itself is there to be read
             assert {"client.late_p99_ms", "client.inflight_p95", "client.inflight_end"} \
                 <= set(line["metrics"])
+    if cell in CRASH:
+        assert "recovery: recover_s " in out.stdout and "check across the kill: " in out.stdout
+        assert line["compared"]["acked_before_kill_lost"] == {"value": 0, "limit": 0}
+        if trace:   # the restarted server's stages and the clerks' re-dial
+            assert RECOVERY <= set(line["metrics"])
 
 
 def test_no_tpu_no_result():
@@ -108,8 +119,9 @@ def test_a_generator_that_cannot_offer_its_schedule_gives_no_result(monkeypatch,
     assert "generator: " in out and "error: the generator did not offer its schedule" in err
 
 
+@pytest.mark.parametrize("cell", [OPEN[0]] + CRASH)
 @pytest.mark.parametrize("fault", ["answer_altered", "update_acknowledged_and_never_sent"])
-def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault):
+def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault, cell):
     from multiraft_tpu.distributed.engine_clerks import EngineClerk
     from traffic import TAG
 
@@ -133,7 +145,7 @@ def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault):
                 return ""
             return (yield from real_put(self, key, value))
         monkeypatch.setattr(EngineClerk, "put", put)
-    rc, lines, _out, err = rehearse(monkeypatch, capfd, OPEN[0])
+    rc, lines, _out, err = rehearse(monkeypatch, capfd, cell)
     assert rc == 0 and lines[-1]["correct"] is False, err[-1000:]
     failed = {k for k, c in lines[-1]["compared"].items() if c["value"] > c["limit"]}
     assert failed and "compared " in err
@@ -141,3 +153,26 @@ def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault):
         assert failed & {"reads_of_values_nobody_wrote", "reads_from_the_future"}
     else:
         assert failed & {"stale_reads", "acked_updates_not_in_wal", "keys_read_back_wrong"}
+
+
+def lose_the_wal(restart):
+    """The control of a cell with a kill: the server starts again on its
+    data directory without the WAL, as one that acknowledged updates
+    before they reached the disk would come back.  ``restart`` is
+    ``run.Server.restart``; this returns its replacement."""
+    def restart_without_the_wal(server):
+        server.kill()
+        data = server.argv[server.argv.index("--data-dir") + 1]
+        os.remove(os.path.join(data, "ops.wal"))
+        restart(server)
+    return restart_without_the_wal
+
+
+@pytest.mark.parametrize("cell", CRASH)
+def test_a_restart_that_loses_the_wal_comes_out_not_correct(monkeypatch, capfd, cell):
+    import run
+
+    monkeypatch.setattr(run.Server, "restart", lose_the_wal(run.Server.restart))
+    rc, lines, _out, err = rehearse(monkeypatch, capfd, cell)
+    assert rc == 0 and lines[-1]["correct"] is False, err[-1000:]
+    assert lines[-1]["compared"]["acked_before_kill_lost"]["value"] > 0
