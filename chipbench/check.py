@@ -219,3 +219,78 @@ def durability_counters(before: Dict[str, Any], after: Dict[str, Any],
         wrong.append("updates were acknowledged and wal.fsyncs did not grow")
     return wrong, {"acked_updates_not_in_wal": max(acked_updates - int(appends), 0),
                    "acks_without_fsync": int(bool(acked_updates and fsyncs <= 0))}
+
+
+def reconfiguration(legs: List[Dict[str, Any]], groups: int, shards: int
+                    ) -> Tuple[List[str], Dict[str, int]]:
+    """Ownership across the admin calls of a sharded deployment
+    (``admin.ShardSettle``'s legs), against the plain reference
+    (``shardref``): the config before the first call is the bootstrap
+    join of every replica group, and each call's config is the
+    reference's after the same join or leave.  Each leg is one config
+    (``configs_not_one_a_call``: a call applied twice or not at all);
+    after a leave no leaving group is in the config or owns a shard, and
+    the shards that moved are exactly those the leaving groups held;
+    after every call each group the calls leave in holds the shards
+    divided evenly, rounded down or up (3 or 4 of 33,330 over 9,999), and
+    the config holds no other group; the migration inserted and
+    deleted each moved shard once.  Returns what is wrong and the
+    numbers compared (limit 0)."""
+    from shardref import rebalance
+
+    wrong: List[str] = []
+    out = dict.fromkeys(("configs_not_one_a_call", "owners_not_the_references",
+                         "leavers_still_owning", "shards_moved_not_the_leavers",
+                         "groups_off_even_share", "inserts_not_shards_moved",
+                         "deletes_not_shards_moved", "confirms_not_shards_moved"), 0)
+    members = set(range(1, groups))
+    ref = rebalance(np.zeros(shards, np.int64), members)
+    for i, leg in enumerate(legs):
+        c0, c1, moved = leg["config0"], leg["config1"], leg["moved"]
+        gone = set(leg["gids"])
+        if i == 0:
+            out["owners_not_the_references"] += int((c0["owners"] != ref).sum())
+        members = members - gone if leg["op"] == "leave" else members | gone
+        ref = rebalance(ref, members)
+        counts = {
+            "configs_not_one_a_call": abs(c1["num"] - c0["num"] - 1),
+            "owners_not_the_references": int((c1["owners"] != ref).sum()),
+            "inserts_not_shards_moved": abs(leg["grew"]["shard.inserts"] - len(moved)),
+            "deletes_not_shards_moved": abs(leg["grew"]["shard.deletes"] - len(moved)),
+            "confirms_not_shards_moved": abs(leg["grew"]["shard.confirms"] - len(moved)),
+        }
+        if leg["op"] == "leave":
+            held = np.flatnonzero(np.isin(c0["owners"], leg["gids"]))
+            counts["leavers_still_owning"] = (int(np.isin(c1["owners"], leg["gids"]).sum())
+                                              + len(gone & set(c1["groups"])))
+            counts["shards_moved_not_the_leavers"] = len(np.setxor1d(held, moved))
+        load = np.bincount(c1["owners"], minlength=groups)
+        even = {shards // len(members), -(-shards // len(members))}
+        counts["groups_off_even_share"] = (sum(int(load[g]) not in even for g in members)
+                                           + len(set(c1["groups"]) - members))
+        for k, v in counts.items():
+            out[k] += v
+            if v:
+                wrong.append(f"{leg['op']} of {len(gone)} groups: {k} {v}")
+    return wrong, out
+
+
+def lost_on_the_move(h: History, keys: np.ndarray, tags: np.ndarray
+                     ) -> Tuple[List[str], Dict[str, int]]:
+    """Read back after the window, every key of a shard that moved: each
+    must read the value of a write that no acknowledged write followed
+    (one called after it was acknowledged), its last acknowledged value
+    or a later unacknowledged one.  ``h`` holds the loop's writes.
+    Returns what is wrong and the number compared (limit 0)."""
+    wk, wc, wr, wcode = map(np.concatenate, (h.w_key, h.w_call, h.w_ret, h.w_code))
+    last_acked_call = np.full(h.records.n, -np.inf)
+    acked = np.isfinite(wr)
+    np.maximum.at(last_acked_call, wk[acked], wc[acked])
+    order = np.argsort(wcode)
+    pos = np.minimum(np.searchsorted(wcode[order], tags), len(order) - 1)
+    seen = order[pos]
+    known = (wcode[seen] == tags) & (wk[seen] == keys)
+    lost = ~known | (wr[seen] < last_acked_call[keys])
+    wrong = [f"{h.records.keys[k]}: read back tag {t} after the moves, not its last "
+             f"acknowledged value" for k, t in zip(keys[lost][:3].tolist(), tags[lost][:3].tolist())]
+    return wrong, {"moved_keys_lost": int(lost.sum())}

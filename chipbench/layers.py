@@ -17,6 +17,10 @@ Readers (``"reader": {"kind": ..., ...}``):
 ``counter_ratio``  ``num``, ``den`` (one name or a list, summed), ``scale``:
                    growth of one over growth of the other, times scale
                    (0 where ``num`` is one of ``den`` and only the rest grew)
+``counter_per_op`` ``counter``, ``scale``: growth over the operations the
+                   load generator completed in the window, times scale
+                   (0 where the counter is there and did not grow: a share
+                   of the operations)
 ``client``         ``field``: a number the load generator measured
 ``trace``          ``field``: a number from the reduced trace
 ``restart_gauge``  ``gauge``: a gauge of the server started again after a
@@ -102,6 +106,13 @@ def _counter_ratio(g: Gathered, r: Dict[str, Any]) -> Reading:
     return num / grown * float(r.get("scale", 1.0)), ""
 
 
+def _counter_per_op(g: Gathered, r: Dict[str, Any]) -> Reading:
+    d, ops = g.counter(r["counter"]), g.client.get("completed")
+    if d is None or not ops:
+        return None, f"no counter {r['counter']}, or no operation completed in the window"
+    return d / ops * float(r.get("scale", 1.0)), ""
+
+
 def _field(source: str) -> Callable[[Gathered, Dict[str, Any]], Reading]:
     def read(g: Gathered, r: Dict[str, Any]) -> Reading:
         v = getattr(g, source).get(r["field"])
@@ -125,6 +136,7 @@ READERS: Dict[str, Callable[[Gathered, Dict[str, Any]], Reading]] = {
     "counter_delta": _counter_delta,
     "counter_rate": _counter_rate,
     "counter_ratio": _counter_ratio,
+    "counter_per_op": _counter_per_op,
     "client": _field("client"),
     "trace": _field("trace"),
     "restart_gauge": _restart_gauge,

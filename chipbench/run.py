@@ -43,6 +43,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, ROOT)
 
+import admin  # noqa: E402
 import check  # noqa: E402
 import layers  # noqa: E402
 import manifest  # noqa: E402
@@ -218,11 +219,17 @@ class Client:
         self.node = RpcNode()
         self.end = self.node.client_end("127.0.0.1", port)
 
-    def call(self, verb: str, cap_s: float = 120.0) -> Any:
+    def request(self, verb: str, args: Any, cap_s: float = 120.0) -> Any:
         from multiraft_tpu.sim.scheduler import TIMEOUT
 
-        out = self.node.sched.wait(self.end.call(verb, None), cap_s)
-        if out is TIMEOUT or not isinstance(out, dict):
+        out = self.node.sched.wait(self.end.call(verb, args), cap_s)
+        if out is TIMEOUT or out is None:
+            raise RunFailed(f"{verb} said {out!r}")
+        return out
+
+    def call(self, verb: str, cap_s: float = 120.0) -> Dict[str, Any]:
+        out = self.request(verb, None, cap_s)
+        if not isinstance(out, dict):
             raise RunFailed(f"{verb} said {out!r}")
         return out
 
@@ -313,6 +320,30 @@ def kill_inside(server: Server, client: Client, t0: float, at_s: float,
     out["restarted"] = client.scrape(retry_s=30.0)
     out["t_restarted"] = time.perf_counter()
     out["report_ready"] = server.report()
+    return out
+
+
+def admin_report(calls: admin.AdminCalls, t0: float) -> Dict[str, float]:
+    """Waits for the admin calls' last work, prints a ``reconfig:`` line
+    a call, and returns the numbers per-layer metrics read, by op (the
+    first call of each): ``<op>_ack_s`` (call to OK), ``<op>_settle_s``
+    (call to settled) and ``<op>_shards_moved``."""
+    legs = calls.finish((admin.ACK_CAP_S + admin.SETTLE_CAP_S) * len(calls.calls))
+    out: Dict[str, float] = {}
+    for leg in legs:
+        op, polls = leg["op"], leg.get("polls_s", [])
+        fields = {f"{op}_ack_s": leg["t_ack"] - leg["t_call"]}
+        if "t_settle" in leg:
+            fields[f"{op}_settle_s"] = leg["t_settle"] - leg["t_call"]
+            fields[f"{op}_shards_moved"] = float(len(leg["moved"]))
+        for k, v in fields.items():
+            out.setdefault(k, v)
+        say(f"reconfig: {op} of {len(leg['gids'])} groups called {leg['t_call'] - t0:.2f}s into "
+            f"the window" + (f" ({leg['late_s']:.2f}s late)" if "late_s" in leg else "")
+            + ", " + ", ".join(f"{k[len(op) + 1:]} {v:.3f}" for k, v in fields.items())
+            + (f"; {len(polls)} polls, round trip s p50 {np.median(polls):.4f} max "
+               f"{max(polls):.4f}; config {leg['config0']['num']} -> {leg['config1']['num']}; grew "
+               f"{json.dumps(leg['grew'])}" if polls else ""))
     return out
 
 
@@ -410,12 +441,15 @@ def reduce_trace(side: str, program: str, rehearse: bool) -> Dict[str, Any]:
 
 def verify(client: Client, loop, history: check.History, keep: np.ndarray,
            rng: np.random.Generator, quiet0: Dict[str, Any], quiet1: Dict[str, Any],
-           compiled: int, kill: Optional[Dict[str, Any]] = None) -> Dict[str, int]:
+           compiled: int, kill: Optional[Dict[str, Any]] = None,
+           calls: Optional[admin.AdminCalls] = None) -> Dict[str, int]:
     """What decides ``correct``, outside the window: returns every number
     compared (each has the limit 0: the comparisons are exact).
     ``quiet0``/``quiet1`` are the server's counters before the first and
     after the last operation of the loop.  ``kill``: ``kill_inside``'s
-    readings, where the server was killed and started again."""
+    readings, where the server was killed and started again.  ``calls``:
+    the admin calls made inside the window, whose settle rule (if the
+    service has one) names keys to read back and checks of its own."""
     records = history.records
     wrong: List[str] = list(loop.rec.bad_value[:3])
     compared = {"replies_that_are_no_value": len(loop.rec.bad_value), "keys_read_back_wrong": 0}
@@ -428,6 +462,10 @@ def verify(client: Client, loop, history: check.History, keep: np.ndarray,
         lost_keys, last_call = check.before_the_kill(loop, kill["t_kill"], KILL_READBACK_KEYS,
                                                      KILL_RECENT_S)
         sample = np.union1d(sample, lost_keys)
+    rule = calls.rule if calls is not None else None
+    if rule:
+        moved_keys = rule.read_back(calls.legs, records)
+        sample = np.union1d(sample, moved_keys)
     c0 = time.perf_counter()
     got = client.firehose([("Get", records.keys[k], "") for k in sample.tolist()], 120.0)
     c1 = time.perf_counter()
@@ -464,6 +502,14 @@ def verify(client: Client, loop, history: check.History, keep: np.ndarray,
         compared.update(counts)
         say(f"check across the kill: {len(lost_keys)} keys whose last acknowledged update "
             f"came before it read back, {counts['acked_before_kill_lost']} of them lost it")
+    if rule:
+        at = np.searchsorted(sample, moved_keys)
+        lines, counts = rule.check(calls.legs, history, moved_keys,
+                                   np.asarray(tags, np.int64)[at])
+        wrong += lines
+        compared.update(counts)
+        say(f"check across the admin calls: {len(moved_keys)} keys of the shards that moved read "
+            f"back; " + ", ".join(f"{k} {v}" for k, v in counts.items()))
     verdict, n_ops = check.porcupine_sample(
         history, loop, keep.tolist(),
         [(k, c0, c1, v) for k, v in zip(sample.tolist(), got)], PORCUPINE_TIMEOUT_S)
@@ -505,11 +551,15 @@ def run(ns) -> int:
     platform = "cpu" if ns.rehearse_cpu else "tpu"
     seconds = float(ns.seconds)
     at_s = manifest.kill_at(mix, seconds)
-    work = os.path.join(ROOT, ".chipbench_run", ns.workload)
+    admin_calls = manifest.admin_calls(mix, seconds)
+    # One directory a process: runs side by side share nothing.
+    work = os.path.join(ROOT, ".chipbench_run", f"{ns.workload}.{os.getpid()}")
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
+    say(f"run directory {work}")
     server: Optional[Server] = None
     client: Optional[Client] = None
+    calls: Optional[admin.AdminCalls] = None
     try:
         server = Server(work, serve, cfg.get("env", {}), platform, ns.seed)
         # While the child reaches the chip and compiles: the data.
@@ -544,8 +594,10 @@ def run(ns) -> int:
             raise RunFailed(f"the cell asks for {cell['chips']} chip(s), found {dev['count']}")
         client = Client(server.port, service)
         info = client.call(f"{service}.info")
-        if info["G"] != cfg["groups"]:
-            raise RunFailed(f"the server serves G={info['G']}, the configuration says {cfg['groups']}")
+        for got, key in (("G", "groups"), ("P", "replicas"), ("shards", "shards")):
+            if key in cfg and info.get(got) != cfg[key]:
+                raise RunFailed(f"the server serves {got}={info.get(got)}, the configuration "
+                                f"says {key} {cfg[key]}")
 
         # FirehoseClerk cuts the batch into the server's 8,192-row frames.
         client.firehose(records.load_ops(), 300.0)
@@ -573,10 +625,18 @@ def run(ns) -> int:
         setup_s = time.monotonic() - _T0
         say(f"window of {seconds:.0f}s starts {time.monotonic() - t_ready:.1f}s after ready")
 
-        profiler, kill = None, None
+        profiler, kill, reconfig = None, None, {}
         if at_s is not None:
             kill = kill_inside(server, client, t0, at_s, bool(ns.trace))
             profiler = kill["profiler"]
+        elif admin_calls:
+            # The calls keep their own schedule; a traced run's profiler
+            # starts with the first of them.
+            calls = admin.AdminCalls(service, admin_calls, cfg)
+            calls.begin(Client(server.port, service), t0)
+            if ns.trace:
+                _sleep_until(t0 + float(admin_calls[0]["at_s"]))
+                profiler = profile(server)
         elif ns.trace:
             time.sleep(max((seconds - TRACE_S) / 2.0, 0.0))
             profiler = profile(server)
@@ -588,6 +648,8 @@ def run(ns) -> int:
             f"{clerk.get('clerk.retries', 0)} retries, {clerk.get('clerk.busy', 0)} shed (ErrBusy)")
         if kill is None:
             after = client.scrape()
+            if calls is not None:
+                reconfig = admin_report(calls, t0)
         else:
             after = kill["after"]
             first_ack_after(loop, kill["t_ready"], kill["t_kill"] + RECOVER_CAP_S)
@@ -599,7 +661,7 @@ def run(ns) -> int:
                             "over SCHEDULE_SLACK_S to stop" if is_open else
                             "a client ran out of drawn operations: raise DRAWN_OPS_PER_CLIENT_PER_S")
 
-        e2e = end_to_end(loop, t0, t1)
+        e2e = dict(end_to_end(loop, t0, t1), **reconfig)
         grew = {
             k: v - before["counters"].get(k, 0) for k, v in sorted(after["counters"].items())
             if not k.endswith(("_p50", "_p99", "_count"))
@@ -664,7 +726,7 @@ def run(ns) -> int:
                 f"start's " + ", ".join(f"{k} {before['counters'][k]:.3f}" for k in stages
                                         if k in before["counters"]))
         compared = verify(client, loop, history, keep, rng,
-                          quiet0["counters"], quiet1["counters"], compiled, kill)
+                          quiet0["counters"], quiet1["counters"], compiled, kill, calls)
 
         final = server.report()
         server.kill()
@@ -726,13 +788,12 @@ def run(ns) -> int:
         print(json.dumps(out), flush=True)
         return 0
     finally:
-        if client is not None:
-            client.node.close()
+        for c in (client, calls and calls.client):
+            if c:
+                c.node.close()
         if server is not None:
             server.kill()
         shutil.rmtree(work, ignore_errors=True)
-        if not os.listdir(os.path.dirname(work)):
-            os.rmdir(os.path.dirname(work))
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -749,7 +810,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ns = ap.parse_args(argv)
     try:
         rc = run(ns)
-    except (RunFailed, manifest.ManifestError, ModuleNotFoundError) as exc:
+    except (RunFailed, admin.AdminFailed, manifest.ManifestError, ModuleNotFoundError) as exc:
         # ModuleNotFoundError: chipbench/ without the program beside it.
         print(f"error: {exc}", file=sys.stderr, flush=True)
         rc = 1

@@ -106,3 +106,46 @@ def test_acknowledged_updates_from_before_a_kill_are_read_back():
     assert lost(c(0, 0), c(1, 0)) == 1       # acknowledged before the last was called
     assert lost(c(0, 1), LOADED(4)) == 1     # the load: the update is gone
     assert lost(c(1, 0), -1) == 2            # another key's write; no value at all
+
+
+def test_a_moved_key_must_read_its_last_acknowledged_value():
+    writes = [(3, 1.0, 2.0, 1, 0), (3, 2.5, 3.0, 2, 0), (4, 1.0, np.inf, 1, 1)]
+    h = history([], writes)
+    keys = np.array([3, 3, 3, 4, 4, 5, 5])
+    tags = np.array([W2, W1, LOADED(3), traffic.code(1, 1), LOADED(4), LOADED(5), W1])
+    lines, counts = check.lost_on_the_move(h, keys, tags)
+    # W1 and the load were overwritten by W2; an unacknowledged update may
+    # or may not have landed; key 5 was never updated, and W1 is not its
+    assert counts == {"moved_keys_lost": 3} and len(lines) == 3
+
+
+def leg(op, gids, owners0, owners1, groups1, moved=None, grew=None, num=1):
+    moved = np.flatnonzero(np.array(owners0) != np.array(owners1)) if moved is None else moved
+    n = len(moved)
+    return {"op": op, "gids": gids, "moved": moved,
+            "config0": {"num": num, "owners": np.array(owners0), "groups": []},
+            "config1": {"num": num + 1, "owners": np.array(owners1), "groups": groups1},
+            "grew": grew or {f"shard.{k}": n for k in ("inserts", "deletes", "confirms")}}
+
+
+def test_a_leave_and_a_join_back_against_the_reference():
+    # 6 shards over groups 1-3 (G = 4), then group 3 leaves and joins back:
+    # orphans to the least loaded, lowest gid first; then the most loaded
+    # gives its lowest shard to the least loaded
+    boot, left = [1, 2, 3, 1, 2, 3], [1, 2, 1, 1, 2, 2]
+    sound = [leg("leave", [3], boot, left, [1, 2]),
+             leg("join", [3], left, [3, 3, 1, 1, 2, 2], [1, 2, 3], num=2)]
+    lines, counts = check.reconfiguration(sound, 4, 6)
+    assert not lines and not any(counts.values()), counts
+    # the join never happened: no config, group 3 holds nothing
+    skipped = [sound[0], leg("join", [3], left, left, [1, 2], num=2)]
+    skipped[1]["config1"]["num"] = 2
+    lines, counts = check.reconfiguration(skipped, 4, 6)
+    assert counts["configs_not_one_a_call"] == 1 and counts["groups_off_even_share"] == 3
+    assert counts["owners_not_the_references"] == 2
+    # a leaver keeps a shard, and the migration inserted one shard twice
+    kept = [leg("leave", [3], boot, [1, 2, 1, 1, 2, 3], [1, 2],
+                grew={"shard.inserts": 2, "shard.deletes": 1, "shard.confirms": 1})]
+    lines, counts = check.reconfiguration(kept, 4, 6)
+    assert counts["leavers_still_owning"] == 1 and counts["shards_moved_not_the_leavers"] == 1
+    assert counts["inserts_not_shards_moved"] == 1 and len(lines) >= 3

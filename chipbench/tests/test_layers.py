@@ -57,3 +57,16 @@ def test_a_gauge_of_the_restarted_server():
     assert layers.read({"name": "x", "reader": gauge}, g) == (2.5, "")
     value, why = layers.read({"name": "x", "reader": {**gauge, "gauge": "ready.nothing_s"}}, g)
     assert value is None and why
+
+
+def test_a_counter_per_operation_of_the_window():
+    g = layers.Gathered(10.0, {"shard.wrong_group": 5, "same": 7}, {"shard.wrong_group": 25, "same": 7},
+                        {}, {}, {"completed": 400}, {})
+    per_op = dict(kind="counter_per_op", counter="shard.wrong_group", scale=100.0)
+    assert layers.read({"name": "x", "reader": per_op}, g) == (5.0, "")
+    assert layers.read({"name": "x", "reader": {**per_op, "counter": "same"}}, g) == (0.0, "")
+    for reader, gathered in (({**per_op, "counter": "never-seen"}, g),
+                             (per_op, layers.Gathered(10.0, {}, {"shard.wrong_group": 3}, {}, {},
+                                                      {}, {}))):
+        value, why = layers.read({"name": "x", "reader": reader}, gathered)
+        assert value is None and why

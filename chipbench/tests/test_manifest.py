@@ -25,6 +25,8 @@ def test_every_cell_resolves_to_its_files():
         assert cell["layers"], "every cell reports a per-layer metric"
         assert w["chips"] in (1, 4)
         manifest.kill_at(cell["traffic"], MAN["run_seconds"])   # a schedule that is built
+        if cell["traffic"].get("rehearse_cpu", {}).get("faults"):
+            manifest.kill_at(cell["traffic"]["rehearse_cpu"], MAN["run_seconds"])
 
 
 def test_configs_are_files_under_paths_and_state_what_the_manifest_says():
@@ -112,6 +114,57 @@ def test_a_fault_schedule_is_one_kill_inside_the_window():
     ):
         with pytest.raises(manifest.ManifestError):
             manifest.kill_at({"faults": faults}, 50.0)
+
+
+def test_admin_calls_are_in_time_order_and_never_beside_a_kill():
+    assert manifest.admin_calls({"loop": "closed"}, 50.0) == []
+    assert manifest.admin_calls({"faults": [{"kind": "kill", "at_s": 20}]}, 50.0) == []
+    leave = {"kind": "admin", "at_s": 14, "op": "leave", "gids": {"every": 3}}
+    join = dict(leave, at_s=30, op="join")
+    calls = {"faults": [leave, join]}
+    assert manifest.admin_calls(calls, 50.0) == [leave, join]
+    assert manifest.kill_at(calls, 50.0) is None
+    for faults in (
+        [join, leave],                               # out of time order
+        [leave, dict(join, at_s=14)],
+        [leave, {"kind": "kill", "at_s": 20}],       # the two kinds mixed
+        [dict(leave, gids={"every": 0})],
+        [dict(leave, gids=[3, 6])],
+        [{k: v for k, v in leave.items() if k != "op"}],
+        [dict(leave, at_s=50)],
+    ):
+        with pytest.raises(manifest.ManifestError):
+            manifest.kill_at({"faults": faults}, 50.0)
+        with pytest.raises(manifest.ManifestError):
+            manifest.admin_calls({"faults": faults}, 50.0)
+
+
+def test_an_admin_op_is_one_the_service_takes(monkeypatch):
+    assert set(manifest.admin_ops("EngineShardKV")) >= {"join", "leave"}
+    assert manifest.admin_ops("NoSuchKV") == ()
+    assert manifest.gids({"every": 3}, 10000) == list(range(3, 10000, 3))
+    assert len(manifest.gids({"every": 3}, 64)) == 21
+    man = manifest.manifest()
+    cell = [w for w in man["workloads"] if w["traffic"] == "ycsb-a.reconfig"][0]["name"]
+    real = manifest._load
+
+    def unknown_op(path):
+        out = real(path)
+        for f in out.get("faults", ()):
+            if f["kind"] == "admin":
+                f["op"] = "drain"
+        return out
+    monkeypatch.setattr(manifest, "_load", unknown_op)
+    with pytest.raises(manifest.ManifestError, match="drain"):
+        manifest.cell(cell)
+
+
+def test_the_reconfig_metrics_are_read_exactly_where_admin_calls_are():
+    for w in MAN["workloads"]:
+        cell = manifest.cell(w["name"])
+        calls = manifest.admin_calls(cell["traffic"], MAN["run_seconds"])
+        named = {m["name"] for m in cell["layers"] if m["name"].startswith("reconfig.")}
+        assert bool(calls) == bool(named), w["name"]
 
 
 def test_unknown_workload_is_an_error():

@@ -19,14 +19,21 @@ import manifest
 CELLS = [w["name"] for w in manifest.manifest()["workloads"]]
 CELL = CELLS[0]
 OPEN = [c for c in CELLS if manifest.cell(c)["traffic"]["loop"] == "open"]
-CRASH = [c for c in CELLS if manifest.cell(c)["traffic"].get("faults")]
+CRASH = [c for c in CELLS if manifest.kill_at(manifest.cell(c)["traffic"], 50.0) is not None]
+RECONFIG = [c for c in CELLS if manifest.admin_calls(manifest.cell(c)["traffic"], 50.0)]
+MAY_BE_ZERO = {"admit.shed_share", "reconfig.wrong_group_share"}   # shares of the calls
 RECOVERY = {f"recover.{part}_s" for part in
             ("outage", "exit", "start", "backend", "restore", "warm", "replay", "checkpoint",
              "reconnect")}
 SEED = str(2 ** 31 + 5)
 
 
-def check_line(line, cell_name, trace):
+def run_directory(out):
+    """The directory a run names on its standard output."""
+    return [l.split("run directory ", 1)[1] for l in out.splitlines() if "] run directory " in l][0]
+
+
+def check_line(line, cell_name, trace, out):
     assert line.pop("rehearsal") is True
     want = {"correct", "attempted", "failed", "metrics", "device", "compared"}
     assert set(line) == (want | {"breakdown"} if trace else want)
@@ -39,10 +46,10 @@ def check_line(line, cell_name, trace):
     if not trace:
         assert set(line["metrics"]) == names
     for name, m in line["metrics"].items():   # a share of the calls may be 0 %: none was shed
-        assert set(m) == {"value", "unit"} and (m["value"] > 0 or name == "admit.shed_share"), name
+        assert set(m) == {"value", "unit"} and (m["value"] > 0 or name in MAY_BE_ZERO), name
     device = {"platform", "kind", "count", "memory_peak_bytes"}
     assert set(line["device"]) == (device | {"busy_s", "window_s"} if trace else device)
-    assert not os.path.exists(os.path.join(manifest.ROOT, ".chipbench_run", cell_name))
+    assert not os.path.exists(run_directory(out))   # the run removed what it wrote
 
 
 @pytest.mark.parametrize("cell,trace", [(CELL, 0), (CELL, 1)] + [(c, 1) for c in OPEN]
@@ -57,7 +64,7 @@ def test_rehearsal_prints_the_contract_line(cell, trace):
     line = json.loads(out.stdout.strip().splitlines()[-1])
     # every number compared stands beside its limit, last on stderr too
     assert out.stderr.strip().splitlines()[-1].startswith("compared ")
-    check_line(line, cell, trace)
+    check_line(line, cell, trace, out.stdout)
     if cell in OPEN:
         assert "ms from due:" in out.stdout and "generator: " in out.stdout
         if trace:   # what the generator says of itself is there to be read
@@ -119,7 +126,7 @@ def test_a_generator_that_cannot_offer_its_schedule_gives_no_result(monkeypatch,
     assert "generator: " in out and "error: the generator did not offer its schedule" in err
 
 
-@pytest.mark.parametrize("cell", [OPEN[0]] + CRASH)
+@pytest.mark.parametrize("cell", [OPEN[0]] + CRASH + RECONFIG)
 @pytest.mark.parametrize("fault", ["answer_altered", "update_acknowledged_and_never_sent"])
 def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capfd, fault, cell):
     from multiraft_tpu.distributed.engine_clerks import EngineClerk

@@ -19,9 +19,12 @@ CELL = "shardkv10k.ycsb-a"
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_the_sharded_cell_rehearses_to_the_contract_line(trace):
+    # 12 s: the window holds the server's first checkpoint (30 s after
+    # `ready`, the window 20 s after it), which the cell's ckpt.* metrics
+    # read, however soon the profiler's stop returns
     out = subprocess.run(
         [sys.executable, os.path.join(manifest.HERE, "run.py"), "--workload", CELL,
-         "--seed", SEED, "--seconds", "4", "--trace", str(trace), "--rehearse-cpu"],
+         "--seed", SEED, "--seconds", "12", "--trace", str(trace), "--rehearse-cpu"],
         cwd=manifest.ROOT, text=True, capture_output=True, timeout=600,
     )
     assert out.returncode == 0, out.stderr[-2000:]
@@ -32,7 +35,7 @@ def test_the_sharded_cell_rehearses_to_the_contract_line(trace):
         # a settled config: the sweep visits no group, and that reads 0, not nothing
         assert line["metrics"].pop("shard.orchestrate_groups") == {"value": 0.0, "unit": "%"}
         assert 0 < line["metrics"]["shard.orchestrate_ms"]["value"] < 0.1
-    check_line(line, CELL, trace)
+    check_line(line, CELL, trace, out.stdout)
 
 
 def test_the_cell_is_the_sharded_service_at_the_sources_ratio():
