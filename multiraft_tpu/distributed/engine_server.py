@@ -58,7 +58,7 @@ from .engine_wire import (
 from .admission import install_admission
 from .observe import Observability
 from .overload import install_overload_watch
-from .pump_cycle import PumpCycle
+from .pump_cycle import SERVED_TICKS, PumpCycle
 from .wedge import install_wedge_watch
 from .realtime import RealtimeScheduler
 from .tcp import RpcNode
@@ -99,7 +99,7 @@ class EngineKVService:
         sched: RealtimeScheduler,
         kv: BatchedKV,
         pump_interval: float = 0.002,
-        ticks_per_pump: int = 2,
+        ticks_per_pump: int = SERVED_TICKS,
         durability: Optional[EngineDurability] = None,
         obs=None,
     ) -> None:
@@ -574,9 +574,9 @@ def serve_engine_kv(
             driver.tracer = node.tracer  # ticks + RPCs on one timeline
         svc = EngineKVService(
             sched, kv, durability=dur, obs=node.obs,
-            ticks_per_pump=int(
-                os.environ.get("MULTIRAFT_SERVE_TICKS_PER_PUMP", "2")
-            ),
+            ticks_per_pump=int(os.environ.get(
+                "MULTIRAFT_SERVE_TICKS_PER_PUMP", str(SERVED_TICKS)
+            )),
         )
         ready.lap("warm")
         if dur is not None:

@@ -39,7 +39,7 @@ from .engine_wire import (
     make_mesh,
 )
 from .observe import Observability
-from .pump_cycle import PumpCycle
+from .pump_cycle import SERVED_TICKS, PumpCycle
 from .realtime import RealtimeScheduler
 from .tcp import RpcNode
 
@@ -72,7 +72,7 @@ class EngineShardKVService:
         sched: RealtimeScheduler,
         skv,  # BatchedShardKV
         pump_interval: float = 0.002,
-        ticks_per_pump: int = 2,
+        ticks_per_pump: int = SERVED_TICKS,
         peers: Optional[dict] = None,  # gid -> TcpClientEnd (remote owners)
         durability: Optional[EngineDurability] = None,
         obs=None,

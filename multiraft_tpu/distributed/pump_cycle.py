@@ -24,13 +24,20 @@ import functools
 import time
 from typing import Callable, Optional
 
+from ..engine.core import COMMIT_DEPTH
 from ..sim.scheduler import TIMEOUT, Future
 from ..utils.knobs import knob_float, knob_int
 from . import flightrec
 from .engine_pump import PUMP_THREAD_PREFIX, EnginePump
 from .realtime import PumpCadence, service_busy
 
-__all__ = ["PumpCycle"]
+__all__ = ["PumpCycle", "SERVED_TICKS"]
+
+# Ticks a served pump fuses into one program, both services' default:
+# the commit path's depth plus the ingest tick, so an entry ingested at
+# a batch's first tick commits inside the same program and its update
+# is answered at that pump's end, not the next one's.
+SERVED_TICKS = COMMIT_DEPTH + 1
 
 
 class PumpCycle:

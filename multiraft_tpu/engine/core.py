@@ -60,9 +60,17 @@ from ..utils.knobs import knob_bool
 __all__ = [
     "EngineConfig", "EngineState", "Mailbox", "SENDER_LANES", "init_state",
     "empty_mailbox", "per_edge", "tick", "METRIC_KEYS", "SCALAR_METRIC_KEYS",
+    "COMMIT_DEPTH",
 ]
 
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
+
+# Ticks from a leader's ingest of an entry to its ``commit_index``
+# covering it, read off the phase order of ``_tick_phases``: the leader
+# appends in phase 5b and sends in 5c of tick t; followers append and
+# reply in phase 3 of t+1; the leader counts the replies and advances
+# its commit in phase 4 of t+2.  tests/test_commit_depth.py pins it.
+COMMIT_DEPTH = 2
 
 
 def prevote_default() -> bool:

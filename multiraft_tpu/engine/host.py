@@ -1034,13 +1034,23 @@ class EngineDriver:
             )
             # np.nonzero on [n, G] is row-major: tick-major, group-minor —
             # exactly the serial loop's binding order.
-            for i, g in zip(*np.nonzero(accepted)):
+            ii, gg = np.nonzero(accepted)
+            for i, g in zip(ii, gg):
                 k = int(accepted[i, g])
                 self.backlog[g] -= k
                 self._bind_accepted(
                     int(g), k, int(starts[i, g]),
                     int(terms[i, g]) if terms is not None else None,
                 )
+            # Entries the batch ingested, and those of them (slots
+            # start+1 .. start+k) the group's commit covers by the
+            # batch's last tick: whether an update commits inside the
+            # pump that ingests it (pump_cycle.SERVED_TICKS).
+            took = accepted[ii, gg]
+            m.inc("pump.ingested", int(took.sum()))
+            m.inc("pump.committed_in_batch", int(np.clip(
+                host_rec["commit_index"][-1, gg] - starts[ii, gg], 0, took
+            ).sum()))
             # A host int from here on: the serial loop (warm-up,
             # elections) leaves a device scalar, which a sum would keep
             # on the device — a program launched on the loop every pump.

@@ -27,6 +27,9 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from multiraft_tpu.distributed.engine_durability import (  # noqa: E402
+    EngineDurability,
+)
 from multiraft_tpu.distributed.engine_server import (  # noqa: E402
     EngineClerk,
     EngineKVService,
@@ -42,8 +45,9 @@ from multiraft_tpu.distributed.engine_wire import (  # noqa: E402
     route_group,
 )
 from multiraft_tpu.distributed.observe import Observability  # noqa: E402
+from multiraft_tpu.distributed.pump_cycle import SERVED_TICKS  # noqa: E402
 from multiraft_tpu.distributed.tcp import RpcNode  # noqa: E402
-from multiraft_tpu.engine.core import EngineConfig  # noqa: E402
+from multiraft_tpu.engine.core import COMMIT_DEPTH, EngineConfig  # noqa: E402
 from multiraft_tpu.engine.firehose import (  # noqa: E402
     FH_OK,
     FH_RETRY,
@@ -60,6 +64,9 @@ G = 4
 GID = 1  # the sharded rigs join one gid: it serves every shard
 CYCLE_S = 0.012  # what the tests let pass between two pump ends
 HANDLERS = ("command", "batch", "firehose")
+# Ticks a pump one short of the commit path: a cycle that ends before
+# the write it ingested commits, so a handler parks across a pump end.
+SHORT = COMMIT_DEPTH
 
 
 class _Dur:
@@ -85,18 +92,29 @@ class _Dur:
 class _Rig:
     """One service on virtual time whose own pump loop is stopped; the
     test runs the cycles.  ``kind`` picks the service, and the methods
-    hide what differs between the two engines under them."""
+    hide what differs between the two engines under them.  ``ticks``:
+    the cycle's ticks a pump (the services' default, ``SERVED_TICKS``,
+    commits a write inside the pump that ingests it; ``SHORT`` ends a
+    pump before it commits).  ``data_dir``: a real ``EngineDurability``
+    there in place of ``durability``."""
 
-    def __init__(self, kind, durability=None):
+    def __init__(self, kind, durability=None, ticks=SERVED_TICKS,
+                 data_dir=None):
         self.kind = kind
         self.sched = sched = Scheduler()
         d = EngineDriver(EngineConfig(G=G, P=3, L=32, E=4, INGEST=4), seed=3)
         assert d.run_until_quiet_leaders(2000)
+        obs = Observability()
+        d.metrics = obs.metrics  # one registry, as a served node has
         if kind == "kv":
             self.engine = BatchedKV(d)
+            if data_dir is not None:
+                durability = EngineDurability(
+                    data_dir, d, self.engine, metrics=obs.metrics
+                )
             self.svc = EngineKVService(
-                sched, self.engine, durability=durability,
-                obs=Observability(),
+                sched, self.engine, durability=durability, obs=obs,
+                ticks_per_pump=ticks,
             )
         else:
             self.engine = skv = BatchedShardKV(d)
@@ -110,10 +128,14 @@ class _Rig:
                 skv.pump(2)
             else:
                 raise AssertionError("the join did not settle")
+            if data_dir is not None:
+                durability = EngineDurability(
+                    data_dir, d, skv, metrics=obs.metrics
+                )
             self.svc = EngineShardKVService(
-                sched, skv, durability=durability, obs=Observability()
+                sched, skv, durability=durability, obs=obs,
+                ticks_per_pump=ticks,
             )
-        assert self.svc.cycle.pipe is None  # the whole cycle inline
         self.svc.stop()
         sched.run_for(0)  # the constructor's pump timer finds it stopped
         self.counters = self.svc.m.counters
@@ -205,9 +227,15 @@ class _Rig:
 
 @pytest.fixture(params=["kv", "shardkv"])
 def sim(request, monkeypatch):
-    """``make(durability=None) -> _Rig`` for the service under test."""
-    monkeypatch.setenv("MRT_ENGINE_PIPELINE", "0")  # the whole cycle inline
-    return lambda durability=None: _Rig(request.param, durability)
+    """``make(**kw) -> _Rig`` for the service under test."""
+    monkeypatch.setenv("MRT_ENGINE_PIPELINE", "0")
+
+    def make(**kw):
+        rig = _Rig(request.param, **kw)
+        assert rig.svc.cycle.pipe is None  # the whole cycle inline
+        return rig
+
+    return make
 
 
 # -- (a) one step per pump end, nothing in between ---------------------------
@@ -229,7 +257,7 @@ def test_a_parked_write_steps_once_per_pump_end_it_spans(sim):
 
 
 def _events_between_two_pump_ends(make, parked):
-    rig = make()
+    rig = make(ticks=SHORT)
     futs = [rig.call("command", i) for i in range(parked)]
     rig.pump()  # first pump end: nothing has committed yet
     assert not any(f.done for f in futs)
@@ -251,7 +279,7 @@ def test_a_events_between_pump_ends_do_not_grow_with_parked_updates(sim):
 
 @pytest.mark.parametrize("handler", HANDLERS)
 def test_a_every_handler_wakes_at_a_pump_end_and_not_between(sim, handler):
-    rig = sim()
+    rig = sim(ticks=SHORT)
     fut = rig.call(handler, 0)
     rig.pump()  # first pump end: nothing has committed yet
     assert not fut.done
@@ -355,7 +383,7 @@ def test_b_reply_leaves_at_the_pump_end_that_finds_the_record_synced(
     sim, handler
 ):
     dur = _Dur()
-    rig = sim(dur)
+    rig = sim(durability=dur)
     fut = rig.call(handler, 0)
     rig.pump_until_logged(dur)
     assert rig.svc._write_seqs == {(100, 1): 1}
@@ -382,7 +410,7 @@ def test_b_an_applied_write_that_never_syncs_answers_timeout_not_ok(
     had no deadline before it parked on the cycle's wait, and a WAL that
     never synced held its handler for ever."""
     dur = _Dur()
-    rig = sim(dur)
+    rig = sim(durability=dur)
     fut = rig.call(handler, 0)
     rig.pump_until_logged(dur)
     for _ in range(5):  # pumps end, the record stays unsynced
@@ -400,7 +428,7 @@ def test_b_an_applied_write_that_never_syncs_answers_timeout_not_ok(
 
 
 def test_c_evicted_ticket_is_resubmitted_at_the_pump_end_that_failed_it(sim):
-    rig = sim()
+    rig = sim(ticks=SHORT)
     submits = rig.capture_submits()
     fut = rig.call("command", 0, op="Append")
     assert len(submits) == 1
@@ -479,3 +507,42 @@ def test_d_deadline_wakes_only_the_handler_it_belongs_to(sim):
     pumps = rig.pump_until([first, second])
     assert first.value.err == OK and second.value.err == OK
     assert rig.counters["kv.wait_steps"] <= 1 + 2 * pumps
+
+
+# -- (e) the served ticks a pump: a write commits inside the pump -----------
+
+
+@pytest.fixture(params=["kv", "shardkv"])
+def durable(request, tmp_path, monkeypatch):
+    """A ``_Rig`` at the services' default ticks a pump with a real WAL
+    in ``tmp_path``, its pump fused as the served one is (the pipeline
+    on; the test still runs the cycles inline)."""
+    monkeypatch.delenv("MRT_ENGINE_PIPELINE", raising=False)
+    rig = _Rig(request.param, data_dir=str(tmp_path))
+    assert rig.svc.cycle.ticks == SERVED_TICKS
+    assert rig.engine.driver.fused_eligible()
+    return rig
+
+
+def test_e_an_update_is_acknowledged_at_the_end_of_the_first_pump_that_carries_it(
+    durable,
+):
+    rig = durable
+    c = rig.counters
+    before = dict(c)  # the rig's own set-up pumped too (the join)
+    waves, per_wave = 6, 4  # a wave fits one tick's ingest (INGEST=4)
+    for w in range(waves):
+        futs = [rig.call("command", per_wave * w + i) for i in range(per_wave)]
+        assert not any(f.done for f in futs)  # parked on an idle pipeline
+        rig.pump()
+        assert all(f.done for f in futs), w
+        assert [f.value.err for f in futs] == [OK] * per_wave
+    assert rig.read("key0") == "v0"
+    assert c["kv.writes"] == waves * per_wave
+    assert c["kv.wait_steps"] / c["kv.writes"] <= 2
+    assert c["kv.wait_timeouts"] == 0
+    # the entries ingested were committed by their own batch's last tick
+    ingested = c["pump.ingested"] - before.get("pump.ingested", 0)
+    done = c["pump.committed_in_batch"] - before.get("pump.committed_in_batch", 0)
+    assert ingested >= waves * per_wave
+    assert done >= 0.9 * ingested

@@ -31,6 +31,7 @@ from multiraft_tpu.distributed.engine_server import (  # noqa: E402
 from multiraft_tpu.distributed.engine_wire import make_mesh  # noqa: E402
 from multiraft_tpu.distributed.tcp import RpcNode  # noqa: E402
 from multiraft_tpu.engine.core import (  # noqa: E402
+    COMMIT_DEPTH,
     FOLLOWER,
     LEADER,
     EngineConfig,
@@ -445,8 +446,14 @@ def test_no_pump_copies_the_whole_state(served, monkeypatch):
 
     monkeypatch.setattr(EngineDriver, "np_state", refuse("np_state"))
     monkeypatch.setattr(EngineDriver, "leader_of", refuse("leader_of"))
-    # A sweep at every pump's end, so that many meet a binding.
+    # A sweep at every pump's end, so that many meet a binding; and a
+    # cycle one tick short of the commit path, so that a binding outlives
+    # the pump that made it (at the served ticks a pump a write commits
+    # and applies inside its own pump, and the sweep finds nothing bound).
     kv.ORPHAN_SWEEP_TICKS = 2
+    node.sched.run_call(
+        lambda: setattr(node.engine_service.cycle, "ticks", COMMIT_DEPTH)
+    )
     client = RpcNode()
     try:
         end = client.client_end("127.0.0.1", node.port)
